@@ -22,16 +22,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-try:
-    from jax._src.core import (ClosedJaxpr, DropVar, Jaxpr, Literal, Var,
-                               check_jaxpr)
-except ImportError:  # pragma: no cover - older/newer jax layouts
-    from jax.core import (ClosedJaxpr, DropVar, Jaxpr, Literal,  # type: ignore
-                          Var)
-    try:
-        from jax.core import check_jaxpr  # type: ignore
-    except ImportError:
-        check_jaxpr = None  # type: ignore
+from jax._src.core import (ClosedJaxpr, DropVar, Jaxpr, Literal, Var,
+                           check_jaxpr)
 
 from .findings import Finding
 
@@ -80,7 +72,7 @@ def check_shapes(program) -> List[Finding]:
                 and v not in defined:
             findings.append(Finding(
                 "DF001", f"program output {v} is never defined", line=0))
-    if not findings and check_jaxpr is not None:
+    if not findings:
         try:
             check_jaxpr(jaxpr)
         except Exception as e:  # JaxprTypeError and friends

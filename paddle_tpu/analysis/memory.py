@@ -97,10 +97,7 @@ def peak_hbm_estimate(program, donate: Sequence[int] = (),
     defaults reproduce the original whole-program accounting bit-for-bit.
     """
     from .dataflow import _closed  # lazy: pulls in jax
-    try:
-        from jax._src.core import DropVar, Literal, Var
-    except ImportError:  # pragma: no cover
-        from jax.core import DropVar, Literal, Var  # type: ignore
+    from jax._src.core import DropVar, Literal, Var
 
     closed = _closed(program)
     jaxpr = closed.jaxpr

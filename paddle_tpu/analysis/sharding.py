@@ -291,11 +291,7 @@ class PropagationResult:
 
 
 def _jax_core():
-    try:
-        from jax._src.core import ClosedJaxpr, DropVar, Jaxpr, Literal, Var
-    except ImportError:  # pragma: no cover - older/newer jax layouts
-        from jax.core import (ClosedJaxpr, DropVar, Jaxpr,  # type: ignore
-                              Literal, Var)
+    from jax._src.core import ClosedJaxpr, DropVar, Jaxpr, Literal, Var
     return ClosedJaxpr, DropVar, Jaxpr, Literal, Var
 
 

@@ -40,16 +40,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.tensor import Tensor
 from .auto_parallel import ProcessMesh
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except (ImportError, TypeError):  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 #: jaxpr primitive names that are cross-rank collectives. This is the
@@ -158,18 +151,6 @@ def _maybe_init_multihost():
     host, port = coord.rsplit(":", 1)
     coord_addr = os.environ.get("JAX_COORDINATOR_ADDRESS",
                                 f"{host}:{int(port) + 1}")
-    # the CPU PJRT client has no cross-process collectives of its own —
-    # without gloo every multi-process CPU-proxy run dies at the first
-    # collective with "Multiprocess computations aren't implemented on
-    # the CPU backend". Must be set BEFORE the backend is created, so
-    # key off the platform request rather than jax.default_backend().
-    platforms = (os.environ.get("JAX_PLATFORMS")
-                 or getattr(jax.config, "jax_platforms", None) or "")
-    if "cpu" in platforms.split(","):
-        try:
-            jax.config.update("jax_cpu_enable_gloo_collectives", True)
-        except Exception:
-            pass  # flag absent on this jaxlib: keep the TPU path intact
     try:
         # num_processes/process_id must be explicit: jax only reads the
         # coordinator address from env, not the process counts

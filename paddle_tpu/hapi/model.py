@@ -350,7 +350,10 @@ class Model:
             reg.gauge("train_tokens_per_sec",
                       "input elements consumed per second by "
                       "train_batch").set(tokens / dt)
-        fwd = self._fwd_flops_estimate(shapes)
+        from ..ops import pallas as _pl
+        # utilization is defined against a TPU's peak: off the chip the
+        # step publishes its time and rate and no MFU
+        fwd = self._fwd_flops_estimate(shapes) if _pl.on_tpu() else 0
         if fwd and dt > 0:
             from ..utils.flops import peak_device_flops
             # train ≈ 3× forward (fwd + ~2× bwd), the usual MFU convention

@@ -519,15 +519,23 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
     # grouped scores: q regrouped [T, KV, rep, D] vs timeline [T, KV, S, D]
     qg = qt.reshape(token_num, kvh, rep, hd).astype(jnp.float32)
     scale = 1.0 / float(hd) ** 0.5
-    scores = jnp.einsum("tgrd,tgsd->tgrs", qg,
-                        gk[seq_of].astype(jnp.float32)) * scale
     kv_pos = jnp.arange(s_kv)[None, None, None, :]
     ok = (kv_pos <= pos[:, None, None, None]) \
         & (kv_pos < kv_len[seq_of][:, None, None, None])
-    scores = jnp.where(ok, scores, _NEG)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("tgrs,tgsd->tgrd", probs,
-                     gv[seq_of].astype(jnp.float32))
+    if bsz == 1:
+        # One sequence, which is how the batcher admits a prompt: every
+        # token attends the same timeline. Indexing it per token copies it
+        # T times when the op runs eagerly (10.7 GB for a 640-token prompt
+        # at 32 x 128 heads and a 2048-row slot — the chip ran out of
+        # memory); XLA folds that gather away only inside one jit program.
+        tk, tv, timeline = gk[0], gv[0], "gsd"
+    else:
+        tk, tv, timeline = gk[seq_of], gv[seq_of], "tgsd"
+    scores = jnp.einsum(f"tgrd,{timeline}->tgrs", qg,
+                        tk.astype(jnp.float32)) * scale
+    probs = jax.nn.softmax(jnp.where(ok, scores, _NEG), axis=-1)
+    out = jnp.einsum(f"tgrs,{timeline}->tgrd", probs,
+                     tv.astype(jnp.float32))
     result = (Tensor(out.reshape(token_num, nh * hd).astype(qt.dtype)),
               Tensor(kc), Tensor(vc))
     if new_scales is not None:

@@ -197,6 +197,7 @@ class Gateway:
         self._finished: Dict[int, GatewayRequest] = {}
         self._failed: Dict[int, Exception] = {}
         self._sessions: Dict[int, StreamingSession] = {}
+        self._last_death: Optional[BaseException] = None
         self._tele = _GatewayStats()
 
     # -- pool lifecycle -------------------------------------------------------
@@ -356,6 +357,7 @@ class Gateway:
                 continue
             status, payload = self.pool.step_replica(rep)
             if status == "dead":
+                self._last_death = payload
                 if isinstance(self.router, SessionAffinityPolicy):
                     self.router.forget_replica(rep.name)
                 self._requeue_from(rep)
@@ -766,6 +768,13 @@ class Gateway:
             done += self.step()
             if not self._has_work():
                 break
+            if not self.pool.live():
+                # nobody is left to serve what remains: say what killed
+                # the last replica instead of spinning out the budget
+                raise RuntimeError(
+                    f"run_until_done: every replica is dead with "
+                    f"{len(self._requests)} request(s) unfinished"
+                ) from self._last_death
         else:
             raise RuntimeError(
                 f"run_until_done: {len(self._queue)} queued / "

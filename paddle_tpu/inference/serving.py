@@ -772,9 +772,9 @@ class PagedContinuousBatcher(_BatcherBase):
     decode_block=K (greedy only): pure-decode phases run K steps as ONE
     compiled executable with on-device argmax feedback — one dispatch
     and one K*B-token download per K tokens instead of K dispatches
-    each hauling [B, V] logits to the host. On a remote-relayed device
-    the per-dispatch latency dominates a small model's decode compute,
-    so this is the serving-throughput lever there. Token-exact vs the
+    each hauling [B, V] logits to the host. Where per-dispatch latency
+    dominates a small model's decode compute this is the
+    serving-throughput lever (not measured on the chip). Token-exact vs the
     per-step path; EOS/budget overshoot inside a block is discarded on
     the host and its K/V rows land in the slot's own pages or scratch.
 
@@ -1219,9 +1219,7 @@ class PagedContinuousBatcher(_BatcherBase):
             # K decode steps unrolled into ONE executable with on-device
             # greedy feedback: one dispatch (and one host round trip for
             # K*B token ids instead of K full [B, V] logits downloads)
-            # per K tokens. Through a remote-relay device the per-call
-            # latency dominates the decode step's compute, so this is
-            # the serving-throughput lever for pure-decode phases.
+            # per K tokens.
             def _block_body(tok, state, _K=decode_block, _m=model):
                 toks = []
                 for _ in range(_K):

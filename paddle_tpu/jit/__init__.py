@@ -27,6 +27,7 @@ from ..autograd import engine as _engine
 from ..core import capture as _capture
 from ..core import random as _random
 from ..core.tensor import Tensor
+from ..ops import pallas as _pallas
 from ..optimizer.clip import ClipGradByGlobalNorm
 from ..perf import compile_cache as _cc
 from ..perf.buckets import resolve_ladder as _resolve_ladder
@@ -123,7 +124,6 @@ class StaticFunction:
         key = _sig_of(args, kwargs)
         entry = self._cache.get(key)
         if entry is None:
-            _cc.maybe_enable_persistent_cache()
             with _cc.timed_miss():
                 entry = self._trace(args, kwargs)
             self._cache[key] = entry
@@ -612,7 +612,6 @@ class TrainStep:
         key = _sig_of(args, {})
         entry = self._cache.get(key)
         if entry is None:
-            _cc.maybe_enable_persistent_cache()
             if self._cache:
                 # The pure step re-executes the model under tracing, so it is
                 # shape-polymorphic: a new batch shape only needs an XLA
@@ -732,8 +731,13 @@ class TrainStep:
                 if rng_used:
                     gen.set_state(rng_key)
                 batch_t = [Tensor(b) for b in batch]
-                loss_t = fn(*batch_t)
-                _engine.run_backward([loss_t], [None])
+                # under a mesh plan this traces one SPMD program, in
+                # which a Pallas kernel has to be wrapped to run at all
+                with _pallas.whole_on_each_device(
+                        None if mesh_plan is None
+                        else mesh_plan.runtime.mesh):
+                    loss_t = fn(*batch_t)
+                    _engine.run_backward([loss_t], [None])
                 grads = [None if p._grad is None else p._grad._data
                          for p in params]
                 if mesh_plan is not None:
@@ -895,6 +899,21 @@ class TrainStep:
         except Exception:
             pass
 
+    def aot_compile(self, *args):
+        """The step's XLA executable for this batch, compiled ahead of
+        time at the live state's shapes: nothing runs and no donated
+        buffer is touched. ``as_text()`` is the optimized HLO (kernels
+        appear as ``tpu_custom_call``, collectives by name) and
+        ``memory_analysis()`` XLA's own buffer assignment. Call after a
+        compiled step has run at this batch signature; the persistent
+        compilation cache absorbs the second compile."""
+        entry = self._cache.get(_sig_of(args, {}))
+        if entry is None:
+            raise RuntimeError("aot_compile needs a step that has already "
+                               "run at this batch signature")
+        return entry["compiled"].lower(
+            *self._assemble(entry, args)).compile()
+
     def mesh_memory_report(self, *args, tolerance: float = 0.10):
         """Runtime/static memory cross-check for the compiled SPMD step.
 
@@ -905,17 +924,10 @@ class TrainStep:
         report dict, or None when there is no mesh plan / the backend
         exposes no memory analysis. Call after at least one step."""
         mp = self._mesh_plan
-        if mp is None or not self._cache:
+        if mp is None or _sig_of(args, {}) not in self._cache:
             return None
         from ..distributed.mesh import MeshRuntime
-        entry = (self._cache.get(_sig_of(args, {})) if args
-                 else next(iter(self._cache.values())))
-        if entry is None or entry.get("first_loss") is not None:
-            return None
-        call_args = self._assemble(entry, args) if args else None
-        if call_args is None:
-            return None
-        exe = entry["compiled"].lower(*call_args).compile()
+        exe = self.aot_compile(*args)
         measured = MeshRuntime.measured_live_bytes(exe)
         predicted = mp.memory_report
         if measured is None or not predicted:

@@ -17,7 +17,6 @@ fusion kernels). This is a TPU-first redesign, not a port:
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -537,14 +536,8 @@ class ScannedLlamaLayers(Layer):
                                          attn_mask is None,
                                          batch_axis, head_axis,
                                          attn_mask is not None, False)
-        # PADDLE_TPU_FLASH_INTERPRET=1 routes the flash kernel interpreted
-        # on the CPU mesh — the only way to exercise the exact bench
-        # composition (flash x selective remat x scan) before a hardware
-        # window; production routing stays TPU-only
-        flash_interp = (os.environ.get("PADDLE_TPU_FLASH_INTERPRET") == "1"
-                        and not _pl.on_tpu())
         use_flash = (ring_impl is None and attn_mask is None
-                     and (_pl.on_tpu() or flash_interp)
+                     and _pl.on_tpu()
                      and get_flag("FLAGS_use_pallas_attention"))
         if use_flash:
             from ..ops.pallas.flash_attention import supported
@@ -627,8 +620,7 @@ class ScannedLlamaLayers(Layer):
                     # (the index map expands the group in-kernel)
                     from ..ops.pallas.flash_attention import \
                         flash_attention_pallas
-                    ctx = flash_attention_pallas(q, k, v, causal=True,
-                                                 interpret=flash_interp)
+                    ctx = flash_attention_pallas(q, k, v, causal=True)
                 else:
                     if kv != h:
                         rep = h // kv

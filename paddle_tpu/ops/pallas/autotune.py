@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import math
 import time
+import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -101,9 +102,10 @@ def tune_flash_blocks(q, k, v, causal: bool = True, attn_mask=None,
                       iters: int = 5, include_bwd: bool = True):
     """Measure the candidate tilings on-device; cache + return the winner.
 
-    Returns (best, results) where results is {(bq, bk): seconds | None}
-    (None = that tiling failed to compile/run, e.g. VMEM overflow —
-    recorded, not raised, so one oversized candidate can't kill tuning).
+    Returns (best, results) where results is {(bq, bk): seconds | None}.
+    None means the compiler refused that tiling (VMEM overflow): it is
+    warned about once with the compiler's message and the sweep goes on.
+    A sweep in which every candidate is refused raises.
     """
     from . import on_tpu
     from .flash_attention import flash_attention_pallas
@@ -140,14 +142,20 @@ def tune_flash_blocks(q, k, v, causal: bool = True, attn_mask=None,
         jax.block_until_ready(r)
         return (time.perf_counter() - t0) / iters
 
+    refusals: Dict[Tuple[int, int], str] = {}
     for c in cands:
         try:
             results[c] = run(*c)
-        except Exception:
-            results[c] = None  # VMEM overflow / Mosaic reject at this tile
+        except jax.errors.JaxRuntimeError as e:
+            results[c] = None
+            refusals[c] = str(e).splitlines()[0][:300]
+            sig = _sig(q, k, causal, attn_mask is not None, dropout_p)
+            warnings.warn(f"flash autotune: tiling {c} refused at {sig}: "
+                          f"{refusals[c]}")
     timed = {c: t for c, t in results.items() if t is not None}
     if not timed:
-        raise RuntimeError(f"no flash block candidate ran: {results}")
+        raise RuntimeError(
+            f"flash autotune: the compiler refused every tiling: {refusals}")
     best = min(timed, key=timed.get)
     _BEST[_sig(q, k, causal, attn_mask is not None, dropout_p)] = best
     return best, results

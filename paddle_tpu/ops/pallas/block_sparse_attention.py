@@ -108,6 +108,7 @@ def _bs_fwd(q, k, v, kmap, counts, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="block_sparse_fwd",
     )(kmap, counts, q, k, v)
 
 

@@ -39,6 +39,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_call
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -350,7 +352,7 @@ def _fwd_call(q, k, v, mask, seqlens, seed_arr, causal, dropout_p, hq, hkv,
         _fwd_kernel, block_q=block_q, block_k=block_k, causal=causal,
         scale=scale, dropout_p=dropout_p, has_mask=has_mask,
         has_seqlens=has_seqlens, hq=hq, tpu_prng=not interpret)
-    out, lse = pl.pallas_call(
+    out, lse = kernel_call(pl.pallas_call(
         kernel,
         grid=(bh, s // block_q, s // block_k),
         in_specs=in_specs,
@@ -370,7 +372,8 @@ def _fwd_call(q, k, v, mask, seqlens, seed_arr, causal, dropout_p, hq, hkv,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+        name="flash_fwd",
+    ), *args)
     return out, lse
 
 
@@ -399,7 +402,7 @@ def _bwd_call(q, k, v, o, do, lse, mask, seqlens, seed_arr, causal,
     in_specs += [seq_spec, seed_spec]
     args += [seqlens, seed_arr]
 
-    dq = pl.pallas_call(
+    dq = kernel_call(pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
                           causal=causal, scale=scale, dropout_p=dropout_p,
                           has_mask=has_mask, has_seqlens=has_seqlens,
@@ -412,7 +415,8 @@ def _bwd_call(q, k, v, o, do, lse, mask, seqlens, seed_arr, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+        name="flash_bwd_dq",
+    ), *args)
 
     # dk/dv: grid over K/V tiles, Q stream innermost. Outputs are per Q-head;
     # the GQA group-sum happens outside the kernel (one cheap XLA reduce).
@@ -450,7 +454,7 @@ def _bwd_call(q, k, v, o, do, lse, mask, seqlens, seed_arr, causal,
     ]
     dkv_args += [seqlens, seed_arr]
 
-    dk_ph, dv_ph = pl.pallas_call(
+    dk_ph, dv_ph = kernel_call(pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
                           causal=causal, scale=scale, dropout_p=dropout_p,
                           has_mask=has_mask, has_seqlens=has_seqlens,
@@ -472,7 +476,8 @@ def _bwd_call(q, k, v, o, do, lse, mask, seqlens, seed_arr, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*dkv_args)
+        name="flash_bwd_dkv",
+    ), *dkv_args)
 
     if group > 1:
         b = bh // hq
@@ -569,12 +574,11 @@ def _resolve_blocks(q, k, v, causal, attn_mask, dropout_p, block_q, block_k,
     signature. Traced calls (the training path always traces through
     jax.vjp) tune on synthesized concrete arrays matching the tracer's
     aval — tuning needs the shapes, not the values — so the flag works
-    for compiled training, not just eager inference. A failed sweep
-    negative-caches the defaults so serving loops don't re-pay the
-    compile attempts per call. Sequences below DEFAULT_BLOCK_Q skip the
-    consult entirely: the short-sequence shrink below would override any
-    tuned tiling, so tuning them would burn compiles for a discarded
-    answer.
+    for compiled training, not just eager inference. A sweep in which
+    the compiler refuses every tiling raises here. Sequences below
+    DEFAULT_BLOCK_Q skip the consult entirely: the short-sequence shrink
+    below would override any tuned tiling, so tuning them would burn
+    compiles for a discarded answer.
     """
     if block_q is not None or block_k is not None:
         return (block_q or DEFAULT_BLOCK_Q, block_k or DEFAULT_BLOCK_K)
@@ -586,21 +590,13 @@ def _resolve_blocks(q, k, v, causal, attn_mask, dropout_p, block_q, block_k,
             tuned = autotune.cached_blocks(q, k, causal,
                                            attn_mask is not None, dropout_p)
             if tuned is None and on_tpu():
-                try:
-                    if isinstance(q, jax.core.Tracer):
-                        qc, kc, vc, mc = autotune.synth_like(q, k, v,
-                                                             attn_mask)
-                    else:
-                        qc, kc, vc, mc = q, k, v, attn_mask
-                    tuned, _ = autotune.tune_flash_blocks(
-                        qc, kc, vc, causal=causal, attn_mask=mc,
-                        dropout_p=dropout_p)
-                except Exception:
-                    # tuning must never break the call; remember the
-                    # failure so the sweep isn't re-paid every call
-                    tuned = (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
-                    autotune.set_best(q, k, causal, attn_mask is not None,
-                                      dropout_p, tuned)
+                if isinstance(q, jax.core.Tracer):
+                    qc, kc, vc, mc = autotune.synth_like(q, k, v, attn_mask)
+                else:
+                    qc, kc, vc, mc = q, k, v, attn_mask
+                tuned, _ = autotune.tune_flash_blocks(
+                    qc, kc, vc, causal=causal, attn_mask=mc,
+                    dropout_p=dropout_p)
             if tuned is not None:
                 return tuned
     return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K

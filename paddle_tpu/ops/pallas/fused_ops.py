@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_call
+
 
 def _round_up(n, m):
     return (n + m - 1) // m * m
@@ -67,13 +69,22 @@ def _rms_bwd_kernel(x_ref, w_ref, g_ref, rstd_ref, dx_ref, dw_ref):
         dw_ref[:] += part
 
 
-def _pick_block_rows(n_rows: int) -> int:
-    # callers pad n_rows to a multiple of 8 (TPU sublane tiling), so a
-    # multiple-of-8 block always exists
-    for cand in (256, 128, 64, 32, 16, 8):
-        if n_rows % cand == 0:
+# Mosaic refuses a kernel whose scoped VMEM passes 16 MiB on a v5e; the
+# row block is sized to stay under 12 MiB of it.
+_VMEM_BUDGET = 12 << 20
+
+
+def _pick_block_rows(n_rows: int, h: int, itemsize: int) -> int:
+    # Sized for the backward, the larger of the two kernels: the x, g and
+    # dx blocks are double-buffered in the input dtype and the compiler
+    # keeps about two fp32 temporaries per element (it reported 17.3 B per
+    # element for bf16 at 256 x 4096). Callers pad n_rows to a multiple of
+    # 8 (TPU sublane tiling), so the 8-row block always divides.
+    per_row = h * (6 * itemsize + 8)
+    for cand in (256, 128, 64, 32, 16):
+        if n_rows % cand == 0 and cand * per_row <= _VMEM_BUDGET:
             return cand
-    return n_rows
+    return 8
 
 
 def _pad_rows(a, n_pad):
@@ -87,8 +98,8 @@ def _rms_fwd_call(x2d, w, eps, interpret):
     n_orig, h = x2d.shape
     n = _round_up(n_orig, 8)
     x2d = _pad_rows(x2d, n)   # zero rows: rstd=rsqrt(eps), sliced off below
-    br = _pick_block_rows(n)
-    out, rstd = pl.pallas_call(
+    br = _pick_block_rows(n, h, x2d.dtype.itemsize)
+    out, rstd = kernel_call(pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
         grid=(n // br,),
         in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0)),
@@ -98,7 +109,8 @@ def _rms_fwd_call(x2d, w, eps, interpret):
         out_shape=[jax.ShapeDtypeStruct((n, h), x2d.dtype),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         interpret=interpret,
-    )(x2d, w)
+        name="rms_norm_fwd",
+    ), x2d, w)
     return out[:n_orig], rstd[:n_orig]
 
 
@@ -110,9 +122,9 @@ def _rms_bwd_call(x2d, w, g2d, rstd, interpret):
     x2d = _pad_rows(x2d, n)
     g2d = _pad_rows(g2d, n)
     rstd = _pad_rows(rstd, n)
-    br = _pick_block_rows(n)
+    br = _pick_block_rows(n, h, x2d.dtype.itemsize)
     grid = n // br
-    dx, dw = pl.pallas_call(
+    dx, dw = kernel_call(pl.pallas_call(
         _rms_bwd_kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0)),
@@ -124,7 +136,8 @@ def _rms_bwd_call(x2d, w, g2d, rstd, interpret):
         out_shape=[jax.ShapeDtypeStruct((n, h), x2d.dtype),
                    jax.ShapeDtypeStruct((1, h), jnp.float32)],
         interpret=interpret,
-    )(x2d, w, g2d, rstd)
+        name="rms_norm_bwd",
+    ), x2d, w, g2d, rstd)
     return dx[:n_orig], dw[0]
 
 
@@ -240,6 +253,7 @@ def adamw_pallas(p, m, v, g, *, lr, beta1, beta2, eps, weight_decay,
                    jax.ShapeDtypeStruct((rows, lane), jnp.float32)],
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="adamw_update",
     )(scalars, flat(p, p.dtype), flat(m, jnp.float32),
       flat(v, jnp.float32), flat(g, jnp.float32))
 

@@ -20,13 +20,8 @@ from typing import Callable, Dict, List, Sequence, Union
 import jax
 import jax.numpy as jnp
 
-try:  # jaxpr types/evaluator moved between jax versions; import defensively
-    from jax._src.core import (ClosedJaxpr, DropVar, Jaxpr, Literal, Var,
-                               jaxpr_as_fun)
-except ImportError:  # pragma: no cover
-    from jax.core import (ClosedJaxpr, DropVar, Jaxpr, Literal,  # type: ignore
-                          Var)
-    from jax.extend.core import jaxpr_as_fun  # type: ignore
+from jax._src.core import (ClosedJaxpr, DropVar, Jaxpr, Literal, Var,
+                           jaxpr_as_fun)
 
 __all__ = ["IrProgram", "register_pass", "apply_pass", "list_passes",
            "is_analysis_pass"]

@@ -1,0 +1,261 @@
+"""Offer the main path's kernels to the chip's compiler, without a chip.
+
+libtpu compiles for a TPU that is described, not attached
+(``jax.experimental.topologies``), so what Mosaic or XLA:TPU would refuse on
+a v5e — a kernel over its scoped VMEM, a misaligned tile, a program that
+does not fit 16 GB — is refused here, in tier-1, at the widths of
+``llama2_7b_config()``. Nothing runs: these say nothing about results or
+times. Interpret-mode tests cannot see any of this (the rmsnorm kernel
+passed all of them and did not compile at hidden 4096).
+
+Skipped where the topology cannot be described. JAX's persistent cache is
+off around the compiles: an executable for a described device cannot be
+read back without the device.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# Llama-2-7B widths (models/llama.py: llama2_7b_config)
+HIDDEN, HEADS, HEAD_DIM, MLP, SEQ = 4096, 32, 128, 11008, 2048
+
+
+@pytest.fixture(scope="module")
+def host():
+    """The four chips of a described v5e 2x2 host."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it cannot start
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def chip(host):
+    """Sharding on one of them."""
+    return SingleDeviceSharding(host[0])
+
+
+@pytest.fixture(autouse=True)
+def _as_on_the_chip():
+    """Cache off (see module docstring) and x64 off: tier-1 turns x64 on
+    for its numeric-gradient checks, the chip runs without it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    with jax.enable_x64(False):
+        yield
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Compile for the described chip; raises what its compiler raises."""
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _kernel_calls(compiled, name):
+    """tpu_custom_call lines of the optimized HLO that carry a kernel's
+    ``pallas_call(name=...)``."""
+    return [ln for ln in compiled.as_text().splitlines()
+            if "tpu_custom_call" in ln and name in ln]
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha32", "gqa32_8"])
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles_at_7b_widths(chip, kv_heads, bwd):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
+
+    def fwd(q, k, v):
+        return flash_attention_pallas(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((2, SEQ, HEADS, HEAD_DIM), jnp.bfloat16,
+                             sharding=chip)
+    kv = jax.ShapeDtypeStruct((2, SEQ, kv_heads, HEAD_DIM), jnp.bfloat16,
+                              sharding=chip)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)) if bwd else fwd,
+                        q, kv, kv)
+    assert _kernel_calls(compiled, "flash_fwd")
+    if bwd:
+        assert _kernel_calls(compiled, "flash_bwd_dq")
+        assert _kernel_calls(compiled, "flash_bwd_dkv")
+
+
+@pytest.mark.parametrize("hidden", [2048, 4096, 8192])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_rmsnorm_fwd_bwd_compiles(chip, hidden, dtype):
+    """Hidden 4096 and 8192 overflowed scoped VMEM (18.13 MiB against a
+    16 MiB limit) while the row block was 256 whatever the width."""
+    from paddle_tpu.ops.pallas.fused_ops import rms_norm_pallas
+
+    def loss(x, w):
+        return rms_norm_pallas(x, w, 1e-5).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((2, SEQ, hidden), dtype, sharding=chip)
+    w = jax.ShapeDtypeStruct((hidden,), dtype, sharding=chip)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), x, w)
+    assert _kernel_calls(compiled, "rms_norm_fwd")
+    assert _kernel_calls(compiled, "rms_norm_bwd")
+
+
+def test_kernels_compile_inside_a_mesh_program(host):
+    """Mosaic kernels cannot be partitioned automatically: lowered in a
+    program over four chips they raise, which is what the SPMD train step
+    did on a TPU. ``whole_on_each_device`` wraps them (ops/pallas)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops import pallas as _pl
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
+    from paddle_tpu.ops.pallas.fused_ops import rms_norm_pallas
+
+    mesh = Mesh(np.array(host, dtype=object).reshape(2, 2),
+                ("fsdp", "tensor"))
+
+    def loss(x, w):
+        b, s, _ = x.shape
+        y = rms_norm_pallas(x, w, 1e-5).reshape(b, s, HEADS, HEAD_DIM)
+        return flash_attention_pallas(y, y, y, causal=True).astype(
+            jnp.float32).sum()
+
+    def spmd_loss(x, w):
+        with _pl.whole_on_each_device(mesh):
+            return jax.grad(loss, argnums=(0, 1))(x, w)
+
+    x = jax.ShapeDtypeStruct((1, SEQ, HIDDEN), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    w = jax.ShapeDtypeStruct((HIDDEN,), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(jax.grad(loss, argnums=(0, 1)), x, w)
+    compiled = _compile(spmd_loss, x, w)
+    for kernel in ("rms_norm_fwd", "rms_norm_bwd", "flash_fwd",
+                   "flash_bwd_dq", "flash_bwd_dkv"):
+        assert _kernel_calls(compiled, kernel), kernel
+
+
+def test_adamw_kernel_compiles_at_mlp_width(chip):
+    """Off by default (FLAGS_use_pallas_adamw); offered at the size of
+    one 7B MLP matrix so that ROADMAP S5 starts from a kernel that
+    compiles."""
+    from paddle_tpu.ops.pallas.fused_ops import adamw_pallas
+
+    def update(p, m, v, g):
+        return adamw_pallas(p, m, v, g, lr=1e-4, beta1=0.9, beta2=0.999,
+                            eps=1e-8, weight_decay=0.01, beta1_pow=0.9,
+                            beta2_pow=0.999)
+
+    a = jax.ShapeDtypeStruct((HIDDEN, MLP), jnp.float32, sharding=chip)
+    assert _kernel_calls(_compile(update, a, a, a, a), "adamw_update")
+
+
+def test_block_sparse_attention_compiles(chip):
+    """Causal sliding window of 4 tiles plus a global first column, at
+    s 2048, 32 x 128 heads (ROADMAP S5)."""
+    import numpy as np
+
+    from paddle_tpu.ops.pallas.block_sparse_attention import \
+        block_sparse_attention_pallas
+
+    nb = SEQ // 128
+    i, j = np.indices((nb, nb))
+    pattern = (j <= i) & ((j >= i - 3) | (j == 0))
+
+    def fwd(q, k, v):
+        return block_sparse_attention_pallas(q, k, v, pattern)
+
+    x = jax.ShapeDtypeStruct((1, SEQ, HEADS, HEAD_DIM), jnp.bfloat16,
+                             sharding=chip)
+    assert _kernel_calls(_compile(fwd, x, x, x), "block_sparse_fwd")
+
+
+@pytest.mark.parametrize("tokens", [8, 1024], ids=["decode_b8", "prefill_1k"])
+def test_paged_attention_step_compiles(chip, tokens):
+    """The serving attention op (an XLA gather over the block table, no
+    Pallas kernel yet — ROADMAP S3) at 32 x 128 heads with 16-token pages:
+    one decode step for 8 slots, and a 1024-token prompt admitted into one
+    slot, which is how the batcher admits. (Two packed prompts in one call
+    do not fit: the op gathers a copy of the timeline per token, 34 GB at
+    these sizes; XLA folds that gather away only for a single sequence.)"""
+    from paddle_tpu.incubate.nn.functional.decode_attention import \
+        block_gqa_attention
+
+    block, blocks_per_seq = 16, SEQ // 16
+    bsz = 8 if tokens == 8 else 1
+    n_pages = 8 * blocks_per_seq + 1
+
+    def step(q, k, v, kc, vc, dec, bt, cos, sin):
+        if tokens == bsz:        # decode: one token per slot, appended
+            enc, this = jnp.zeros_like(dec), jnp.ones_like(dec)
+        else:                    # prefill: the whole prompt into slot 0
+            enc = this = jnp.full_like(dec, tokens)
+        cu_q = jnp.arange(bsz + 1, dtype=jnp.int32) * (tokens // bsz)
+        out, kc, vc = block_gqa_attention(
+            q, k, v, kc, vc, enc, dec, this, cu_q, bt, block_size=block,
+            rope_cos=cos, rope_sin=sin)
+        return out._data, kc._data, vc._data
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    qkv = sds((tokens, HEADS, HEAD_DIM))
+    pool = sds((n_pages, HEADS, block, HEAD_DIM))
+    rope = sds((SEQ, HEAD_DIM // 2))
+    compiled = _compile(step, qkv, qkv, qkv, pool, pool,
+                        sds((bsz,), jnp.int32),
+                        sds((bsz, blocks_per_seq), jnp.int32), rope, rope)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 << 30, mem
+
+
+def test_scanned_decoder_layer_fwd_bwd_compiles(chip, monkeypatch):
+    """One layer of the scanned stack, forward and backward under
+    selective recompute, at 7B widths: flash attention inside
+    jax.checkpoint inside lax.scan, as the train step composes them. The
+    test answers ``on_tpu()`` for the program, which sees the CPU here."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.models.llama import ScannedLlamaLayers, llama2_7b_config
+    from paddle_tpu.ops import pallas as _pl
+
+    cfg = llama2_7b_config(num_hidden_layers=1, dtype="bfloat16",
+                           scan_layers=True, use_recompute=True,
+                           recompute_granularity="selective")
+    layer = ScannedLlamaLayers(cfg)
+    params = list(layer.parameters())
+    monkeypatch.setattr(_pl, "on_tpu", lambda: True)
+
+    def loss(arrays, hidden, cos, sin):
+        saved = [p._data for p in params]
+        try:
+            for p, a in zip(params, arrays):
+                p._data = a
+            with paddle.no_grad():
+                out = layer(Tensor(hidden), cos, sin)
+            return out._data.astype(jnp.float32).sum()
+        finally:
+            for p, a in zip(params, saved):
+                p._data = a
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.bfloat16,
+                                    sharding=chip)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)),
+                        [sds(p.shape) for p in params],
+                        sds((1, SEQ, HIDDEN)),
+                        sds((SEQ, HEAD_DIM // 2)), sds((SEQ, HEAD_DIM // 2)))
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert _kernel_calls(compiled, kernel), kernel

@@ -208,34 +208,49 @@ def test_fused_rope_half_style():
 def test_bench_composition_flash_selective_scan(monkeypatch):
     """The EXACT bench.py headline composition — Pallas flash attention
     INSIDE a jax.checkpoint(selective)-wrapped lax.scan body with a full
-    TrainStep — has to trace/compile/train as one program. This runs it
-    interpreted on the CPU mesh (PADDLE_TPU_FLASH_INTERPRET=1) so a
-    composition break (e.g. checkpoint-over-custom_vjp-in-scan) surfaces
-    before a hardware window instead of burning one."""
+    TrainStep — has to trace/compile/train as one program. The test
+    stands in for the chip: it answers ``on_tpu()`` with True and runs
+    the kernel interpreted, so a composition break (e.g.
+    checkpoint-over-custom_vjp-in-scan) surfaces on the CPU mesh.
+    tests/test_chip_compile.py offers the same composition to the chip's
+    compiler at 7B widths. (Hidden 64 is below the rmsnorm kernel's
+    128-lane gate, so only flash is reached.)"""
+    import functools
+
     import numpy as np
 
     import paddle_tpu as paddle
     from paddle_tpu import jit, optimizer
     from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
+    from paddle_tpu.ops import pallas as _pl
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    flash_calls = []
+
+    @functools.wraps(fa.flash_attention_pallas)
+    def interpreted(*a, **kw):
+        flash_calls.append(1)
+        return interpreted.__wrapped__(*a, **dict(kw, interpret=True))
 
     def losses(flash: bool):
-        if flash:
-            monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "1")
-        else:
-            monkeypatch.delenv("PADDLE_TPU_FLASH_INTERPRET", raising=False)
-        paddle.seed(0)
-        cfg = llama_tiny_config(scan_layers=True, use_recompute=True,
-                                recompute_granularity="selective")
-        m = LlamaForCausalLM(cfg)
-        opt = optimizer.AdamW(learning_rate=1e-3,
-                              parameters=m.parameters())
-        step = jit.TrainStep(lambda i, l: m(i, labels=l)[1], opt)
-        rng = np.random.RandomState(0)
-        ids = paddle.to_tensor(rng.randint(0, cfg.vocab_size, (2, 64)))
-        lbl = paddle.to_tensor(rng.randint(0, cfg.vocab_size, (2, 64)))
-        return [float(step(ids, lbl)) for _ in range(3)]
+        with monkeypatch.context() as mp:
+            if flash:
+                mp.setattr(_pl, "on_tpu", lambda: True)
+                mp.setattr(fa, "flash_attention_pallas", interpreted)
+            paddle.seed(0)
+            cfg = llama_tiny_config(scan_layers=True, use_recompute=True,
+                                    recompute_granularity="selective")
+            m = LlamaForCausalLM(cfg)
+            opt = optimizer.AdamW(learning_rate=1e-3,
+                                  parameters=m.parameters())
+            step = jit.TrainStep(lambda i, l: m(i, labels=l)[1], opt)
+            rng = np.random.RandomState(0)
+            ids = paddle.to_tensor(rng.randint(0, cfg.vocab_size, (2, 64)))
+            lbl = paddle.to_tensor(rng.randint(0, cfg.vocab_size, (2, 64)))
+            return [float(step(ids, lbl)) for _ in range(3)]
 
     flash_losses = losses(True)
+    assert flash_calls, "the scan body never reached the flash kernel"
     dense_losses = losses(False)
     assert flash_losses[-1] < flash_losses[0]
     # flash vs dense attention are numerically close, not bit-equal

@@ -14,6 +14,21 @@ from paddle_tpu.distributed.launch import (Container, KVClient, KVServer,
                                            Pod, Watcher, launch)
 from paddle_tpu.distributed.fleet.elastic import ElasticManager, ElasticStatus
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_launcher_parent_takes_no_device():
+    """One process per chip: the launcher starts the trainers and must not
+    hold the chip they need. The package root imports jax, which takes
+    nothing; what takes the chip is starting a backend, and importing the
+    launcher must not do that."""
+    code = ("import paddle_tpu.distributed.launch.main\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
 
 def test_kv_server_roundtrip():
     server = KVServer().start()

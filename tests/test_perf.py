@@ -205,26 +205,60 @@ def test_bucketed_serving_matches_unbucketed():
         np.testing.assert_array_equal(a, b)
 
 
-# -- persistent cache env gate -----------------------------------------------
+# -- roofline model (tools/roofline.py) ---------------------------------------
+# (the persistent-cache placement test lives in tests/test_chip_smoke.py)
 
-def test_persistent_cache_env_gate(tmp_path, monkeypatch):
-    from paddle_tpu.perf import compile_cache as cc
+def test_roofline_model_runs_and_is_compute_bound():
+    """tools/roofline.py: pin the schema and the analytic conclusion —
+    every config is COMPUTE-bound on v5e with an MFU ceiling far above the
+    0.50 bar — so a sub-0.5 measurement indicts kernel/fusion efficiency,
+    not HBM bandwidth."""
+    import json
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable,
+                          os.path.join(repo, "tools", "roofline.py")],
+                         cwd=repo, capture_output=True, text=True,
+                         timeout=60)
+    assert out.returncode == 0, out.stderr
+    with open(os.path.join(repo, "ROOFLINE.json")) as f:
+        rec = json.load(f)
+    names = {c["config"] for c in rec["configs"]}
+    assert {"large", "medium", "small"} <= names
+    for c in rec["configs"]:
+        assert c["bound"] == "compute", c
+        assert c["measured_mfu_ceiling"] > 0.5, c
+        assert c["hbm_bytes"]["total"] > 0
 
-    monkeypatch.setattr(cc, "_PERSISTENT_STATE", None)
-    monkeypatch.setenv("PADDLE_COMPILE_CACHE", "")
-    assert cc.maybe_enable_persistent_cache() is False
-    monkeypatch.setattr(cc, "_PERSISTENT_STATE", None)
-    monkeypatch.setenv("PADDLE_COMPILE_CACHE", "0")
-    assert cc.maybe_enable_persistent_cache() is False
-    monkeypatch.setattr(cc, "_PERSISTENT_STATE", None)
-    monkeypatch.setenv("PADDLE_COMPILE_CACHE", str(tmp_path / "xla"))
-    assert cc.maybe_enable_persistent_cache() is True
-    import jax
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
-    # leave the process with the cache disabled again
-    monkeypatch.setattr(cc, "_PERSISTENT_STATE", None)
-    monkeypatch.setenv("PADDLE_COMPILE_CACHE", "")
-    assert cc.maybe_enable_persistent_cache() is False
+
+def test_roofline_large_config_mirrors_bench():
+    """tools/roofline.py hardcodes the bench dimensions; if bench.py is
+    retuned without updating the mirror, the roofline table silently
+    describes a config that no longer runs. bench.py runs one size, the
+    roofline's "large"."""
+    import importlib.util
+    import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(name, *path):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(repo, *path))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    bench = load("bench_mod", "bench.py")       # imports no jax
+    roof = load("roofline_mod", "tools", "roofline.py")
+    [large] = [c for c in roof.BENCH_CONFIGS if c[0] == "large"]
+    _name, V, H, I, L, heads, kvh, batch, seq, remat = large
+    m = bench.MODEL
+    assert (V, H, I, L, heads, kvh, batch, seq, remat) == (
+        m["vocab_size"], m["hidden_size"], m["intermediate_size"],
+        m["num_hidden_layers"], m["num_attention_heads"],
+        m["num_key_value_heads"], bench.BATCH, bench.SEQ, "selective"), (
+        "bench.py and tools/roofline.py disagree: update the mirror")
 
 
 # -- input pipeline ----------------------------------------------------------

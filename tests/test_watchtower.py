@@ -178,8 +178,15 @@ def test_trace_survives_chaos_failover_with_requeued_tag(lm):
 
 # -- roofline attribution -----------------------------------------------------
 
-def test_roofline_mfu_gap_after_jit_train_steps():
+def test_roofline_mfu_gap_after_jit_train_steps(monkeypatch):
     from paddle_tpu import hapi, nn, optimizer
+    # MFU is published on a TPU only; stand in for one so that the join
+    # against the roofline runs here (the net reaches no Pallas kernel)
+    monkeypatch.setattr("paddle_tpu.ops.pallas.on_tpu", lambda: True)
+    import sys
+    # (utils/__init__ rebinds the name `flops` to the function)
+    monkeypatch.setattr(sys.modules["paddle_tpu.utils.flops"],
+                        "peak_device_flops", lambda device=None: 1e12)
     paddle.seed(0)
     net = nn.Sequential(nn.Linear(16, 32), nn.ReLU(), nn.Linear(32, 4))
     m = hapi.Model(net)

@@ -5,7 +5,7 @@ Computes, from first principles (no hardware needed), where a training
 step's time must go on a v5e chip: MXU FLOPs, HBM traffic per step
 (weights fwd/bwd, optimizer-state update, saved activations, logits),
 the resulting compute/memory time bounds, and the measured-MFU ceiling
-those bounds imply. Next healthy window, compare `BENCH_TPU_SNAPSHOT`
+those bounds imply. After a chip run, compare `BENCH_TPU_SNAPSHOT`
 against `ROOFLINE.json`: measured step time ~ compute bound -> MXU-bound
 and healthy; >> bound -> the gap names the suspect (opt traffic,
 attention workspace, remat replay).
@@ -115,7 +115,9 @@ def _saved_bytes(H, I, L, tokens, remat):
 
 
 BENCH_CONFIGS = [
-    # mirrors bench.py main(): (V, H, I, L, heads, kvh, batch, seq, remat)
+    # (V, H, I, L, heads, kvh, batch, seq, remat). "large" mirrors bench.py
+    # (tests/test_perf.py pins it); "small" is the config of the one chip
+    # number on record (BENCH_TPU_SNAPSHOT.json) and "medium" lies between.
     ("large", 32000, 1536, 4096, 16, 12, 12, 4, 2048, "selective"),
     ("medium", 32000, 1152, 3072, 16, 9, 9, 4, 2048, "selective"),
     ("small", 32000, 1024, 2816, 24, 16, 16, 4, 1024, "off"),
