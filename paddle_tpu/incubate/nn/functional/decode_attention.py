@@ -487,17 +487,20 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
 
     seq_of, local, pos = _token_timeline(cu_q, dec, token_num)
 
+    # the scopes are metadata of the traced program: the trace's device
+    # events are put down to rope, scatter, gather and scores by them
     if rope_cos is not None:
-        cos_t = _arr(rope_cos)[pos].astype(jnp.float32)        # [T, D/2]
-        sin_t = _arr(rope_sin)[pos].astype(jnp.float32)
+        with jax.named_scope("qkv_rope"):
+            cos_t = _arr(rope_cos)[pos].astype(jnp.float32)    # [T, D/2]
+            sin_t = _arr(rope_sin)[pos].astype(jnp.float32)
 
-        def _rope(u):
-            uf = u.astype(jnp.float32)
-            u1, u2 = uf[..., 0::2], uf[..., 1::2]
-            c, s = cos_t[:, None, :], sin_t[:, None, :]
-            return jnp.stack([u1 * c - u2 * s, u2 * c + u1 * s],
-                             axis=-1).reshape(u.shape).astype(u.dtype)
-        qt, kt = _rope(qt), _rope(kt)
+            def _rope(u):
+                uf = u.astype(jnp.float32)
+                u1, u2 = uf[..., 0::2], uf[..., 1::2]
+                c, s = cos_t[:, None, :], sin_t[:, None, :]
+                return jnp.stack([u1 * c - u2 * s, u2 * c + u1 * s],
+                                 axis=-1).reshape(u.shape).astype(u.dtype)
+            qt, kt = _rope(qt), _rope(kt)
 
     new_scales = None
     if compute_dynamic_scales:
@@ -510,32 +513,37 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
                                              valid_mask)
         kq, vq, kdq, vdq = (new_scales["kq"], new_scales["vq"],
                             new_scales["kdq"], new_scales["vdq"])
-    kc, vc = _scatter_paged(kc, vc, bt, seq_of, pos, kt, vt, bs_,
-                            k_quant=kq, v_quant=vq)
-    kv_len = jnp.where(enc > 0, enc, dec + this)
-    gk, gv, s_kv = _gather_paged(kc, vc, bt, kvh, k_dequant=kdq,
-                                 v_dequant=vdq, out_dtype=qt.dtype)
-
-    # grouped scores: q regrouped [T, KV, rep, D] vs timeline [T, KV, S, D]
-    qg = qt.reshape(token_num, kvh, rep, hd).astype(jnp.float32)
-    scale = 1.0 / float(hd) ** 0.5
-    kv_pos = jnp.arange(s_kv)[None, None, None, :]
-    ok = (kv_pos <= pos[:, None, None, None]) \
-        & (kv_pos < kv_len[seq_of][:, None, None, None])
-    if bsz == 1:
-        # One sequence, which is how the batcher admits a prompt: every
-        # token attends the same timeline. Indexing it per token copies it
-        # T times when the op runs eagerly (10.7 GB for a 640-token prompt
-        # at 32 x 128 heads and a 2048-row slot — the chip ran out of
-        # memory); XLA folds that gather away only inside one jit program.
-        tk, tv, timeline = gk[0], gv[0], "gsd"
-    else:
-        tk, tv, timeline = gk[seq_of], gv[seq_of], "tgsd"
-    scores = jnp.einsum(f"tgrd,{timeline}->tgrs", qg,
-                        tk.astype(jnp.float32)) * scale
-    probs = jax.nn.softmax(jnp.where(ok, scores, _NEG), axis=-1)
-    out = jnp.einsum(f"tgrs,{timeline}->tgrd", probs,
-                     tv.astype(jnp.float32))
+    with jax.named_scope("paged_attention"):
+        with jax.named_scope("kv_scatter"):
+            kc, vc = _scatter_paged(kc, vc, bt, seq_of, pos, kt, vt, bs_,
+                                    k_quant=kq, v_quant=vq)
+        kv_len = jnp.where(enc > 0, enc, dec + this)
+        with jax.named_scope("kv_gather"):
+            gk, gv, s_kv = _gather_paged(kc, vc, bt, kvh, k_dequant=kdq,
+                                         v_dequant=vdq, out_dtype=qt.dtype)
+        with jax.named_scope("scores"):
+            # grouped scores: q regrouped [T, KV, rep, D] vs timeline
+            # [T, KV, S, D]
+            qg = qt.reshape(token_num, kvh, rep, hd).astype(jnp.float32)
+            scale = 1.0 / float(hd) ** 0.5
+            kv_pos = jnp.arange(s_kv)[None, None, None, :]
+            ok = (kv_pos <= pos[:, None, None, None]) \
+                & (kv_pos < kv_len[seq_of][:, None, None, None])
+            if bsz == 1:
+                # One sequence, which is how the batcher admits a prompt:
+                # every token attends the same timeline. Indexing it per
+                # token copies it T times when the op runs eagerly (10.7 GB
+                # for a 640-token prompt at 32 x 128 heads and a 2048-row
+                # slot — the chip ran out of memory); XLA folds that gather
+                # away only inside one jit program.
+                tk, tv, timeline = gk[0], gv[0], "gsd"
+            else:
+                tk, tv, timeline = gk[seq_of], gv[seq_of], "tgsd"
+            scores = jnp.einsum(f"tgrd,{timeline}->tgrs", qg,
+                                tk.astype(jnp.float32)) * scale
+            probs = jax.nn.softmax(jnp.where(ok, scores, _NEG), axis=-1)
+            out = jnp.einsum(f"tgrs,{timeline}->tgrd", probs,
+                             tv.astype(jnp.float32))
     result = (Tensor(out.reshape(token_num, nh * hd).astype(qt.dtype)),
               Tensor(kc), Tensor(vc))
     if new_scales is not None:

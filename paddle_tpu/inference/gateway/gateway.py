@@ -37,6 +37,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ...observability import trace_context as _trace
+from ...observability.tracing import span as _span
 from ...resilience.recovery import DeadlineExceeded, Overloaded
 from ...perf.buckets import resolve_ladder
 from .quota import TenantQuotas, TokenBucket
@@ -350,21 +351,24 @@ class Gateway:
         step every live replica (under the pool's retry/death policy),
         deliver new tokens, harvest finished requests. Returns the gids
         that finished during THIS call."""
-        self._expire_queued()
-        self._dispatch()
+        with _span("gateway.dispatch"):
+            self._expire_queued()
+            self._dispatch()
         for rep in list(self.pool.live()):
             if not rep.batcher._has_work():
                 continue
-            status, payload = self.pool.step_replica(rep)
+            with _span("gateway.replica_step", replica=rep.name):
+                status, payload = self.pool.step_replica(rep)
             if status == "dead":
                 self._last_death = payload
                 if isinstance(self.router, SessionAffinityPolicy):
                     self.router.forget_replica(rep.name)
                 self._requeue_from(rep)
-        finished = self._poll()
-        self._update_gauges()
-        from ...observability.fleet import autospool_tick
-        autospool_tick()   # rank-sharded metrics spool; no-op unarmed
+        with _span("gateway.poll"):
+            finished = self._poll()
+            self._update_gauges()
+            from ...observability.fleet import autospool_tick
+            autospool_tick()   # rank-sharded metrics spool; no-op unarmed
         return finished
 
     def _expire_queued(self):

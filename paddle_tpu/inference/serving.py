@@ -20,6 +20,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..observability.tracing import span as _span
+
 __all__ = ["ContinuousBatcher", "PagedContinuousBatcher", "Request"]
 
 
@@ -1853,107 +1855,112 @@ class PagedContinuousBatcher(_BatcherBase):
                 if matched:
                     self.prefix_cache.unpin(matched)
                 break
-            with self._intake:
-                self._pending.pop(0)
-            self._promo_denied.discard(req.rid)
-            slot = self._free_slots.pop(0)
-            if matched:
-                self._bt[slot, :len(matched)] = [n.page for n in matched]
-            if not self._alloc_pages(slot, upto):
-                raise RuntimeError("page accounting bug: admission gate "
-                                   "passed but allocation failed")
-            self._trace_admit_begin(req)
-            self._trace_prefill_begin(req)
-            bt_row = paddle.to_tensor(self._bt[slot:slot + 1])
-            S = L - m_rows
-            with paddle.no_grad():
-                if self.prefill_chunk:
-                    logits = self._prefill_chunked(ids_np[m_rows:], bt_row,
-                                                   slot, dec0=m_rows)
-                elif self.cache_quant:
-                    ids = paddle.to_tensor(ids_np[None, :])
-                    logits, self._state["layers"], seq_scales = \
-                        self.model.paged_prefill_into(
-                            ids, self._state["layers"], bt_row,
-                            self.block_size, dynamic_cache_scales=True)
-                    self._store_slot_scales(slot, seq_scales)
-                elif m_rows or self._prompt_ladder is not None:
-                    # suffix prefill: append S real tokens after the
-                    # m_rows cached rows, padded up to the resolved rung
-                    # (pad rows sit past the timeline — stale until
-                    # decode overwrites them, never read before that)
-                    pad_s = padded_len - m_rows
-                    if pad_s != S:
-                        self._count_pad_waste(pad_s, pad_s - S)
-                    sfx = np.zeros((pad_s,), np.int64)
-                    sfx[:S] = ids_np[m_rows:]
-                    logits, self._state["layers"] = \
-                        self.model.paged_prefill_into(
-                            paddle.to_tensor(sfx[None, :]),
-                            self._state["layers"], bt_row,
-                            self.block_size,
-                            dec_base=paddle.to_tensor(
-                                np.array([m_rows], np.int32)),
-                            logits_at=paddle.to_tensor(
-                                np.array([S - 1], np.int32)))
-                else:
-                    ids = paddle.to_tensor(ids_np[None, :])
-                    logits, self._state["layers"] = \
-                        self.model.paged_prefill_into(
-                            ids, self._state["layers"], bt_row,
-                            self.block_size)
-                if self.draft_model is not None:
-                    # mirror the suffix into the DRAFT pool (same block-
-                    # table row, its own physical pages); cached pages
-                    # already hold this prefix's draft rows — every page
-                    # enters the tree through an admission that wrote
-                    # both pools
-                    dfx = np.zeros((max(S, 1),), np.int64)
-                    dfx[:S] = ids_np[m_rows:]
-                    _dl, self._dstate["layers"] = \
-                        self.draft_model.paged_prefill_into(
-                            paddle.to_tensor(dfx[None, :]),
-                            self._dstate["layers"], bt_row,
-                            self.block_size,
-                            dec_base=paddle.to_tensor(
-                                np.array([m_rows], np.int32)),
-                            logits_at=paddle.to_tensor(
-                                np.array([0], np.int32)))
-                    self._ddec[slot] = L
-            if self.prefix_cache is not None:
-                self._prefix_hit_c.inc(m_rows)
-                self._prefix_miss_c.inc(S)
-                self.prefix_cache.hit_tokens += m_rows
-                self.prefix_cache.miss_tokens += S
-                self._tier_hit_c.labels(tier="device").inc(
-                    m_rows - promoted_rows)
-                for t in src_tiers:
-                    self._tier_hit_c.labels(tier=t).inc(self.block_size)
-                self.prefix_cache.host_hit_tokens += promoted_rows
-                new_nodes = self.prefix_cache.insert(
-                    ids_np, self._bt[slot], len(matched),
-                    L // self.block_size)
-                self._slot_nodes[slot] = list(matched) + new_nodes
-            end_tags = dict(prompt_tokens=len(ids_np), pages=need,
-                            prefix_hit=m_rows, padded_to=padded_len)
-            if promoted_rows:
-                # the ledger splits evicted_prefix_recompute pricing on
-                # this: a promoted resume repaid its eviction from the
-                # host tier, not by recomputing
-                end_tags["host_promoted"] = promoted_rows
-            self._trace_prefill_end(req, **end_tags)
-            tok = int(self._pick(np.asarray(logits._data))[0])
-            req.slot = slot
-            req.tokens.append(tok)
-            self._tele.on_admit()
-            self._tele.on_token(req)
-            self._slot_req[slot] = req
-            self._admit_order.append(slot)
-            self._dec[slot] = len(ids_np)
-            self._last_tok[slot] = tok
-            self._trace_admit_end(req, slot)
-            if self._maybe_finish(req, tok):
-                finished.append(req.rid)
+            with _span("serving.admit", rid=req.rid,
+                       prompt_tokens=len(ids_np), hit_tokens=m_rows):
+                with self._intake:
+                    self._pending.pop(0)
+                self._promo_denied.discard(req.rid)
+                slot = self._free_slots.pop(0)
+                if matched:
+                    self._bt[slot, :len(matched)] = [n.page for n in matched]
+                if not self._alloc_pages(slot, upto):
+                    raise RuntimeError("page accounting bug: admission gate "
+                                       "passed but allocation failed")
+                self._trace_admit_begin(req)
+                self._trace_prefill_begin(req)
+                bt_row = paddle.to_tensor(self._bt[slot:slot + 1])
+                S = L - m_rows
+                with paddle.no_grad():
+                    if self.prefill_chunk:
+                        logits = self._prefill_chunked(
+                            ids_np[m_rows:], bt_row, slot, dec0=m_rows,
+                            rid=req.rid)
+                    elif self.cache_quant:
+                        ids = paddle.to_tensor(ids_np[None, :])
+                        logits, self._state["layers"], seq_scales = \
+                            self.model.paged_prefill_into(
+                                ids, self._state["layers"], bt_row,
+                                self.block_size, dynamic_cache_scales=True)
+                        self._store_slot_scales(slot, seq_scales)
+                    elif m_rows or self._prompt_ladder is not None:
+                        # suffix prefill: append S real tokens after the
+                        # m_rows cached rows, padded up to the resolved rung
+                        # (pad rows sit past the timeline — stale until
+                        # decode overwrites them, never read before that)
+                        pad_s = padded_len - m_rows
+                        if pad_s != S:
+                            self._count_pad_waste(pad_s, pad_s - S)
+                        sfx = np.zeros((pad_s,), np.int64)
+                        sfx[:S] = ids_np[m_rows:]
+                        logits, self._state["layers"] = \
+                            self.model.paged_prefill_into(
+                                paddle.to_tensor(sfx[None, :]),
+                                self._state["layers"], bt_row,
+                                self.block_size,
+                                dec_base=paddle.to_tensor(
+                                    np.array([m_rows], np.int32)),
+                                logits_at=paddle.to_tensor(
+                                    np.array([S - 1], np.int32)))
+                    else:
+                        ids = paddle.to_tensor(ids_np[None, :])
+                        logits, self._state["layers"] = \
+                            self.model.paged_prefill_into(
+                                ids, self._state["layers"], bt_row,
+                                self.block_size)
+                    if self.draft_model is not None:
+                        # mirror the suffix into the DRAFT pool (same block-
+                        # table row, its own physical pages); cached pages
+                        # already hold this prefix's draft rows — every page
+                        # enters the tree through an admission that wrote
+                        # both pools
+                        dfx = np.zeros((max(S, 1),), np.int64)
+                        dfx[:S] = ids_np[m_rows:]
+                        _dl, self._dstate["layers"] = \
+                            self.draft_model.paged_prefill_into(
+                                paddle.to_tensor(dfx[None, :]),
+                                self._dstate["layers"], bt_row,
+                                self.block_size,
+                                dec_base=paddle.to_tensor(
+                                    np.array([m_rows], np.int32)),
+                                logits_at=paddle.to_tensor(
+                                    np.array([0], np.int32)))
+                        self._ddec[slot] = L
+                if self.prefix_cache is not None:
+                    self._prefix_hit_c.inc(m_rows)
+                    self._prefix_miss_c.inc(S)
+                    self.prefix_cache.hit_tokens += m_rows
+                    self.prefix_cache.miss_tokens += S
+                    self._tier_hit_c.labels(tier="device").inc(
+                        m_rows - promoted_rows)
+                    for t in src_tiers:
+                        self._tier_hit_c.labels(tier=t).inc(self.block_size)
+                    self.prefix_cache.host_hit_tokens += promoted_rows
+                    new_nodes = self.prefix_cache.insert(
+                        ids_np, self._bt[slot], len(matched),
+                        L // self.block_size)
+                    self._slot_nodes[slot] = list(matched) + new_nodes
+                end_tags = dict(prompt_tokens=len(ids_np), pages=need,
+                                prefix_hit=m_rows, padded_to=padded_len)
+                if promoted_rows:
+                    # the ledger splits evicted_prefix_recompute pricing on
+                    # this: a promoted resume repaid its eviction from the
+                    # host tier, not by recomputing
+                    end_tags["host_promoted"] = promoted_rows
+                self._trace_prefill_end(req, **end_tags)
+                with _span("serving.fetch"):
+                    logits_np = np.asarray(logits._data)
+                tok = int(self._pick(logits_np)[0])
+                req.slot = slot
+                req.tokens.append(tok)
+                self._tele.on_admit()
+                self._tele.on_token(req)
+                self._slot_req[slot] = req
+                self._admit_order.append(slot)
+                self._dec[slot] = len(ids_np)
+                self._last_tok[slot] = tok
+                self._trace_admit_end(req, slot)
+                if self._maybe_finish(req, tok):
+                    finished.append(req.rid)
         return finished
 
     def _count_pad_waste(self, rung: int, waste: int):
@@ -1963,7 +1970,8 @@ class PagedContinuousBatcher(_BatcherBase):
             "pad tokens admission added to reach the prompt bucket",
             labelnames=("rung",)).labels(rung=str(rung)).inc(waste)
 
-    def _prefill_chunked(self, ids_np, bt_row, slot, dec0: int = 0):
+    def _prefill_chunked(self, ids_np, bt_row, slot, dec0: int = 0,
+                         rid: int = -1):
         """Feed the prompt through fixed-width append chunks (ONE compiled
         executable for every prompt length). The tail chunk is zero-padded;
         pad rows land past the true timeline and are overwritten by decode
@@ -1985,6 +1993,7 @@ class PagedContinuousBatcher(_BatcherBase):
         ``dec0``: cached-prefix offset — ``ids_np`` is the SUFFIX and the
         chunks append after ``dec0`` existing rows (prefix-cache hits;
         always 0 on the quantized path, which is gated off prefix reuse).
+        ``rid`` tags each chunk's ``serving.prefill_chunk`` span.
         """
         import paddle_tpu as paddle
         C = self.prefill_chunk
@@ -2002,26 +2011,27 @@ class PagedContinuousBatcher(_BatcherBase):
             w = min(C, padded_len - dec)     # tail shortens at capacity
             has_last = 0 <= (L - 1) - dec < w
             at = (L - 1) - dec if has_last else 0
-            ids_t = paddle.to_tensor(padded[None, dec:dec + w])
-            dec_t = paddle.to_tensor(np.array([dec0 + dec], np.int32))
-            at_t = paddle.to_tensor(np.array([at], np.int32))
-            if not self.cache_quant:
-                lg, self._state["layers"] = self._chunk_fn(
-                    ids_t, self._state["layers"], bt_row, dec_t, at_t)
-            elif scales is None:
-                first_nvalid = min(L - dec, w)
-                nvalid = paddle.to_tensor(
-                    np.array([first_nvalid], np.int32))
-                lg, self._state["layers"], scales = \
-                    self._chunk_dyn_first_fn(
-                        ids_t, self._state["layers"], bt_row, dec_t,
-                        at_t, nvalid)
-            else:
-                lg, self._state["layers"] = self._chunk_dyn_rest_fn(
-                    ids_t, self._state["layers"], bt_row, dec_t, at_t,
-                    scales)
-                if L - dec > 0:
-                    last_rest = (dec, min(L - dec, w))
+            with _span("serving.prefill_chunk", rid=rid):
+                ids_t = paddle.to_tensor(padded[None, dec:dec + w])
+                dec_t = paddle.to_tensor(np.array([dec0 + dec], np.int32))
+                at_t = paddle.to_tensor(np.array([at], np.int32))
+                if not self.cache_quant:
+                    lg, self._state["layers"] = self._chunk_fn(
+                        ids_t, self._state["layers"], bt_row, dec_t, at_t)
+                elif scales is None:
+                    first_nvalid = min(L - dec, w)
+                    nvalid = paddle.to_tensor(
+                        np.array([first_nvalid], np.int32))
+                    lg, self._state["layers"], scales = \
+                        self._chunk_dyn_first_fn(
+                            ids_t, self._state["layers"], bt_row, dec_t,
+                            at_t, nvalid)
+                else:
+                    lg, self._state["layers"] = self._chunk_dyn_rest_fn(
+                        ids_t, self._state["layers"], bt_row, dec_t, at_t,
+                        scales)
+                    if L - dec > 0:
+                        last_rest = (dec, min(L - dec, w))
             if has_last:
                 # the final chunk always contains position L-1 (its start
                 # k*C < L by the ceil-padding construction)
@@ -2104,20 +2114,21 @@ class PagedContinuousBatcher(_BatcherBase):
 
     def _sync_tables(self):
         import paddle_tpu as paddle
-        self._state["block_tables"] = paddle.to_tensor(self._bt)
-        self._state["dec_lens"] = paddle.to_tensor(self._dec)
-        # a compiled step returns the pass-through python ints as 0-d
-        # arrays; restore them so the NEXT call's signature (and its
-        # executable) stays identical
-        self._state["block_size"] = self.block_size
-        self._state["capacity"] = self.blocks_per_seq * self.block_size
-        if self.cache_quant and self._scales_dirty:
-            # scales change only at admit/release — skip the L x 4
-            # re-uploads on the steady-state decode path
-            self._state["cache_scales"] = [
-                {k: paddle.to_tensor(layer[k]) for k in layer}
-                for layer in self._scales_np]
-            self._scales_dirty = False
+        with _span("serving.sync_tables"):
+            self._state["block_tables"] = paddle.to_tensor(self._bt)
+            self._state["dec_lens"] = paddle.to_tensor(self._dec)
+            # a compiled step returns the pass-through python ints as 0-d
+            # arrays; restore them so the NEXT call's signature (and its
+            # executable) stays identical
+            self._state["block_size"] = self.block_size
+            self._state["capacity"] = self.blocks_per_seq * self.block_size
+            if self.cache_quant and self._scales_dirty:
+                # scales change only at admit/release — skip the L x 4
+                # re-uploads on the steady-state decode path
+                self._state["cache_scales"] = [
+                    {k: paddle.to_tensor(layer[k]) for k in layer}
+                    for layer in self._scales_np]
+                self._scales_dirty = False
 
     def _preempt_latest(self, protect: int) -> bool:
         """Evict the most-recently admitted active request (≠ protect) back
@@ -2140,27 +2151,28 @@ class PagedContinuousBatcher(_BatcherBase):
         """ondemand: every active slot is about to write kv row dec[slot];
         back it with a page, preempting (slots, then any in-flight fused
         admission) if the pool is dry."""
-        for slot in list(self._admit_order):
-            if slot not in self._slot_req:
-                continue
-            while not self._alloc_pages(slot, int(self._dec[slot]) + 1):
-                if self._promo is not None:
-                    # an in-flight promotion loses the race to live
-                    # decode: reclaim its reserved pages before touching
-                    # any live request (its admission full-prefills)
-                    self._cancel_promotion(deny=True)
+        with _span("serving.grow"):
+            for slot in list(self._admit_order):
+                if slot not in self._slot_req:
                     continue
-                if self._preempt_latest(protect=slot):
-                    continue
-                if self._admitting is not None:
-                    # the admission's detached row holds pages too —
-                    # evict it rather than failing a live decode
-                    self._abort_admission()
-                    continue
-                raise RuntimeError(
-                    f"page pool exhausted: slot {slot} needs a page at "
-                    f"row {int(self._dec[slot])}, no free pages and no "
-                    f"other request to preempt (n_pages={self.n_pages})")
+                while not self._alloc_pages(slot, int(self._dec[slot]) + 1):
+                    if self._promo is not None:
+                        # an in-flight promotion loses the race to live
+                        # decode: reclaim its reserved pages before touching
+                        # any live request (its admission full-prefills)
+                        self._cancel_promotion(deny=True)
+                        continue
+                    if self._preempt_latest(protect=slot):
+                        continue
+                    if self._admitting is not None:
+                        # the admission's detached row holds pages too —
+                        # evict it rather than failing a live decode
+                        self._abort_admission()
+                        continue
+                    raise RuntimeError(
+                        f"page pool exhausted: slot {slot} needs a page at "
+                        f"row {int(self._dec[slot])}, no free pages and no "
+                        f"other request to preempt (n_pages={self.n_pages})")
 
     # -- fused admission (vLLM unified scheduling) --------------------------
     def _has_work(self) -> bool:
@@ -2265,7 +2277,9 @@ class PagedContinuousBatcher(_BatcherBase):
             return
         req, slot = adm["req"], adm["slot"]
         self._trace_prefill_end(req, prompt_tokens=L, fused=1)
-        tok = int(self._pick(np.asarray(chunk_logits._data))[0])
+        with _span("serving.fetch"):
+            chunk_np = np.asarray(chunk_logits._data)
+        tok = int(self._pick(chunk_np)[0])
         self._bt[slot] = adm["row"]
         self._dec[slot] = L
         self._last_tok[slot] = tok
@@ -2295,9 +2309,9 @@ class PagedContinuousBatcher(_BatcherBase):
         self._step_prologue()
         n_active = len(self._slot_req)
         t0 = _time.perf_counter()
-        tok_t = paddle.to_tensor(self._last_tok)
-        ids_t, row_t, dec_t, at_t = self._fused_chunk_inputs()
-        with paddle.no_grad():
+        with _span("serving.launch"), paddle.no_grad():
+            tok_t = paddle.to_tensor(self._last_tok)
+            ids_t, row_t, dec_t, at_t = self._fused_chunk_inputs()
             dec_logits, chunk_logits, self._state = self._fused_fn(
                 tok_t, ids_t, row_t, dec_t, at_t, self._state)
         self._advance_decoders(dec_logits, finished)
@@ -2309,15 +2323,19 @@ class PagedContinuousBatcher(_BatcherBase):
     def _advance_decoders(self, logits, finished: List[int]):
         """Consume a step's decode logits: advance timelines, append the
         picked tokens, evict finished slots."""
-        self._dec += np.asarray(self._slot_active_mask(), np.int32)
-        next_tok = self._pick(np.asarray(logits._data))
-        for slot, req in list(self._slot_req.items()):
-            tok = int(next_tok[slot])
-            req.tokens.append(tok)
-            self._tele.on_token(req)
-            self._last_tok[slot] = tok
-            if self._maybe_finish(req, tok):
-                finished.append(req.rid)
+        with _span("serving.fetch"):
+            # the host waits here for the device, then copies [B, V]
+            logits_np = np.asarray(logits._data)
+        with _span("serving.pick"):
+            self._dec += np.asarray(self._slot_active_mask(), np.int32)
+            next_tok = self._pick(logits_np)
+            for slot, req in list(self._slot_req.items()):
+                tok = int(next_tok[slot])
+                req.tokens.append(tok)
+                self._tele.on_token(req)
+                self._last_tok[slot] = tok
+                if self._maybe_finish(req, tok):
+                    finished.append(req.rid)
 
     def _step_prologue(self):
         """Shared pre-decode bookkeeping: on-demand page growth, step
@@ -2350,8 +2368,8 @@ class PagedContinuousBatcher(_BatcherBase):
         self._step_prologue()
         n_active = len(self._slot_req)
         t0 = _time.perf_counter()
-        tok_t = paddle.to_tensor(self._last_tok)
-        with paddle.no_grad():
+        with _span("serving.launch"), paddle.no_grad():
+            tok_t = paddle.to_tensor(self._last_tok)
             logits, self._state = self._step_fn(tok_t, self._state)
         self._advance_decoders(logits, finished)
         self._tele.on_decode_time(_time.perf_counter() - t0,
@@ -2411,26 +2429,29 @@ class PagedContinuousBatcher(_BatcherBase):
         self._sync_tables()
         n_active = len(self._slot_req)
         t0 = _time.perf_counter()
-        tok_t = paddle.to_tensor(self._last_tok)
-        with paddle.no_grad():
+        with _span("serving.launch"), paddle.no_grad():
+            tok_t = paddle.to_tensor(self._last_tok)
             toks, self._state = self._block_fn(tok_t, self._state)
-        toks_np = np.asarray(toks._data)                  # [K, B]
+        with _span("serving.fetch"):
+            toks_np = np.asarray(toks._data)              # [K, B]
         self._tele.on_decode_time(_time.perf_counter() - t0, K,
                                   tokens=K * n_active)
-        # survivors consumed all K rows; evicted slots' counters are
-        # reset at their next admission
-        self._dec += K * np.asarray(self._slot_active_mask(), np.int32)
-        for k in range(K):
-            # occupancy at each sub-step's ENTRY (post prior evictions),
-            # matching the per-step path's _step_prologue accounting
-            self._tele.on_occupancy(len(self._slot_req))
-            for slot, req in list(self._slot_req.items()):
-                tok = int(toks_np[k, slot])
-                req.tokens.append(tok)
-                self._tele.on_token(req)
-                self._last_tok[slot] = tok
-                if self._maybe_finish(req, tok):
-                    finished.append(req.rid)
+        with _span("serving.pick"):
+            # survivors consumed all K rows; evicted slots' counters are
+            # reset at their next admission
+            self._dec += K * np.asarray(self._slot_active_mask(), np.int32)
+            for k in range(K):
+                # occupancy at each sub-step's ENTRY (post prior
+                # evictions), matching the per-step path's
+                # _step_prologue accounting
+                self._tele.on_occupancy(len(self._slot_req))
+                for slot, req in list(self._slot_req.items()):
+                    tok = int(toks_np[k, slot])
+                    req.tokens.append(tok)
+                    self._tele.on_token(req)
+                    self._last_tok[slot] = tok
+                    if self._maybe_finish(req, tok):
+                        finished.append(req.rid)
 
     # -- in-batcher speculative decoding ------------------------------------
     def _sync_draft_tables(self):
@@ -2442,7 +2463,9 @@ class PagedContinuousBatcher(_BatcherBase):
 
     @staticmethod
     def _argmax_b(logits) -> np.ndarray:
-        return np.asarray(logits._data).argmax(-1)
+        with _span("serving.fetch"):
+            logits_np = np.asarray(logits._data)
+        return logits_np.argmax(-1)
 
     def _speculative_tail(self, finished: List[int]) -> bool:
         """One batched draft/verify round for every active slot; returns
@@ -2514,18 +2537,21 @@ class PagedContinuousBatcher(_BatcherBase):
             dbase[slot] = lo
         with paddle.no_grad():
             self._sync_draft_tables()
-            dl, self._dstate["layers"] = self._catchup_fn(
-                paddle.to_tensor(cu_ids), self._dstate["layers"],
-                self._dstate["block_tables"], paddle.to_tensor(dbase),
-                paddle.to_tensor(cu_at))
+            with _span("serving.launch"):
+                dl, self._dstate["layers"] = self._catchup_fn(
+                    paddle.to_tensor(cu_ids), self._dstate["layers"],
+                    self._dstate["block_tables"], paddle.to_tensor(dbase),
+                    paddle.to_tensor(cu_at))
             props = [self._argmax_b(dl)]        # [B] proposal 1
             for slot, _ in reqs:
                 self._ddec[slot] = int(self._dec[slot]) + 1
             self._dstate["dec_lens"] = paddle.to_tensor(self._ddec)
             tok = props[0]
             for _ in range(k - 1):
-                dlg, self._dstate = self._dstep_fn(
-                    paddle.to_tensor(tok.astype(np.int64)), self._dstate)
+                with _span("serving.launch"):
+                    dlg, self._dstate = self._dstep_fn(
+                        paddle.to_tensor(tok.astype(np.int64)),
+                        self._dstate)
                 tok = self._argmax_b(dlg)
                 props.append(tok)
             ids_v = np.zeros((B, k + 1), np.int64)
@@ -2533,11 +2559,24 @@ class PagedContinuousBatcher(_BatcherBase):
                 ids_v[slot, 0] = self._last_tok[slot]
                 for i in range(k):
                     ids_v[slot, 1 + i] = props[i][slot]
-            vlogits, self._state["layers"] = self._verify_fn(
-                paddle.to_tensor(ids_v), self._state["layers"],
-                self._state["block_tables"],
-                paddle.to_tensor(self._dec.copy()))
-        g = np.asarray(vlogits._data).argmax(-1)          # [B, k+1]
+            with _span("serving.launch"):
+                vlogits, self._state["layers"] = self._verify_fn(
+                    paddle.to_tensor(ids_v), self._state["layers"],
+                    self._state["block_tables"],
+                    paddle.to_tensor(self._dec.copy()))
+        g = self._argmax_b(vlogits)                       # [B, k+1]
+        with _span("serving.pick"):
+            total = self._accept_round(reqs, props, g, k, finished)
+        self.spec_stats["rounds"] += 1
+        self._tele.on_decode_time(_time.perf_counter() - t0,
+                                  tokens=total)
+        return True
+
+    def _accept_round(self, reqs, props, g, k: int,
+                      finished: List[int]) -> int:
+        """Each slot accepts its longest matching prefix of the draft's
+        proposals plus the target's own correction; returns the tokens
+        appended over all slots."""
         total = 0
         for slot, req in reqs:
             pv = [int(props[i][slot]) for i in range(k)]
@@ -2570,10 +2609,7 @@ class PagedContinuousBatcher(_BatcherBase):
                 total += 1
                 if self._maybe_finish(req, int(t)):
                     finished.append(req.rid)
-        self.spec_stats["rounds"] += 1
-        self._tele.on_decode_time(_time.perf_counter() - t0,
-                                  tokens=total)
-        return True
+        return total
 
     # -- the engine ---------------------------------------------------------
     def _step_impl(self) -> List[int]:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -27,6 +28,7 @@ from ..autograd import engine as _engine
 from ..core import capture as _capture
 from ..core import random as _random
 from ..core.tensor import Tensor
+from ..observability.tracing import span as _span
 from ..ops import pallas as _pallas
 from ..optimizer.clip import ClipGradByGlobalNorm
 from ..perf import compile_cache as _cc
@@ -44,6 +46,15 @@ def enable_to_static(flag: bool):
 
 def _is_tensor(x):
     return isinstance(x, Tensor)
+
+
+def _named(pure, label: str):
+    """Give the traced function its label (dots to underscores) before
+    ``jax.jit`` sees it, so that the device trace's module line and the
+    optimized HLO read ``jit_<label>`` and not ``jit_pure`` for every
+    executable the program compiles."""
+    pure.__name__ = pure.__qualname__ = re.sub(r"\W", "_", label)
+    return pure
 
 
 def _sig_of(args, kwargs):
@@ -370,7 +381,8 @@ class StaticFunction:
                         f"{len(args)} positional arguments")
                 donate_leaves.extend(range(*ranges[i]))
         donate = tuple(3 + j for j in donate_leaves)
-        compiled = jax.jit(pure, donate_argnums=donate)
+        compiled = jax.jit(_named(pure, self._label()),
+                           donate_argnums=donate)
         entry = {"compiled": compiled, "state": state, "mutated": mutated,
                  "grad_ts": grad_ts, "rng_used": rng_used, "first_out": out,
                  "treedef": treedef, "tensor_pos": self._tensor_pos,
@@ -433,6 +445,13 @@ class StaticFunction:
             entry.pop("compiled", None)  # free the trace
             return self._fn(*args, **kwargs)
 
+    def _label(self) -> str:
+        """What names this function's executables: in the opprof
+        observatory as it stands, in the device trace with dots as
+        underscores (``jit_serving_paged_decode``)."""
+        return (getattr(self, "_opprof_label", None)
+                or f"static.{getattr(self._fn, '__name__', 'fn')}")
+
     def _maybe_opprof(self, entry, args, kwargs):
         """Op-level cost capture of this signature's executable (opprof
         observatory). Free unless ``observability.opprof`` is enabled;
@@ -443,8 +462,7 @@ class StaticFunction:
                 or "compiled" not in entry):
             return
         entry["opprof_done"] = True
-        label = (getattr(self, "_opprof_label", None)
-                 or f"static.{self._fn.__name__}")
+        label = self._label()
         try:
             gen = _random.default_generator()
             flat = jax.tree_util.tree_flatten(
@@ -799,6 +817,7 @@ class TrainStep:
         # replaced after the step, so XLA reuses their HBM in place (halves
         # steady-state memory for the update).
         donate_argnums = (0, 1, 2) if self._donate else ()
+        _named(pure, self._opprof_label)
         if mesh_plan is None:
             compiled = jax.jit(pure, donate_argnums=donate_argnums)
         else:
@@ -940,26 +959,31 @@ class TrainStep:
         gen = _random.default_generator()
         params = entry["params"]
         use_master = entry["use_master"]
-        (loss, new_p, new_masters, new_states, new_extra, new_other_grads,
-         new_key) = entry["compiled"](*self._assemble(entry, args))
-        for p, a in zip(params, new_p):
-            p._data = a
-        for p, um, m in zip(params, use_master, new_masters):
-            if um:
-                opt._master_weights[id(p)] = m
-        for p, st in zip(params, new_states):
-            for name, v in st.items():
-                # ZeRO-offload hook: fresh state buffers return to their
-                # sharded host residence (identity when offload is off)
-                opt._accumulators[name][id(p)] = \
-                    opt._restore_state_placement(v)
-        for t, a in zip(entry["extra_mut"], new_extra):
-            t._data = a
-        for t, g in zip(entry["other_grad_ts"], new_other_grads):
-            t._grad = None if g is None else Tensor(g)
-        if entry["rng_used"]:
-            gen.set_state(new_key)
-        opt._step_count += 1
+        with _span("trainstep.assemble"):
+            call = self._assemble(entry, args)
+        with _span("trainstep.launch"):     # returns when dispatched
+            (loss, new_p, new_masters, new_states, new_extra,
+             new_other_grads, new_key) = entry["compiled"](*call)
+        with _span("trainstep.writeback"):
+            for p, a in zip(params, new_p):
+                p._data = a
+            for p, um, m in zip(params, use_master, new_masters):
+                if um:
+                    opt._master_weights[id(p)] = m
+            for p, st in zip(params, new_states):
+                for name, v in st.items():
+                    # ZeRO-offload hook: fresh state buffers return to
+                    # their sharded host residence (identity when
+                    # offload is off)
+                    opt._accumulators[name][id(p)] = \
+                        opt._restore_state_placement(v)
+            for t, a in zip(entry["extra_mut"], new_extra):
+                t._data = a
+            for t, g in zip(entry["other_grad_ts"], new_other_grads):
+                t._grad = None if g is None else Tensor(g)
+            if entry["rng_used"]:
+                gen.set_state(new_key)
+            opt._step_count += 1
         return Tensor(loss)
 
 

@@ -996,15 +996,20 @@ class LlamaForCausalLM(Layer):
         model = self.model
         cos_tab, sin_tab = model._cos, model._sin
 
-        hidden = model.embed_tokens(input_ids)         # [B, s, E]
+        # the scopes are metadata of the traced program: a device event of
+        # the trace is put down to a phase of the model by them
+        with jax.named_scope("embed"):
+            hidden = model.embed_tokens(input_ids)     # [B, s, E]
         layers_state = []
         scales_out = [] if dynamic_cache_scales else None
         for li, (layer, (kc, vc)) in enumerate(zip(model.layers, layers)):
             attn = layer.self_attn
-            x = layer.input_layernorm(hidden)
-            q = attn.q_proj(x).reshape([b * s, h, d])
-            k = attn.k_proj(x).reshape([b * s, kvh, d])
-            v = attn.v_proj(x).reshape([b * s, kvh, d])
+            with jax.named_scope("attn_norm"):
+                x = layer.input_layernorm(hidden)
+            with jax.named_scope("qkv_rope"):
+                q = attn.q_proj(x).reshape([b * s, h, d])
+                k = attn.k_proj(x).reshape([b * s, kvh, d])
+                v = attn.v_proj(x).reshape([b * s, kvh, d])
             if dynamic_cache_scales:
                 extra = dict(use_dynamic_cachekv_quant=True,
                              compute_dynamic_scales=True,
@@ -1023,24 +1028,29 @@ class LlamaForCausalLM(Layer):
                                    "kdq": kdq, "vdq": vdq})
             else:
                 out, kc, vc = res
-            hidden = hidden + attn.o_proj(out.reshape([b, s, h * d]))
-            hidden = hidden + layer.mlp(
-                layer.post_attention_layernorm(hidden))
+            with jax.named_scope("o_proj"):
+                hidden = hidden + attn.o_proj(out.reshape([b, s, h * d]))
+            with jax.named_scope("mlp_norm"):
+                x = layer.post_attention_layernorm(hidden)
+            with jax.named_scope("mlp"):
+                hidden = hidden + layer.mlp(x)
             layers_state.append((kc, vc))
-        hidden = model.norm(hidden)
-        if logits_all:
-            # speculative verify: score every appended position in one
-            # pass (s = draft_k + 1)
-            logits = self._lm_logits(hidden)             # [b, s, V]
-        elif logits_at is not None:
-            # chunked prefill: project ONLY the requested position (the
-            # lm head over all C positions would be C x the needed FLOPs)
-            oh = F.one_hot(logits_at.reshape([b]).astype("int64"),
-                           s).astype(hidden.dtype)
-            logits = self._lm_logits(paddle.einsum("bs,bse->be", oh,
-                                                   hidden))
-        else:
-            logits = self._lm_logits(hidden[:, s - 1])
+        with jax.named_scope("head"):
+            hidden = model.norm(hidden)
+            if logits_all:
+                # speculative verify: score every appended position in one
+                # pass (s = draft_k + 1)
+                logits = self._lm_logits(hidden)             # [b, s, V]
+            elif logits_at is not None:
+                # chunked prefill: project ONLY the requested position (the
+                # lm head over all C positions would be C x the needed
+                # FLOPs)
+                oh = F.one_hot(logits_at.reshape([b]).astype("int64"),
+                               s).astype(hidden.dtype)
+                logits = self._lm_logits(paddle.einsum("bs,bse->be", oh,
+                                                       hidden))
+            else:
+                logits = self._lm_logits(hidden[:, s - 1])
         if dynamic_cache_scales:
             return logits, layers_state, scales_out
         return logits, layers_state
@@ -1080,16 +1090,19 @@ class LlamaForCausalLM(Layer):
         model = self.model
         cos_tab, sin_tab = model._cos, model._sin
 
-        hidden = model.embed_tokens(tok.reshape([b, 1]))   # [B, 1, E]
+        with jax.named_scope("embed"):
+            hidden = model.embed_tokens(tok.reshape([b, 1]))   # [B, 1, E]
         dyn = state.get("cache_scales")
         new_layers = []
         for li, (layer, (kc, vc)) in enumerate(zip(model.layers,
                                                    state["layers"])):
             attn = layer.self_attn
-            x = layer.input_layernorm(hidden)
-            q = attn.q_proj(x).reshape([b, h, d])
-            k = attn.k_proj(x).reshape([b, kvh, d])
-            v = attn.v_proj(x).reshape([b, kvh, d])
+            with jax.named_scope("attn_norm"):
+                x = layer.input_layernorm(hidden)
+            with jax.named_scope("qkv_rope"):
+                q = attn.q_proj(x).reshape([b, h, d])
+                k = attn.k_proj(x).reshape([b, kvh, d])
+                v = attn.v_proj(x).reshape([b, kvh, d])
             if dyn is not None:
                 # dynamic cachekv int8: per-(slot, head) scales ride the
                 # state, fixed by each sequence's prefill
@@ -1103,12 +1116,16 @@ class LlamaForCausalLM(Layer):
                 q, k, v, kc, vc, enc, t, this, cu_q, bt,
                 block_size=state["block_size"], rope_cos=Tensor(cos_tab),
                 rope_sin=Tensor(sin_tab), **kwargs)
-            hidden = hidden + attn.o_proj(out.reshape([b, 1, h * d]))
-            hidden = hidden + layer.mlp(
-                layer.post_attention_layernorm(hidden))
+            with jax.named_scope("o_proj"):
+                hidden = hidden + attn.o_proj(out.reshape([b, 1, h * d]))
+            with jax.named_scope("mlp_norm"):
+                x = layer.post_attention_layernorm(hidden)
+            with jax.named_scope("mlp"):
+                hidden = hidden + layer.mlp(x)
             new_layers.append((kc, vc))
-        hidden = model.norm(hidden)
-        logits = self._lm_logits(hidden[:, 0])             # [B, V]
+        with jax.named_scope("head"):
+            hidden = model.norm(hidden)
+            logits = self._lm_logits(hidden[:, 0])         # [B, V]
         new_state = dict(state, layers=new_layers, dec_lens=t + 1)
         return logits, new_state
 

@@ -5,8 +5,12 @@ platform/monitor.h STATS_INT + the host profiler, fused):
 
   * ``metrics`` — process-wide Counter / Gauge / Histogram registry with
     labeled series; counters ride the C++ stat tier when available.
-  * ``tracing`` — nested, context-propagated spans that feed BOTH the
-    profiler's chrome-trace recorder and span-duration histograms.
+  * ``tracing`` — nested, context-propagated spans that feed the
+    profiler's chrome-trace recorder, span-duration histograms and, as
+    ``jax.profiler.TraceAnnotation``s, the JAX profiler's trace: the
+    phase spans of the serving and train steps lie there on the clock of
+    the device events (read one by hand with
+    ``python -m chipbench.phases <trace>``).
   * ``export`` — Prometheus text format + JSONL snapshots
     (``tools/telemetry_dump.py`` is the CLI over these).
   * ``fleet`` — rank-sharded telemetry spools under
@@ -23,7 +27,7 @@ platform/monitor.h STATS_INT + the host profiler, fused):
   * ``opprof`` — compiled-program cost profiles: per-op/per-fusion
     FLOPs and bytes parsed from the optimized HLO of every warm
     executable (TrainStep, serving prefill/decode), a shared op-class
-    taxonomy (also used by ``tools/analyze_xplane.py``), per-op-class
+    taxonomy, per-op-class
     MFU-gap attribution, and ``OPPROF_r*.json`` artifacts with a
     ``diff()`` that names recompiles and fusion regressions
     (``tools/profile_report.py`` is the CLI; the bench_guard

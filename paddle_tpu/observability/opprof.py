@@ -16,8 +16,8 @@ Three layers, all deterministic on the CPU backend:
         matmul | attention | collective | elementwise | reduce |
         data-movement | other
 
-    ``tools/analyze_xplane.py`` imports THIS module, so real-TPU xplane
-    captures and CPU cost-model profiles report identical buckets.
+    A device trace is read by hand with ``python -m chipbench.phases
+    <trace>``, which names its rows by executable and model scope.
 
   * **Capture** — ``maybe_capture(label, jitted, args)`` AOT-lowers an
     already-built ``jax.jit`` callable at its live argument tuple,
@@ -128,19 +128,13 @@ _QUANT_HINTS = ("cachekv-quant", "cachekv-dequant", "weight-dequant",
                 "quantize", "dequant")
 
 
-def canon_op(name: str, fold: bool = True) -> str:
+def canon_op(name: str) -> str:
     """Collapse op instances to a stable identity: ``fusion.123`` ->
-    ``fusion``, trailing HLO ids dropped; ``fold=True`` additionally
-    folds ``_`` to ``-`` (HLO opcode spelling) for set lookups.
-
-    Shared with ``tools/analyze_xplane.py`` (which passes
-    ``fold=False`` to keep its historical PROFILES_SUMMARY.json key
-    spelling) so xplane trace names and HLO instruction names collapse
-    by ONE rule."""
+    ``fusion``, trailing HLO ids dropped, ``_`` folded to ``-`` (HLO
+    opcode spelling) for set lookups."""
     name = re.sub(r"\.\d+$", "", name)
     name = re.sub(r"\d+$", "", name) or name
-    name = name.strip()
-    return name.replace("_", "-") if fold else name
+    return name.strip().replace("_", "-")
 
 
 def classify_op(name: str, path: str = "") -> str:
@@ -453,8 +447,10 @@ def profile_hlo_text(text: str, label: str = "",
     ops = sorted(rows.values(),
                  key=lambda r: (-r["flops"], -r["bytes"], r["op"]))
     fingerprint = hashlib.sha1(text.encode()).hexdigest()[:16]
+    op_paths = {ins.name: ins.path for instrs in comps.values()
+                for ins in instrs if ins.path}
     return OpProfile(label=label, fingerprint=fingerprint, ops=ops,
-                     xla_totals=dict(xla_totals or {}))
+                     xla_totals=dict(xla_totals or {}), op_paths=op_paths)
 
 
 # ---------------------------------------------------------------------------
@@ -477,14 +473,19 @@ def _peaks() -> Tuple[float, float]:
 
 
 class OpProfile:
-    """Per-op cost profile of ONE compiled executable."""
+    """Per-op cost profile of ONE compiled executable. ``op_paths`` maps
+    an instruction's name, which is what a device trace calls its events,
+    to the ``op_name`` of its metadata (``jit(serving_paged_decode)/
+    paged_attention/kv_gather/gather``): the model's scopes of an event."""
 
     def __init__(self, label: str, fingerprint: str, ops: List[dict],
-                 xla_totals: Optional[dict] = None):
+                 xla_totals: Optional[dict] = None,
+                 op_paths: Optional[Dict[str, str]] = None):
         self.label = label
         self.fingerprint = fingerprint
         self.ops = ops
         self.xla_totals = dict(xla_totals or {})
+        self.op_paths = dict(op_paths or {})
 
     # -- derived views ------------------------------------------------------
     def cost_units(self) -> Dict[str, float]:

@@ -1,10 +1,14 @@
 """Trace spans: named, nested, context-propagated timing scopes.
 
-One recorder feeds two sinks: every span wraps a ``profiler.RecordEvent``
+One recorder feeds three sinks: every span wraps a ``profiler.RecordEvent``
 (so an active Profiler window sees it in chrome-trace exports and the
-summary table, host-tracer tier included) AND observes its duration into
-the ``span_duration_seconds`` histogram of the metrics registry (so p50/
-p95/p99 per span name are queryable with no profiler attached).
+summary table, host-tracer tier included), enters a
+``jax.profiler.TraceAnnotation`` (so inside a ``jax.profiler.start_trace``
+window it lies in the host plane of the ``.xplane.pb``, on the clock of
+the device events; with no session that is a flag check) AND observes its
+duration into the ``span_duration_seconds`` histogram of the metrics
+registry (so p50/p95/p99 per span name are queryable with no profiler
+attached).
 
 Nesting is tracked per thread; ``capture_context()`` / ``attach_context``
 carry the active span path across thread (or executor) boundaries, the
@@ -15,6 +19,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from ..profiler import RecordEvent
 from .metrics import get_registry
@@ -33,23 +39,37 @@ def _stack():
     return st
 
 
-def _span_hist():
-    return get_registry().histogram(
-        "span_duration_seconds",
-        "trace span wall time by span name", labelnames=("span",))
+_HIST_CHILD = {}     # span name -> its span_duration_seconds series
+
+
+def _span_hist(name: str):
+    """The histogram series of one span name, looked up once: the
+    registry is process-wide and ``reset()`` zeroes series in place."""
+    child = _HIST_CHILD.get(name)
+    if child is None:
+        child = _HIST_CHILD[name] = get_registry().histogram(
+            "span_duration_seconds",
+            "trace span wall time by span name",
+            labelnames=("span",)).labels(span=name)
+    return child
 
 
 class Span:
-    """One named timing scope (context manager, re-usable via span())."""
+    """One named timing scope (context manager, re-usable via span()).
+    ``tags`` are small values (a request id, token counts) that go to the
+    profiler's annotation as its stats."""
 
-    __slots__ = ("name", "path", "start_ns", "end_ns", "_record")
+    __slots__ = ("name", "tags", "path", "start_ns", "end_ns", "_record",
+                 "_annotation")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **tags):
         self.name = name
+        self.tags = tags
         self.path = name          # finalized at __enter__ from the stack
         self.start_ns = None
         self.end_ns = None
         self._record = None
+        self._annotation = None
 
     @property
     def duration_s(self) -> Optional[float]:
@@ -63,11 +83,16 @@ class Span:
         st.append(self)
         self._record = RecordEvent(self.name)
         self._record.begin()
+        self._annotation = TraceAnnotation(self.name, **self.tags)
+        self._annotation.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.end_ns = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._record is not None:
             self._record.end()
             self._record = None
@@ -76,13 +101,13 @@ class Span:
             while st and st[-1] is not self:
                 st.pop()
             st.pop()
-        _span_hist().labels(span=self.name).observe(self.duration_s)
+        _span_hist(self.name).observe(self.duration_s)
         return False
 
 
-def span(name: str) -> Span:
-    """``with span("decode_step"): ...`` — the primary entry point."""
-    return Span(name)
+def span(name: str, **tags) -> Span:
+    """``with span("decode_step", rid=7): ...`` — the primary entry point."""
+    return Span(name, **tags)
 
 
 def current_span() -> Optional[Span]:

@@ -334,3 +334,245 @@ def test_prometheus_dump_after_serving_has_populated_families():
     assert "# TYPE serving_requests_total counter" in text
     assert "# TYPE serving_ttft_seconds histogram" in text
     assert "# TYPE serving_queue_depth gauge" in text
+
+
+# ---------------------------------------------------------------------------
+# spans in the JAX profiler's trace; executables and scopes by name
+# ---------------------------------------------------------------------------
+
+# every span of the serving path, by where it opens (PERF.md section 3)
+GATEWAY_SPANS = ("gateway.dispatch", "gateway.replica_step", "gateway.poll")
+SERVING_SPANS = ("serving.admit", "serving.prefill_chunk", "serving.grow",
+                 "serving.sync_tables", "serving.launch", "serving.fetch",
+                 "serving.pick")
+TRAIN_SPANS = ("trainstep.assemble", "trainstep.launch",
+               "trainstep.writeback")
+
+
+def _tiny_llama():
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(0)
+    m = LlamaForCausalLM(LlamaConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128))
+    m.eval()
+    return m
+
+
+def _tiny_gateway():
+    from paddle_tpu.inference.gateway import Gateway
+    from paddle_tpu.inference.serving import PagedContinuousBatcher
+    batcher = PagedContinuousBatcher(
+        _tiny_llama(), max_batch=2, s_max=64, block_size=8, n_pages=32,
+        prefill_chunk=8, policy="ondemand", prefix_cache=True, compile=True)
+    gateway = Gateway()
+    gateway.add_replica("r0", batcher)
+    return gateway, batcher
+
+
+def _tiny_train_step(label):
+    from paddle_tpu import jit, nn, optimizer
+    paddle.seed(0)
+    net = nn.Linear(8, 4)
+    opt = optimizer.SGD(learning_rate=0.1, parameters=net.parameters())
+    step = jit.TrainStep(lambda x, y: ((net(x) - y) ** 2).mean(), opt,
+                         opprof_label=label)
+    return step, (paddle.ones([2, 8]), paddle.zeros([2, 4]))
+
+
+def _host_events(trace_dir):
+    """{name: [(start_ns, end_ns, stats)]} of the program's spans in the
+    host planes."""
+    import glob
+    import os
+
+    import jax
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("obs_", "gateway.", "serving.",
+                                      "trainstep.")):
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One profiler window for the module: nested spans with tags, a few
+    gateway steps over a tiny paged server, two compiled train steps."""
+    import jax
+    gateway, batcher = _tiny_gateway()
+    rng = np.random.RandomState(5)
+    step, batch = _tiny_train_step("obs.train_step")
+    step(*batch)                       # the eager discovery pass
+    step(*batch)                       # compiles
+    with paddle.no_grad():
+        gateway.submit(rng.randint(0, 128, (20,)), 3)    # warm both
+        gateway.run_until_done()                         # executables
+    batcher.reset_stats()
+    admitted = get_registry().get("serving_admissions_total").labels(
+        engine="paged")
+    admitted_before = admitted.value
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        with span("obs_outer", rid=7, prompt_tokens=12):
+            time.sleep(0.002)
+            with span("obs_inner", replica="r0"):
+                time.sleep(0.002)
+            time.sleep(0.002)
+        with paddle.no_grad():
+            for n in (20, 9, 13):
+                gateway.submit(rng.randint(0, 128, (n,)), 4)
+            gateway.run_until_done()
+        step(*batch)
+        step(*batch)
+    finally:
+        jax.profiler.stop_trace()
+    stats = dict(batcher.stats(),
+                 admissions=admitted.value - admitted_before)
+    batcher.close()
+    return {"events": _host_events(trace_dir), "stats": stats}
+
+
+def test_span_lands_in_the_profilers_host_plane_with_its_tags(traced):
+    ev = traced["events"]
+    (outer,) = ev["obs_outer"]
+    (inner,) = ev["obs_inner"]
+    assert outer[2] == {"rid": 7, "prompt_tokens": 12}
+    assert inner[2] == {"replica": "r0"}
+    # same clock, so nesting is containment, with room on both sides
+    assert outer[0] < inner[0] < inner[1] < outer[1]
+    assert inner[1] - inner[0] >= 1.5e6
+    assert (inner[0] - outer[0]) >= 1.5e6 and (outer[1] - inner[1]) >= 1.5e6
+
+
+def test_span_without_a_profiler_session_still_feeds_the_histogram():
+    hist = get_registry().get("span_duration_seconds")
+    child = hist.labels(span="obs_no_session")
+    before = child.count
+    with span("obs_no_session", rid=1):
+        pass
+    with span("obs_no_session"):
+        pass
+    assert child.count == before + 2
+
+
+def test_serving_phase_spans_counted_as_the_batcher_counts(traced):
+    ev, st = traced["events"], traced["stats"]
+    for name in GATEWAY_SPANS + SERVING_SPANS:
+        assert ev.get(name), f"no {name} span in the trace"
+    assert st["admissions"] == 3 and st["steps"] > 0
+    assert len(ev["serving.admit"]) == st["admissions"]
+    assert len(ev["serving.launch"]) == st["steps"]
+    assert len(ev["serving.sync_tables"]) == st["steps"]
+    assert len(ev["serving.grow"]) == st["steps"]
+    assert len(ev["serving.pick"]) == st["steps"]
+    # a fetch per decode step and one for each admission's first token
+    assert len(ev["serving.fetch"]) == st["steps"] + st["admissions"]
+    # prompts of 20, 9 and 13 tokens in chunks of 8: 3 + 2 + 2
+    assert len(ev["serving.prefill_chunk"]) == 7
+    admit = sorted(ev["serving.admit"])
+    assert [a[2]["prompt_tokens"] for a in admit] == [20, 9, 13]
+    assert all(a[2]["hit_tokens"] == 0 and "rid" in a[2] for a in admit)
+    assert len(ev["gateway.replica_step"]) == len(ev["gateway.poll"])
+    assert {e[2]["replica"] for e in ev["gateway.replica_step"]} == {"r0"}
+
+
+def test_serving_spans_nest_under_the_replica_step(traced):
+    ev = traced["events"]
+    steps = ev["gateway.replica_step"]
+
+    def inside(child, parents):
+        return any(p[0] <= child[0] and child[1] <= p[1] for p in parents)
+
+    for name in SERVING_SPANS:
+        assert all(inside(e, steps) for e in ev[name]), name
+    assert all(inside(c, ev["serving.admit"])
+               for c in ev["serving.prefill_chunk"])
+    # disjoint phases of one step: nothing of the poll or the dispatch
+    # lies inside a replica step
+    assert not any(inside(e, steps)
+                   for e in ev["gateway.poll"] + ev["gateway.dispatch"])
+
+
+def test_train_step_spans_in_order(traced):
+    ev = traced["events"]
+    for name in TRAIN_SPANS:
+        assert len(ev[name]) == 2, name
+    for a, l, w in zip(*(sorted(ev[n]) for n in TRAIN_SPANS)):
+        assert a[1] <= l[0] and l[1] <= w[0]
+
+
+@pytest.fixture
+def hlo_texts(monkeypatch):
+    """{label: optimized HLO text} of every executable that reaches its
+    warm transition while the fixture is live, read where opprof reads it."""
+    from paddle_tpu.observability import opprof
+    texts = {}
+    real = opprof.profile_compiled
+
+    def keep(compiled, label=""):
+        texts[label] = compiled.as_text()
+        return real(compiled, label=label)
+
+    monkeypatch.setattr(opprof, "profile_compiled", keep)
+    was = opprof.enabled()
+    opprof.enable()
+    yield texts
+    if not was:
+        opprof.disable()
+
+
+def test_executables_lower_under_their_label(hlo_texts):
+    from paddle_tpu import jit
+
+    def double(x):
+        return x * 2
+
+    plain = jit.to_static(double)
+    labelled = jit.to_static(double)
+    labelled._opprof_label = "obs.double_it"
+    x = paddle.ones([4])
+    for fn in (plain, labelled):
+        fn(x)          # eager discovery
+        fn(x)          # compiled: the warm transition
+    step, batch = _tiny_train_step("obs.train_step")
+    step(*batch)
+    step(*batch)
+    for label, module in (("static.double", "jit_static_double"),
+                          ("obs.double_it", "jit_obs_double_it"),
+                          ("obs.train_step", "jit_obs_train_step")):
+        assert hlo_texts[label].startswith(f"HloModule {module},"), \
+            hlo_texts[label][:120]
+
+
+def test_serving_hlo_carries_the_models_scopes(hlo_texts):
+    import re
+    gateway, batcher = _tiny_gateway()
+    with paddle.no_grad():
+        gateway.submit(np.arange(20) % 128, 3)
+        gateway.run_until_done()
+    batcher.close()
+    for label, module in (
+            ("serving.paged_decode", "jit_serving_paged_decode"),
+            ("serving.paged_prefill_chunk",
+             "jit_serving_paged_prefill_chunk")):
+        text = hlo_texts[label]
+        assert text.startswith(f"HloModule {module},")
+        paths = set(re.findall(r'op_name="([^"]+)"', text))
+        scopes = {part for p in paths for part in p.split("/")}
+        assert {"embed", "attn_norm", "qkv_rope", "paged_attention",
+                "kv_scatter", "kv_gather", "scores", "o_proj", "mlp_norm",
+                "mlp", "head"} <= scopes, sorted(scopes)
+        # the three phases of the attention lie inside its scope
+        for inner in ("kv_scatter", "kv_gather", "scores"):
+            assert any(f"/paged_attention/{inner}/" in p for p in paths)
