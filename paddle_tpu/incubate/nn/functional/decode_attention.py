@@ -10,11 +10,14 @@ Reference surface:
   (python/paddle/incubate/nn/functional/
    variable_length_memory_efficient_attention.py:28)
 
-TPU design: these are the serving-side attention kernels. The paged-cache
-read is a gather over the block table (jnp.take lowers to an XLA gather
-that rides HBM efficiently); cache writes are scatters at static positions
-per decode step. Quantized-cache args (qkv_out_scale, cache_k_quant_scales,
-...) are gated — the quantization tier on TPU lives in paddle_tpu.quantization.
+TPU design: these are the serving-side attention ops. The general
+paged-cache read is a gather over the block table (every slot's whole
+timeline, whatever is cached); cache writes are scatters at static positions
+per step. The decode step (one token a sequence) has an entry of its own,
+``block_gqa_decode_attention``, which on the chip reads only the pages a
+sequence holds through the Pallas kernel of ``ops/pallas/paged_attention``.
+Quantized-cache args (qkv_out_scale, cache_k_quant_scales, ...) are gated —
+the quantization tier on TPU lives in paddle_tpu.quantization.
 """
 from __future__ import annotations
 
@@ -170,6 +173,16 @@ def _dynamic_compute_allowed(enc, this):
                 "1): thread the scales the prefill call returned")
     except jax.errors.TracerBoolConversionError:
         pass
+
+
+def _rope_pairs(u, cos_t, sin_t):
+    """Rotate [T, H, D] rows by their positions' [T, D/2] tables
+    (interleaved-pair convention, computed in fp32)."""
+    uf = u.astype(jnp.float32)
+    u1, u2 = uf[..., 0::2], uf[..., 1::2]
+    c, s = cos_t[:, None, :], sin_t[:, None, :]
+    return jnp.stack([u1 * c - u2 * s, u2 * c + u1 * s],
+                     axis=-1).reshape(u.shape).astype(u.dtype)
 
 
 def _scatter_paged(kc, vc, bt, seq_of, pos, kt, vt, block_size,
@@ -493,14 +506,8 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
         with jax.named_scope("qkv_rope"):
             cos_t = _arr(rope_cos)[pos].astype(jnp.float32)    # [T, D/2]
             sin_t = _arr(rope_sin)[pos].astype(jnp.float32)
-
-            def _rope(u):
-                uf = u.astype(jnp.float32)
-                u1, u2 = uf[..., 0::2], uf[..., 1::2]
-                c, s = cos_t[:, None, :], sin_t[:, None, :]
-                return jnp.stack([u1 * c - u2 * s, u2 * c + u1 * s],
-                                 axis=-1).reshape(u.shape).astype(u.dtype)
-            qt, kt = _rope(qt), _rope(kt)
+            qt, kt = _rope_pairs(qt, cos_t, sin_t), \
+                _rope_pairs(kt, cos_t, sin_t)
 
     new_scales = None
     if compute_dynamic_scales:
@@ -553,6 +560,67 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
     return result
 
 
+def decode_attention_path(pool_shape, pool_dtype, q_heads) -> str:
+    """Which route ``block_gqa_decode_attention`` takes for a page pool:
+    ``"kernel"`` (ops/pallas/paged_attention) on the chip over a pool that
+    is not quantized and whose pages Mosaic takes as they lie, ``"gather"``
+    (``block_gqa_attention``) otherwise. Decided from what can be observed
+    — the backend and the pool — like every kernel of ops/pallas."""
+    from ....ops import pallas as _pl
+    from ....ops.pallas.paged_attention import supported
+    if _pl.on_tpu() and supported(pool_shape, pool_dtype, q_heads):
+        return "kernel"
+    return "gather"
+
+
+def block_gqa_decode_attention(q, k, v, key_cache, value_cache,
+                               seq_lens_decoder, block_tables,
+                               rope_cos=None, rope_sin=None,
+                               **cachekv_quant):
+    """The decode step's paged GQA attention: every sequence contributes
+    exactly one token, appended at ``seq_lens_decoder[i]``.
+
+    q [B, H, D], k / v [B, KV, D]; pool, block table and RoPE tables as in
+    ``block_gqa_attention``, whose decode case (``seq_lens_encoder == 0``,
+    ``seq_lens_this_time == 1``) this computes. That every sequence adds
+    one token is not a fact the general op can read off a traced
+    ``seq_lens_this_time``, so the caller that knows it (the model's
+    ``paged_decode_step``) says so by calling this entry. On the chip the
+    pages a sequence holds are read in place by the Pallas kernel; where
+    ``decode_attention_path`` says ``"gather"``, or cache-quantization
+    scales are passed (``cachekv_quant``: the general op's keywords), the
+    general op runs. Returns (out [B, H*D], key_cache_out, value_cache_out).
+    """
+    qt, kt, vt = _arr(q), _arr(k), _arr(v)
+    kc, vc = _arr(key_cache), _arr(value_cache)
+    bsz, nh, hd = qt.shape
+    if cachekv_quant or decode_attention_path(kc.shape, kc.dtype,
+                                              nh) == "gather":
+        ones = jnp.ones((bsz,), jnp.int32)
+        return block_gqa_attention(
+            q, k, v, key_cache, value_cache, jnp.zeros_like(ones),
+            seq_lens_decoder, ones, jnp.arange(bsz + 1, dtype=jnp.int32),
+            block_tables, rope_cos=rope_cos, rope_sin=rope_sin,
+            **cachekv_quant)
+    from ....ops.pallas.paged_attention import paged_attention_decode
+    dec = _arr(seq_lens_decoder).reshape(-1).astype(jnp.int32)
+    bt = _arr(block_tables).astype(jnp.int32)
+    if rope_cos is not None:
+        with jax.named_scope("qkv_rope"):
+            cos_t = _arr(rope_cos)[dec].astype(jnp.float32)    # [B, D/2]
+            sin_t = _arr(rope_sin)[dec].astype(jnp.float32)
+            qt, kt = _rope_pairs(qt, cos_t, sin_t), \
+                _rope_pairs(kt, cos_t, sin_t)
+    with jax.named_scope("paged_attention"):
+        with jax.named_scope("kv_scatter"):
+            kc, vc = _scatter_paged(kc, vc, bt, jnp.arange(bsz), dec, kt,
+                                    vt, kc.shape[2])
+        with jax.named_scope("scores"):
+            # this step's row is in the pool: dec + 1 rows count
+            out = paged_attention_decode(qt, kc, vc, bt, dec + 1)
+    return Tensor(out.reshape(bsz, nh * hd)), Tensor(kc), Tensor(vc)
+
+
 def variable_length_memory_efficient_attention(query, key, value, seq_lens,
                                                kv_seq_lens, mask=None,
                                                scale=None, causal=False,
@@ -588,6 +656,7 @@ def variable_length_memory_efficient_attention(query, key, value, seq_lens,
 
 
 __all__ = ["masked_multihead_attention", "block_multihead_attention",
-           "block_gqa_attention", "cachekv_scales_from_dense",
+           "block_gqa_attention", "block_gqa_decode_attention",
+           "decode_attention_path", "cachekv_scales_from_dense",
            "cachekv_scale_kwargs",
            "variable_length_memory_efficient_attention"]

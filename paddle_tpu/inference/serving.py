@@ -1118,6 +1118,12 @@ class PagedContinuousBatcher(_BatcherBase):
             "cu_b": paddle.to_tensor(np.arange(max_batch + 1,
                                                dtype=np.int32)),
         }
+        # the route the decode step's attention takes over this pool
+        # ("kernel": the Pallas paged kernel; "gather": the XLA gather),
+        # as the model that builds the executable decides it; every launch
+        # of that executable is counted under it
+        self._decode_path, self._decode_launch_c = \
+            self._decode_attention_series(model, pool)
         if cache_quant:
             # per-(slot, kv-head) dynamic scales, host-owned like the
             # block table; each sequence's prefill fills its slot row
@@ -1148,6 +1154,8 @@ class PagedContinuousBatcher(_BatcherBase):
             self._check_window(draft_model.config, s_max)
             dpool = draft_model.paged_alloc(n_pages + 1, block_size)
             self._ddec = np.zeros((max_batch,), np.int32)
+            _, self._draft_launch_c = self._decode_attention_series(
+                draft_model, dpool)
             self._dstate = {
                 "layers": dpool,
                 "block_tables": paddle.to_tensor(self._bt),
@@ -2112,6 +2120,27 @@ class PagedContinuousBatcher(_BatcherBase):
                 self._scales_np[li][k][slot] = np.asarray(sc[k]._data)[0]
         self._scales_dirty = True
 
+    def _decode_attention_series(self, model, pool):
+        """(path, counter) for executables built from ``model``'s
+        ``paged_decode_step`` over ``pool``: the word is the model's own
+        (``paged_decode_attention_path``; a family without the decode
+        entry gathers), the counter is
+        ``serving_decode_attention_launches_total{path=...}``."""
+        from ..observability.metrics import get_registry
+        route = getattr(model, "paged_decode_attention_path", None)
+        path = route(pool) if route is not None else "gather"
+        return path, get_registry().counter(
+            "serving_decode_attention_launches_total",
+            "launches of a decode-step executable, by the route its "
+            "attention takes (kernel: Pallas paged kernel; gather: XLA)",
+            labelnames=("engine", "path")).labels(
+                engine=self._engine, path=path)
+
+    def stats(self) -> Dict[str, float]:
+        """The base counters, and ``decode_attention_path``: the label of
+        this batcher's ``serving_decode_attention_launches_total``."""
+        return dict(super().stats(), decode_attention_path=self._decode_path)
+
     def _sync_tables(self):
         import paddle_tpu as paddle
         with _span("serving.sync_tables"):
@@ -2369,6 +2398,7 @@ class PagedContinuousBatcher(_BatcherBase):
         n_active = len(self._slot_req)
         t0 = _time.perf_counter()
         with _span("serving.launch"), paddle.no_grad():
+            self._decode_launch_c.inc()
             tok_t = paddle.to_tensor(self._last_tok)
             logits, self._state = self._step_fn(tok_t, self._state)
         self._advance_decoders(logits, finished)
@@ -2430,6 +2460,7 @@ class PagedContinuousBatcher(_BatcherBase):
         n_active = len(self._slot_req)
         t0 = _time.perf_counter()
         with _span("serving.launch"), paddle.no_grad():
+            self._decode_launch_c.inc()
             tok_t = paddle.to_tensor(self._last_tok)
             toks, self._state = self._block_fn(tok_t, self._state)
         with _span("serving.fetch"):
@@ -2549,6 +2580,7 @@ class PagedContinuousBatcher(_BatcherBase):
             tok = props[0]
             for _ in range(k - 1):
                 with _span("serving.launch"):
+                    self._draft_launch_c.inc()
                     dlg, self._dstate = self._dstep_fn(
                         paddle.to_tensor(tok.astype(np.int64)),
                         self._dstate)

@@ -1072,12 +1072,24 @@ class LlamaForCausalLM(Layer):
                                                    block_size,
                                                    blocks_per_seq)
 
+    def paged_decode_attention_path(self, pool) -> str:
+        """``"kernel"`` or ``"gather"``: the route ``paged_decode_step``'s
+        attention takes over ``pool`` (a ``paged_alloc`` result) on this
+        backend — what the batcher labels its decode launches with."""
+        from ..incubate.nn.functional.decode_attention import \
+            decode_attention_path
+        kc = pool[0][0]
+        return decode_attention_path(tuple(kc.shape), kc._data.dtype,
+                                     self.config.num_attention_heads)
+
     def paged_decode_step(self, tok, state):
         """One token per sequence through the paged GQA cache. tok: [B].
         Static shapes — ``jit.to_static(model.paged_decode_step)`` serves
-        every step with one executable."""
-        from ..incubate.nn.functional.decode_attention import \
-            block_gqa_attention
+        every step with one executable. Decode by construction, so the
+        attention is the decode-only entry (the Pallas paged kernel on the
+        chip), not the general ``block_gqa_attention``."""
+        from ..incubate.nn.functional.decode_attention import (
+            block_gqa_decode_attention, cachekv_scale_kwargs)
 
         self._check_paged_servable()
         cfg = self.config
@@ -1086,7 +1098,6 @@ class LlamaForCausalLM(Layer):
                      cfg.head_dim)
         t = state["dec_lens"]
         bt = state["block_tables"]
-        enc, this, cu_q = state["zeros_b"], state["ones_b"], state["cu_b"]
         model = self.model
         cos_tab, sin_tab = model._cos, model._sin
 
@@ -1106,15 +1117,12 @@ class LlamaForCausalLM(Layer):
             if dyn is not None:
                 # dynamic cachekv int8: per-(slot, head) scales ride the
                 # state, fixed by each sequence's prefill
-                from ..incubate.nn.functional.decode_attention import \
-                    cachekv_scale_kwargs
                 kwargs = dict(cachekv_scale_kwargs(dyn, li),
                               use_dynamic_cachekv_quant=True)
             else:
                 kwargs = self._layer_cache_scales(li)
-            out, kc, vc = block_gqa_attention(
-                q, k, v, kc, vc, enc, t, this, cu_q, bt,
-                block_size=state["block_size"], rope_cos=Tensor(cos_tab),
+            out, kc, vc = block_gqa_decode_attention(
+                q, k, v, kc, vc, t, bt, rope_cos=Tensor(cos_tab),
                 rope_sin=Tensor(sin_tab), **kwargs)
             with jax.named_scope("o_proj"):
                 hidden = hidden + attn.o_proj(out.reshape([b, 1, h * d]))

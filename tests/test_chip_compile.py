@@ -183,10 +183,11 @@ def test_block_sparse_attention_compiles(chip):
 
 @pytest.mark.parametrize("tokens", [8, 1024], ids=["decode_b8", "prefill_1k"])
 def test_paged_attention_step_compiles(chip, tokens):
-    """The serving attention op (an XLA gather over the block table, no
-    Pallas kernel yet — ROADMAP S3) at 32 x 128 heads with 16-token pages:
-    one decode step for 8 slots, and a 1024-token prompt admitted into one
-    slot, which is how the batcher admits. (Two packed prompts in one call
+    """The general serving attention op (an XLA gather over the block
+    table; the decode step has a kernel of its own, next test) at 32 x 128
+    heads with 16-token pages: one decode step for 8 slots, and a
+    1024-token prompt admitted into one slot, which is how the batcher
+    admits. (Two packed prompts in one call
     do not fit: the op gathers a copy of the timeline per token, 34 GB at
     these sizes; XLA folds that gather away only for a single sequence.)"""
     from paddle_tpu.incubate.nn.functional.decode_attention import \
@@ -218,6 +219,53 @@ def test_paged_attention_step_compiles(chip, tokens):
                         sds((bsz, blocks_per_seq), jnp.int32), rope, rope)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 << 30, mem
+
+
+@pytest.mark.parametrize("kv_heads,dtype", [
+    (8, jnp.bfloat16), (32, jnp.bfloat16), (8, jnp.float32)],
+    ids=["gqa32_8_bf16", "mha32_bf16", "gqa32_8_f32"])
+def test_paged_decode_entry_compiles_with_the_kernel(chip, monkeypatch,
+                                                     kv_heads, dtype):
+    """The decode step's attention entry at the serving cell's shapes
+    (mistral7b-*: 32 slots, 32/8 heads of 128, 16-token pages, 256 pages a
+    sequence, 3,073 pages; and the same with 32 kv heads, chip_smoke's
+    Llama-2 widths, and in float32): RoPE, the scatter and the Pallas
+    kernel ``paged_attention_decode`` reading the pool in place. Nothing
+    the size of the gathered timelines is left: at these shapes
+    ``_gather_paged`` made 1 GB of them (32 x 8 x 4096 x 128 bf16 for K and
+    for V, and their transposed copies).
+    The entry asks ``on_tpu()``, which sees this sandbox's CPU, so the test
+    steers it: the compile is for the described chip."""
+    import paddle_tpu.ops.pallas as pallas_tier
+    from paddle_tpu.incubate.nn.functional.decode_attention import \
+        block_gqa_decode_attention
+
+    monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
+    slots, block, blocks_per_seq, n_pages = 32, 16, 256, 3073
+
+    def step(q, k, v, kc, vc, dec, bt, cos, sin):
+        out, kc, vc = block_gqa_decode_attention(
+            q, k, v, kc, vc, dec, bt, rope_cos=cos, rope_sin=sin)
+        return out._data, kc._data, vc._data
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    row = sds((slots, kv_heads, HEAD_DIM))
+    pool = sds((n_pages, kv_heads, block, HEAD_DIM))
+    rope = sds((block * blocks_per_seq, HEAD_DIM // 2), jnp.float32)
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        sds((slots, HEADS, HEAD_DIM)), row, row, pool, pool,
+        sds((slots,), jnp.int32), sds((slots, blocks_per_seq), jnp.int32),
+        rope, rope).compile()
+    assert len(_kernel_calls(compiled, "paged_attention_decode")) == 1
+    mem = compiled.memory_analysis()
+    # what is left is the scatter's relayout of a pool array (ROADMAP S8):
+    # none at the cell's shapes, one array's worth at the others
+    pool_bytes = n_pages * kv_heads * block * HEAD_DIM * dtype.dtype.itemsize
+    limit = (64 << 20) if (kv_heads, dtype) == (8, jnp.bfloat16) \
+        else pool_bytes + (16 << 20)
+    assert mem.temp_size_in_bytes < limit, mem
 
 
 def test_scanned_decoder_layer_fwd_bwd_compiles(chip, monkeypatch):

@@ -308,6 +308,65 @@ def test_batcher_stats():
     assert s["pending_now"] == 0 and s["active_now"] == 0
 
 
+def _decode_launches(engine="paged"):
+    from paddle_tpu.observability.metrics import get_registry
+    family = get_registry().get("serving_decode_attention_launches_total")
+    return {path: family.labels(engine=engine, path=path).value
+            for path in ("kernel", "gather")}
+
+
+@pytest.mark.parametrize("family,decode_block", [
+    ("llama", None), ("llama", 4), ("gpt2", None)],
+    ids=["llama", "llama_decode_block", "gpt2"])
+def test_decode_attention_launches_counted_by_path(family, decode_block):
+    """``serving_decode_attention_launches_total{path}`` counts one a
+    launch of the decode executable, under the route its attention was
+    built with: off the chip that is ``gather`` for every family (on the
+    chip ``kernel`` for the Llama family over an unquantized pool), and
+    ``stats()`` carries the same word."""
+    m = _llama() if family == "llama" else _model()
+    rng = np.random.RandomState(7)
+    b = PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
+                               compile=False, decode_block=decode_block)
+    assert b.stats()["decode_attention_path"] == "gather"
+    before = _decode_launches()
+    for _ in range(2):
+        b.submit(rng.randint(0, 128, (5,)), 6)
+    b.run_until_done()
+    s = b.stats()
+    after = _decode_launches()
+    assert after["kernel"] == before["kernel"]
+    # one launch a step on the plain path; a K-step block is one launch
+    launches = s["steps"] - (decode_block - 1) * s["decode_blocks"] \
+        if decode_block else s["steps"]
+    assert launches > 0
+    assert after["gather"] - before["gather"] == launches
+
+
+def test_decode_attention_path_is_the_models_word(monkeypatch):
+    """The batcher does not decide the route: it asks the model that
+    builds the executable, with the pool it allocated."""
+    m = _llama()
+    seen = []
+
+    def route(pool):
+        seen.append(tuple(pool[0][0].shape))
+        return "kernel"
+    monkeypatch.setattr(m, "paged_decode_attention_path", route,
+                        raising=False)
+    b = PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
+                               compile=False)
+    assert b.stats()["decode_attention_path"] == "kernel"
+    assert seen == [(b.n_pages + 1, m.config.num_key_value_heads, 8,
+                     m.config.head_dim)]
+    before = _decode_launches()
+    b.submit(np.arange(5), 3)
+    b.run_until_done()
+    after = _decode_launches()
+    assert after["kernel"] - before["kernel"] == b.stats()["steps"]
+    assert after["gather"] == before["gather"]
+
+
 # -- chunked prefill (one executable for every prompt length) --------------
 
 def test_chunked_prefill_token_exact_mixed_lengths():
