@@ -35,6 +35,7 @@ import os
 import re
 import sys
 
+from . import families
 from . import xplane as X
 from .harness import ROOT, SPANS as HARNESS_SPANS, say
 
@@ -47,11 +48,6 @@ DECODE = "serving_paged_decode"
 PREFILL_CHUNK = "serving_paged_prefill_chunk"
 SCOPES = ("embed", "attn_norm", "qkv_rope", "paged_attention", "kv_scatter",
           "kv_gather", "scores", "o_proj", "mlp_norm", "mlp", "head")
-NEW_METRICS = (
-    "decode_device_ms.batch", "decode_device_ms.sessions",
-    "prefill_chunk_device_ms.sessions", "paged_attn_roofline",
-    "gap_fetch_ms.batch", "gap_host_loop_ms.batch", "admit_ms.sessions",
-    "gap_admit_ms.sessions")
 _MODULE_LINE = "XLA Modules"
 _SIDECAR = "op_scopes.json"
 _INHERITED = " <-operand"
@@ -65,13 +61,13 @@ def newest_trace(root: str = ROOT):
     return max(paths, key=os.path.getmtime, default=None)
 
 
-def scope_of(op_name: str) -> str:
+def scope_of(op_name: str, scopes=SCOPES) -> str:
     """``paged_attention/kv_gather`` from an instruction's ``op_name``: the
     model's scopes in the order they were entered, "" where there is none."""
-    return "/".join(p for p in op_name.split("/") if p in SCOPES)
+    return "/".join(p for p in op_name.split("/") if p in scopes)
 
 
-def program_op_scopes() -> dict:
+def program_op_scopes(scopes=SCOPES) -> dict:
     """{module: {instruction: scope}} of the executables this process
     compiled, from what opprof kept of their optimized HLO; {} where the
     program keeps no such map (before PR 25)."""
@@ -81,7 +77,7 @@ def program_op_scopes() -> dict:
         paths = getattr(profs[-1], "op_paths", None) if profs else None
         if paths:       # the module's name as jit._named makes it
             out[re.sub(r"\W", "_", label)] = {
-                k: s for k, v in paths.items() if (s := scope_of(v))}
+                k: s for k, v in paths.items() if (s := scope_of(v, scopes))}
     return out
 
 
@@ -238,7 +234,9 @@ def innermost_segments(spans, lo, hi) -> list:
     return out
 
 
-def analyse(trace: dict) -> dict:
+def analyse(trace: dict, spans=PROGRAM_SPANS) -> dict:
+    """``spans``: the program's spans that idle time is put down to and
+    whose durations are kept (the base tuple, with a family's own)."""
     timelines = X._device_timelines(trace)
     if not timelines:
         raise ValueError("the trace holds no device operation")
@@ -277,10 +275,9 @@ def analyse(trace: dict) -> dict:
                                "whole_calls": 0, "whole_s": 0.0}
 
     # idle time by the innermost span
-    names = set(PROGRAM_SPANS) | set(HARNESS_SPANS)
-    spans = X.host_spans(trace, names)
+    opened = X.host_spans(trace, set(spans) | set(HARNESS_SPANS))
     gaps, under_admit = {}, 0.0
-    for t0, t1, stack in innermost_segments(spans, lo, hi):
+    for t0, t1, stack in innermost_segments(opened, lo, hi):
         sec = (t1 - t0 - busy_in(t0, t1)) / 1e9
         if sec <= 0:
             continue
@@ -289,8 +286,8 @@ def analyse(trace: dict) -> dict:
         if "serving.admit" in stack and key != "serving.fetch":
             under_admit += sec
     durations = {}
-    for n, s, e in spans:
-        if n in PROGRAM_SPANS and s >= lo and e <= hi:
+    for n, s, e in opened:
+        if n in spans and s >= lo and e <= hi:
             durations.setdefault(n, []).append((e - s) / 1e9)
     return {
         "device": plane["name"], "window_s": (hi - lo) / 1e9,
@@ -300,9 +297,7 @@ def analyse(trace: dict) -> dict:
                                    trace.get("op_scopes") or {}),
         "gaps": gaps, "gap_under_admit_s": under_admit,
         "span_counts": {n: len(v) for n, v in durations.items()},
-        "span_mean_s": {n: sum(v) / len(v) for n, v in durations.items()},
-        "has_names": bool(durations) or any(
-            n not in ("pure", "no_module") for n in by_exe)}
+        "span_mean_s": {n: sum(v) / len(v) for n, v in durations.items()}}
 
 
 def _sorted_rows(seconds: dict, last=None) -> list:
@@ -341,34 +336,17 @@ def of_run(run: dict):
     if path is None or not run.get("trace"):
         return None
     if path not in _ANALYSES:
-        op_scopes = program_op_scopes()
+        family = families.of(run["cfg"])
+        op_scopes = program_op_scopes(SCOPES + tuple(family.SCOPES))
         if op_scopes and path.endswith(".pb"):
             with open(os.path.join(os.path.dirname(path), _SIDECAR),
                       "w") as f:
                 json.dump(op_scopes, f)
-        a = _ANALYSES[path] = analyse(load(path))
+        a = _ANALYSES[path] = analyse(
+            load(path), PROGRAM_SPANS + tuple(family.SPANS))
         for key, value in tables(a).items():
             say(key, value)
-        if not a["has_names"]:
-            _leave_out(NEW_METRICS)
     return _ANALYSES[path]
-
-
-def _leave_out(names):
-    """A program from before PR 25 names no module and opens no span: the
-    readers below find nothing there, return None, and the line leaves
-    their metrics out. The harness's own check of its last line
-    (``lastline.problems``) lets only an off-chip ``--rehearse`` do that,
-    and run.py passes it no other ``optional``; the driver lays these files
-    over such a program too. Until a ``benchmark`` PR lets a reader say so
-    (PERF.md section 7), this hands the check the names for that program."""
-    from . import lastline
-    inner = lastline.problems
-
-    def problems(line, bench, workload, trace, chips, optional=()):
-        return inner(line, bench, workload, trace, chips,
-                     tuple(optional) + tuple(names))
-    lastline.problems = problems
 
 
 # -- what the readers under chipbench/metrics/ return ------------------------

@@ -1,35 +1,32 @@
-"""The plain reference: the architecture's equations in straightforward
+"""The plain reference: a family's equations in straightforward
 ``jax.numpy`` and float32, with no kernels, no cache and no batching.
 
 It imports nothing of the program and takes nothing the program has made: its
-weights come from ``weights.py`` and the seed. Matrix products run at
-``highest`` precision (on a TPU a float32 product otherwise runs in bfloat16
-passes). It works a sequence at a time and a layer at a time so that it fits
-beside nothing else on one chip.
-
-Equations (decoder-only transformer as the Mistral and SmolLM2 reference
-implementations state them): x += Attn(RMSNorm(x)); x += SwiGLU(RMSNorm(x));
-rotary embedding on interleaved pairs (the convention of the models' own
-reference code; the Hugging Face port permutes the projection columns to use
-half-split pairs instead, which with seeded random weights is the same
-model); grouped-query causal attention with softmax in float32; the loss is
-the mean cross entropy over every position of the batch.
+weights come from ``weights.py`` and the seed, its equations from the
+configuration's family (``chipbench/families/``), whose every matrix product
+goes through ``einsum`` below at ``highest`` precision (on a TPU a float32
+product otherwise runs in bfloat16 passes). Kept here is what is the same
+for every architecture: the walk over sequences and layers (a sequence at a
+time and a layer at a time, so that it fits beside nothing else on one
+chip), the loss (the mean cross entropy over every position of the batch),
+its gradient, AdamW, and the measure of a gap between norms.
 
 ``precision="fp8"`` is the control of "How correct is decided": the same
 equations with both operands of every matrix product rounded to float8
 (e4m3, one scale per tensor), the nearest precision below the bfloat16 that
-both configurations state. It has to come out as not correct.
+the configurations state. It has to come out as not correct.
 """
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import families
 from . import weights as W
-from .costs import head_dim
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
@@ -43,62 +40,12 @@ def _fp8_round(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _einsum(precision, spec, a, b):
+def einsum(precision, spec, a, b):
+    """The one matrix product of every family's equations."""
     if precision == "fp8":
         a, b = _fp8_round(a), _fp8_round(b)
     return jnp.einsum(spec, a, b, precision=HI,
                       preferred_element_type=F32)
-
-
-def rope_tables(seq: int, d: int, theta: float):
-    inv = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
-    ang = np.outer(np.arange(seq, dtype=np.float64), inv)
-    return jnp.asarray(np.cos(ang), F32), jnp.asarray(np.sin(ang), F32)
-
-
-def _rope(x, cos, sin):
-    """x [S, H, D]: rotate pairs (2i, 2i+1) by position * theta^(-2i/D)."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    c, s = cos[:, None, :], sin[:, None, :]
-    return jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], -1).reshape(x.shape)
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def layer_forward(x, w, cos, sin, cfg, precision="f32"):
-    """One decoder layer over one sequence. x [S, hidden] float32; ``w`` the
-    layer's leaves in float32."""
-    es = functools.partial(_einsum, precision)
-    s = x.shape[0]
-    h, kv, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], \
-        head_dim(cfg)
-    eps = cfg["rms_norm_eps"]
-    a = _rms(x, w["ln1"], eps)
-    q = _rope(es("se,ef->sf", a, w["wq"]).reshape(s, h, d), cos, sin)
-    k = _rope(es("se,ef->sf", a, w["wk"]).reshape(s, kv, d), cos, sin)
-    v = es("se,ef->sf", a, w["wv"]).reshape(s, kv, d)
-    g = h // kv
-    qg = q.reshape(s, kv, g, d)
-    scores = es("qkgd,tkd->kgqt", qg, k) / np.sqrt(d)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, -1)
-    ctx = es("kgqt,tkd->qkgd", probs, v).reshape(s, h * d)
-    x = x + es("sf,fe->se", ctx, w["wo"])
-    b = _rms(x, w["ln2"], eps)
-    mlp = jax.nn.silu(es("se,ef->sf", b, w["w_gate"])) \
-        * es("se,ef->sf", b, w["w_up"])
-    return x + es("sf,fe->se", mlp, w["w_down"])
-
-
-def head_logits(x, top, cfg, precision="f32"):
-    """Final norm and output head over rows x [N, hidden]."""
-    x = _rms(x, top["norm"], cfg["rms_norm_eps"])
-    if cfg.get("tie_word_embeddings"):
-        return _einsum(precision, "ne,ve->nv", x, top["embed"])
-    return _einsum(precision, "ne,ev->nv", x, top["lm_head"])
 
 
 def _f32(tree):
@@ -114,22 +61,27 @@ def served_logits(cfg, seed: int, ids: np.ndarray, rows: list,
     dropped). ``rows[n]`` lists the positions of sequence n whose next-token
     logits are wanted. Returns a list of [len(rows[n]), vocab] arrays
     (numpy, float32)."""
+    family = families.of(cfg)
     n, t = ids.shape
-    cos, sin = rope_tables(t, head_dim(cfg), cfg["rope_theta"])
+    tables = family.position_tables(t, cfg)
     top = _f32(W.make_top(cfg, seed))
-    hidden = top["embed"][jnp.asarray(ids)]                  # [N, T, E]
+    hidden = family.embed_tokens(jnp.asarray(ids), top, cfg)   # [N, T, E]
 
     # weights and tables are arguments: a closed-over array would be baked
-    # into the program as a constant and folded on the host
-    @jax.jit
-    def run_layer(hidden, w, cos, sin):
+    # into the program as a constant and folded on the host. One program a
+    # kind of layer: ``layer`` is the first layer of its kind
+    @functools.partial(jax.jit, static_argnums=(3,))
+    def run_layer(hidden, w, tables, layer):
         w = _f32(w)
         return jax.lax.map(
-            lambda x: layer_forward(x, w, cos, sin, cfg, precision), hidden)
+            lambda x: family.layer_forward(x, w, tables, cfg, layer,
+                                           precision), hidden)
 
-    for i in range(cfg["num_hidden_layers"]):
-        hidden = run_layer(hidden, W.make_layer(cfg, seed, i), cos, sin)
-    head = jax.jit(lambda x, top: head_logits(x, top, cfg, precision))
+    kinds = families.layer_kinds(family, cfg)
+    for i, kind in enumerate(kinds):
+        hidden = run_layer(hidden, W.make_layer(cfg, seed, i), tables,
+                           kinds.index(kind))
+    head = jax.jit(lambda x, top: family.head_logits(x, top, cfg, precision))
     longest = max(len(r) for r in rows)
     out = []
     for i, r in enumerate(rows):           # one shape: rows padded by repeat
@@ -144,48 +96,48 @@ def served_logits(cfg, seed: int, ids: np.ndarray, rows: list,
 def init_params(cfg, seed: int) -> dict:
     """The training state's leaves in float32 (the rounded bfloat16 values,
     as the optimizer's master copy starts from them): stacked layers plus
-    embedding and final norm (and the head where untied)."""
+    the leaves outside them."""
     p = dict(_f32(W.make_stack(cfg, seed)))
     p.update(_f32(W.make_top(cfg, seed)))
     return p
 
 
-def _row_loss(params, ids, labels, cos, sin, cfg, precision, denom):
-    layers = {k: params[k] for k in W.LAYER_LEAVES}
-    top = {k: v for k, v in params.items() if k not in W.LAYER_LEAVES}
+def _row_loss(family, params, ids, labels, tables, cfg, precision, denom):
+    leaves = set(family.layer_shapes(cfg, 0))
+    layers = {k: v for k, v in params.items() if k in leaves}
+    top = {k: v for k, v in params.items() if k not in leaves}
 
     @jax.checkpoint
-    def body(x, w):
-        return layer_forward(x, w, cos, sin, cfg, precision), None
+    def body(x, w):                # init_params stacked layers of one kind
+        return family.layer_forward(x, w, tables, cfg, 0, precision), None
 
-    x, _ = jax.lax.scan(body, params["embed"][ids], layers)
-    logits = head_logits(x, top, cfg, precision)
+    x, _ = jax.lax.scan(body, family.embed_tokens(ids, top, cfg), layers)
+    logits = family.head_logits(x, top, cfg, precision)
     logp = jax.nn.log_softmax(logits, -1)
     return -jnp.sum(jnp.take_along_axis(logp, labels[:, None], 1)) / denom
 
 
 @functools.lru_cache(maxsize=None)
-def _row_grad(cfg_items, precision, denom):
+def _row_grad(cfg_json, precision, denom):
     """One compiled loss-and-gradient of a row for each configuration and
     precision, whatever the step."""
-    cfg = dict(cfg_items)
+    cfg = json.loads(cfg_json)
+    family = families.of(cfg)
     return jax.jit(jax.value_and_grad(
-        lambda p, i, l, cos, sin: _row_loss(p, i, l, cos, sin, cfg,
-                                            precision, denom)))
+        lambda p, i, l, tables: _row_loss(family, p, i, l, tables, cfg,
+                                          precision, denom)))
 
 
 def loss_and_grads(params, ids, labels, cfg, precision="f32"):
     """Mean cross entropy over the whole batch and its gradient, a row at a
     time. ids, labels: [B, S] int."""
     b, s = ids.shape
-    cos, sin = rope_tables(s, head_dim(cfg), cfg["rope_theta"])
-    fn = _row_grad(tuple(sorted((k, v) for k, v in cfg.items()
-                                if isinstance(v, (int, float, bool)))),
-                   precision, float(b * s))
+    tables = families.of(cfg).position_tables(s, cfg)
+    fn = _row_grad(json.dumps(cfg, sort_keys=True), precision, float(b * s))
     loss, grads = 0.0, None
     for r in range(b):
         l_r, g_r = fn(params, jnp.asarray(ids[r]), jnp.asarray(labels[r]),
-                      cos, sin)
+                      tables)
         loss = loss + l_r
         grads = g_r if grads is None else jax.tree_util.tree_map(
             jnp.add, grads, g_r)

@@ -17,23 +17,23 @@ import time
 
 import numpy as np
 
+from . import families
 from . import harness as H
 from . import traffic as T
 from . import weights as W
-from .train import llama_config, set_flags
+from .train import set_flags
 
 
 def build(cfg: dict, seed: int):
     import paddle_tpu as paddle
     from paddle_tpu.inference.gateway import Gateway
     from paddle_tpu.inference.serving import PagedContinuousBatcher
-    from paddle_tpu.models.llama import LlamaForCausalLM
 
     # one program for every --seed: the generator's key ends up as a
     # constant of the compiled step (PERF.md, finding of PR 24), and the
     # weights, ids and order come from --seed through chipbench itself
     paddle.seed(0)
-    model = LlamaForCausalLM(llama_config(cfg, dtype="bfloat16"))
+    model = families.of(cfg).program_model(cfg, dtype="bfloat16")
     model.bfloat16()
     model.eval()
     W.install(model, cfg, seed, scanned=False)
@@ -142,12 +142,33 @@ def snapshot(batcher):
             "queue_wait_sum": qw.sum, "queue_wait_count": qw.count}
 
 
+def read_series(series) -> dict:
+    """The registry series a family lists as ``(key, name, labels)``: a
+    counter's or a gauge's value under ``key``, a histogram's under
+    ``key.sum`` and ``key.count``; nothing for a series the program has not
+    made yet (it has counted nothing)."""
+    from paddle_tpu.observability.metrics import get_registry
+    out = {}
+    for key, name, labels in series:
+        entry = get_registry().get(name)
+        if entry is None:
+            continue
+        if labels:
+            entry = entry.labels(**labels)
+        if entry.kind == "histogram":
+            out[key + ".sum"], out[key + ".count"] = entry.sum, entry.count
+        else:
+            out[key] = entry.value
+    return out
+
+
 def run(ctx) -> None:
     import jax
     from paddle_tpu.observability import opprof
 
     args, cfg, traffic = ctx["args"], ctx["cfg"], ctx["traffic"]
     seed, vocab = args.seed, cfg["vocab_size"]
+    series = families.of(cfg).COUNTERS
     open_loop = traffic["kind"] == "serve-open"
     set_flags(cfg)
     opprof.enable()
@@ -170,6 +191,7 @@ def run(ctx) -> None:
     tracer = ctx["tracer"]
     drv = Driver(gateway, tracer)
     before, stats0 = counter.snapshot(), snapshot(batcher)
+    series0 = read_series(series)
     tracer.start()
     setup_s = H.clock() - ctx["t0"]
     t_open = H.clock()
@@ -187,7 +209,7 @@ def run(ctx) -> None:
     window_tokens = sum(sum(1 for x in r.times if x <= t_close)
                         for r in drv.finished + drv.live)
     elapsed = t_close - t_open
-    stats1 = snapshot(batcher)
+    stats1, series1 = snapshot(batcher), read_series(series)
     window_steps = list(drv.step_ms)
     tracer.stop()              # the trace is of the window, not of the drain
     # the drain: requests in flight finish, outside the window
@@ -266,7 +288,9 @@ def run(ctx) -> None:
                        "miss_tokens": d["miss_tokens"],
                        "queue_wait_sum": d["queue_wait_sum"],
                        "queue_wait_count": d["queue_wait_count"],
-                       "decode_context_tokens": context})
+                       "decode_context_tokens": context,
+                       "counters": {k: v - series0.get(k, 0)
+                                    for k, v in series1.items()}})
 
 
 def pick_sample(finished, n: int, seed: int):

@@ -71,8 +71,7 @@ def test_a_part_of_the_batch_left_out(capsys, monkeypatch):
     assert line["correct"] is False
 
 
-@pytest.mark.parametrize("cell", ["mistral7b-chat-batch",
-                                  "mistral7b-doc-sessions"])
+@pytest.mark.parametrize("cell", ["mistral7b-doc-sessions"])
 def test_a_token_altered_where_it_is_produced(capsys, monkeypatch, cell):
     from paddle_tpu.inference.serving import PagedContinuousBatcher
     real = PagedContinuousBatcher._pick

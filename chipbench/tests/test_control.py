@@ -23,7 +23,8 @@ def limits(cell):
 
 @pytest.mark.parametrize("seed", [1, 2, 2 ** 31 + 3])
 def test_float8_training_is_not_correct(seed):
-    cfg = dict(hidden_size=64, intermediate_size=176, num_hidden_layers=2,
+    cfg = dict(family="llama", hidden_size=64, intermediate_size=176,
+               num_hidden_layers=2,
                num_attention_heads=4, num_key_value_heads=4, vocab_size=256,
                rms_norm_eps=1e-5, rope_theta=130000,
                tie_word_embeddings=True, initializer_range=0.02)
@@ -47,7 +48,8 @@ def test_float8_training_is_not_correct(seed):
                                   "mistral7b-doc-sessions"])
 @pytest.mark.parametrize("seed", [1, 2, 2 ** 31 + 3])
 def test_float8_serving_is_not_correct(cell, seed):
-    cfg = dict(hidden_size=128, intermediate_size=384, num_hidden_layers=8,
+    cfg = dict(family="llama", hidden_size=128, intermediate_size=384,
+               num_hidden_layers=8,
                num_attention_heads=4, num_key_value_heads=2, vocab_size=512,
                rms_norm_eps=1e-5, rope_theta=1e6, tie_word_embeddings=False,
                initializer_range=0.1)

@@ -7,19 +7,24 @@ import os
 import pytest
 
 from chipbench import harness as H
-from chipbench import lastline, phases as P, xplane as X
+from chipbench import phases as P, xplane as X
 from chipbench.peaks import peaks_for
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RECORDED = os.path.join(HERE, "data", "trace_serve_3steps.json.gz")
 
 
+TRACE_METRICS = (
+    "decode_device_ms.batch", "decode_device_ms.sessions",
+    "prefill_chunk_device_ms.sessions", "paged_attn_roofline",
+    "gap_fetch_ms.batch", "gap_host_loop_ms.batch", "admit_ms.sessions",
+    "gap_admit_ms.sessions")
+
+
 @pytest.fixture
 def fresh(monkeypatch):
-    """No analysis kept from another test, and the harness's own check of
-    the last line put back afterwards."""
+    """No analysis kept from another test."""
     monkeypatch.setattr(P, "_ANALYSES", {})
-    monkeypatch.setattr(lastline, "problems", lastline.problems)
 
 
 def test_recorded_serving_steps():
@@ -69,7 +74,7 @@ def test_every_new_reader_reads_the_recorded_steps(fresh, monkeypatch,
            "peaks": peaks_for("TPU v5 lite"),
            "cfg": H.load_config("mistral-7b-v0.3-serve-d16", False),
            "decode_context_tokens": 3 * 32 * 400}
-    values = {name: H.read_metric(name, run) for name in P.NEW_METRICS}
+    values = {name: H.read_metric(name, run) for name in TRACE_METRICS}
     assert all(v is not None and math.isfinite(v) for v in values.values())
     assert values["decode_device_ms.batch"] == pytest.approx(150.38, abs=0.05)
     assert values["decode_device_ms.sessions"] \
@@ -141,7 +146,6 @@ def test_executions_are_counted_whole_and_clipped():
     assert row["seconds"] == pytest.approx(500e-9)     # busy inside them
     assert row["whole_s"] == pytest.approx(300e-9)
     assert "no_module" not in a["by_executable"]
-    assert a["has_names"]
 
 
 def test_scope_from_the_instruction_or_its_first_operand():
@@ -157,32 +161,33 @@ def test_scope_from_the_instruction_or_its_first_operand():
                       "head": pytest.approx(200e-9)}
 
 
-def test_a_program_without_names_leaves_the_new_metrics_out(fresh,
-                                                            monkeypatch,
-                                                            tmp_path):
-    """The parent of PR 25: every module is jit_pure and no span is the
-    program's. The readers return None and the line may leave them out."""
-    path = tmp_path / "parent.json"
+def test_a_program_without_names_gives_the_readers_nothing(fresh,
+                                                           monkeypatch,
+                                                           tmp_path):
+    """A trace in which every module is jit_pure and no span is the
+    program's: the readers of the trace's names return None, never 0."""
+    path = tmp_path / "unnamed.json"
     path.write_text(json.dumps(synthetic(
         [["gateway.step", 50, 900]],
         modules=[["jit_pure(1)", 90, 320], ["jit_pure(2)", 590, 220]])))
     monkeypatch.setattr(P, "newest_trace", lambda: str(path))
-    bench = {"end_to_end": [{"name": "serve_tokens_per_s", "unit": "t/s"}],
-             "per_layer": [{"name": n, "unit": "ms",
-                            "moves": "serve_tokens_per_s"}
-                           for n in ("old_metric",) + P.NEW_METRICS]}
-    line = {"correct": True, "attempted": 1, "failed": 0,
-            "metrics": {"old_metric": {"value": 1.0, "unit": "ms"}},
-            "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
-                       "memory_peak_bytes": 1, "busy_s": 1.0,
-                       "window_s": 2.0}}
-    assert len(lastline.problems(line, bench, "w", True, 1)) == 8
     run = {"trace": X.reduce(X.load(str(path)), H.SPANS), "peaks": None,
-           "cfg": {}, "decode_context_tokens": 10}
+           "cfg": {"family": "llama"}, "decode_context_tokens": 10}
     assert all(H.read_metric(n, run) is None
-               for n in P.NEW_METRICS if "gap_host" not in n)
-    assert not P.of_run(run)["has_names"]
-    assert lastline.problems(line, bench, "w", True, 1) == []
-    del line["metrics"]["old_metric"]      # what the cell had still counts
-    assert lastline.problems(line, bench, "w", True, 1) == [
-        "metric 'old_metric' is missing"]
+               for n in TRACE_METRICS if "gap_host" not in n)
+    assert P.of_run(run)["span_counts"] == {}
+
+
+def test_a_family_adds_its_spans_and_scopes_to_the_base_ones():
+    spans = [["gateway.step", 50, 900], ["family.summarise", 350, 150]]
+    base = P.analyse(synthetic(spans))
+    assert "family.summarise" not in base["gaps"]
+    own = P.analyse(synthetic(spans), P.PROGRAM_SPANS + ("family.summarise",))
+    assert own["gaps"]["family.summarise"] == pytest.approx(100e-9)
+    assert own["span_counts"] == {"family.summarise": 1}
+    assert sum(own["gaps"].values()) == pytest.approx(sum(
+        base["gaps"].values()))
+    op = "jit(step)/jit(main)/paged_attention/chunk_summary/reduce"
+    assert P.scope_of(op) == "paged_attention"
+    assert P.scope_of(op, P.SCOPES + ("chunk_summary",)) \
+        == "paged_attention/chunk_summary"

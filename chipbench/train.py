@@ -11,19 +11,10 @@ import gc
 
 import numpy as np
 
+from . import families
 from . import harness as H
 from . import traffic as T
 from . import weights as W
-
-
-def llama_config(cfg: dict, **extra):
-    from paddle_tpu.models.llama import LlamaConfig
-    keys = ("vocab_size", "hidden_size", "intermediate_size",
-            "num_hidden_layers", "num_attention_heads",
-            "num_key_value_heads", "max_position_embeddings",
-            "rms_norm_eps", "rope_theta", "tie_word_embeddings",
-            "initializer_range")
-    return LlamaConfig(**{k: cfg[k] for k in keys}, **extra)
 
 
 def set_flags(cfg: dict):
@@ -36,14 +27,13 @@ def build(cfg: dict, seed: int):
     weights installed."""
     import paddle_tpu as paddle
     from paddle_tpu import amp, jit, optimizer
-    from paddle_tpu.models.llama import LlamaForCausalLM
 
     run = cfg["runner"]
     # one program for every --seed: the generator's key ends up as a
     # constant of the compiled step (PERF.md, finding of PR 24), and the
     # weights, ids and order come from --seed through chipbench itself
     paddle.seed(0)
-    model = LlamaForCausalLM(llama_config(cfg, **run["model"]))
+    model = families.of(cfg).program_model(cfg, **run["model"])
     o = run["optimizer"]
     params = list(model.parameters())
     opt = optimizer.AdamW(learning_rate=o["learning_rate"], beta1=o["beta1"],
@@ -60,11 +50,11 @@ def build(cfg: dict, seed: int):
     return model, opt, step
 
 
-def leaf_of(name: str) -> str:
-    """Our leaf name for one of the program's parameter names."""
-    inv = {v: k for k, v in W._SCANNED.items()}
-    inv.update({v: k for k, v in W._TOP.items()})
-    return inv.get(name) or inv[name.rsplit(".", 1)[-1]]
+def leaves_by_parameter(cfg: dict) -> dict:
+    """{the program's parameter name: our leaf} of the scanned model."""
+    family = families.of(cfg)
+    leaves = list(family.top_shapes(cfg)) + list(family.layer_shapes(cfg, 0))
+    return {family.parameter_name(leaf, None, True): leaf for leaf in leaves}
 
 
 def restore(model, opt, cfg: dict, seed: int):
@@ -98,19 +88,20 @@ def _by_state_prefix(model) -> dict:
             for i, (n, p) in enumerate(model.named_parameters())}
 
 
-def state_norms(model, opt, acc: str, minus_seed=None) -> dict:
+def state_norms(model, opt, cfg: dict, acc: str, minus_seed=None) -> dict:
     """Leaf norms of one optimizer accumulator (or of the master weights
     less the seed's weights), read from ``Optimizer.state_dict()``."""
     import jax
     import jax.numpy as jnp
     params = _by_state_prefix(model)
+    leaf_of = leaves_by_parameter(cfg)
     norm = jax.jit(lambda a, b: jnp.sqrt(jnp.sum(jnp.square(
         a.astype(jnp.float32) - b.astype(jnp.float32)))))
     out = {}
     for key, value in opt.state_dict().items():
         if not key.endswith("." + acc):
             continue
-        leaf = leaf_of(params[key.rsplit(".", 1)[0]][0])
+        leaf = leaf_of[params[key.rsplit(".", 1)[0]][0]]
         base = minus_seed[leaf] if minus_seed else jnp.zeros((), jnp.float32)
         out[leaf] = float(norm(value._data, base))
     return out
@@ -173,10 +164,10 @@ def run(ctx) -> None:
         if k == 1:
             prog["grad_norms"] = {
                 leaf: n / (1.0 - b1) for leaf, n in
-                state_norms(model, opt, "moment1").items()}
+                state_norms(model, opt, cfg, "moment1").items()}
     seed_w = dict(W.make_stack(cfg, seed))
     seed_w.update(W.make_top(cfg, seed))
-    prog["delta_norms"] = state_norms(model, opt, "master_weight", seed_w)
+    prog["delta_norms"] = state_norms(model, opt, cfg, "master_weight", seed_w)
     del seed_w
     phases["first_steps"] = H.clock() - t
     H.say("fingerprints", H.program_fingerprints())

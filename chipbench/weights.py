@@ -5,10 +5,12 @@ here: the program has them installed into its model (``Parameter.set_value``),
 the plain reference makes them again from the seed after the program's state
 is freed. Neither takes anything the other has made.
 
-Every leaf is its own stream (seed, leaf name, layer), so one layer, or the
-whole stacked model, is one jitted call on the device. Values are drawn in
-float32 and rounded to bfloat16, the type both configurations hold them in;
-the float32 master copy of training starts from the rounded value.
+Which leaves a model has, their shapes, how each is drawn and what the
+program calls it are its family's (``chipbench/families/``). Every leaf is
+its own stream (seed, leaf name, layer), so one layer, or the whole stacked
+model, is one jitted call on the device. Values are drawn in float32 and
+rounded to bfloat16, the type the configurations hold them in; the float32
+master copy of training starts from the rounded value.
 """
 from __future__ import annotations
 
@@ -17,26 +19,7 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-LAYER_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-                "ln1", "ln2")
-
-
-def layer_shapes(cfg) -> dict:
-    from .costs import head_dim
-    hs, d = cfg["hidden_size"], head_dim(cfg)
-    h, kv, ims = cfg["num_attention_heads"], cfg["num_key_value_heads"], \
-        cfg["intermediate_size"]
-    return {"wq": (hs, h * d), "wk": (hs, kv * d), "wv": (hs, kv * d),
-            "wo": (h * d, hs), "w_gate": (hs, ims), "w_up": (hs, ims),
-            "w_down": (ims, hs), "ln1": (hs,), "ln2": (hs,)}
-
-
-def top_shapes(cfg) -> dict:
-    hs, v = cfg["hidden_size"], cfg["vocab_size"]
-    shapes = {"embed": (v, hs), "norm": (hs,)}
-    if not cfg.get("tie_word_embeddings"):
-        shapes["lm_head"] = (hs, v)
-    return shapes
+from . import families
 
 
 def seed_key(seed: int):
@@ -46,38 +29,41 @@ def seed_key(seed: int):
                               seed >> 31)
 
 
-def _leaf(key, name, shape, std):
+def _leaf(key, name, shape, how, value):
     k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
     x = jax.random.normal(k, shape, jnp.float32)
-    if len(shape) == 1:            # norm gains: around one, not all equal
-        return (1.0 + 0.1 * x).astype(jnp.bfloat16)
-    return (std * x).astype(jnp.bfloat16)
+    if how == "gain":              # around its centre, not all equal
+        return (value + 0.1 * x).astype(jnp.bfloat16)
+    if how == "matrix":
+        return (value * x).astype(jnp.bfloat16)
+    raise ValueError(f"leaf {name!r}: drawn {how!r}, not as a matrix or a "
+                     f"gain")
 
 
-def _layer(key, cfg_items, layer):
-    cfg = dict(cfg_items)
+def _table(family, cfg, shapes: dict) -> tuple:
+    """((leaf, shape, how, value), ...): what a jitted maker is keyed by."""
+    return tuple((n, tuple(s)) + tuple(family.leaf_draw(cfg, n))
+                 for n, s in shapes.items())
+
+
+def _layer(key, table, layer):
     k = jax.random.fold_in(key, layer + 1)
-    return {n: _leaf(k, n, s, cfg["initializer_range"])
-            for n, s in layer_shapes(cfg).items()}
-
-
-def _freeze(cfg):
-    keys = ("hidden_size", "intermediate_size", "num_attention_heads",
-            "num_key_value_heads", "vocab_size", "initializer_range",
-            "tie_word_embeddings", "head_dim")
-    return tuple((k, cfg[k]) for k in keys if k in cfg)
+    return {n: _leaf(k, n, *rest) for n, *rest in table}
 
 
 _make_layer = jax.jit(_layer, static_argnums=(1,))
 
 
 def make_layer(cfg, seed: int, layer: int) -> dict:
-    """One decoder layer's leaves ([in, out] matrices, bfloat16)."""
-    return _make_layer(seed_key(seed), _freeze(cfg), jnp.int32(layer))
+    """One layer's leaves ([in, out] matrices, bfloat16)."""
+    family = families.of(cfg)
+    return _make_layer(seed_key(seed),
+                       _table(family, cfg, family.layer_shapes(cfg, layer)),
+                       jnp.int32(layer))
 
 
-def _stack(key, cfg_items, n_layers):
-    return jax.vmap(lambda i: _layer(key, cfg_items, i))(
+def _stack(key, table, n_layers):
+    return jax.vmap(lambda i: _layer(key, table, i))(
         jnp.arange(n_layers, dtype=jnp.int32))
 
 
@@ -85,58 +71,57 @@ _make_stack = jax.jit(_stack, static_argnums=(1, 2))
 
 
 def make_stack(cfg, seed: int) -> dict:
-    """All layers stacked on a leading axis, in one call."""
-    return _make_stack(seed_key(seed), _freeze(cfg),
+    """All layers stacked on a leading axis, in one call. Only for a model
+    whose layers are all of one kind."""
+    family = families.of(cfg)
+    kinds = set(families.layer_kinds(family, cfg))
+    if len(kinds) > 1:
+        raise ValueError(f"layers of kinds {sorted(map(str, kinds))} do not "
+                         f"stack")
+    return _make_stack(seed_key(seed),
+                       _table(family, cfg, family.layer_shapes(cfg, 0)),
                        cfg["num_hidden_layers"])
 
 
-def _top(key, cfg_items):
-    cfg = dict(cfg_items)
+def _top(key, table):
     k = jax.random.fold_in(key, 0)
-    return {n: _leaf(k, n, s, cfg["initializer_range"])
-            for n, s in top_shapes(cfg).items()}
+    return {n: _leaf(k, n, *rest) for n, *rest in table}
 
 
 _make_top = jax.jit(_top, static_argnums=(1,))
 
 
 def make_top(cfg, seed: int) -> dict:
-    """Embedding, final norm and (where untied) the output head."""
-    return _make_top(seed_key(seed), _freeze(cfg))
-
-
-# names of the program's parameters for each of our leaves
-_SCANNED = {"wq": "q_w", "wk": "k_w", "wv": "v_w", "wo": "o_w",
-            "w_gate": "gate_w", "w_up": "up_w", "w_down": "down_w",
-            "ln1": "ln1_w", "ln2": "ln2_w"}
-_UNROLLED = {"wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
-             "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
-             "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
-             "w_down": "mlp.down_proj.weight",
-             "ln1": "input_layernorm.weight",
-             "ln2": "post_attention_layernorm.weight"}
-_TOP = {"embed": "model.embed_tokens.weight", "norm": "model.norm.weight",
-        "lm_head": "lm_head.weight"}
+    """The leaves outside the layers: embedding, final norm, output head."""
+    family = families.of(cfg)
+    return _make_top(seed_key(seed),
+                     _table(family, cfg, family.top_shapes(cfg)))
 
 
 def install(model, cfg, seed: int, scanned: bool) -> None:
     """Put the seed's weights into the program's model through its public
-    ``named_parameters`` / ``set_value``. Every parameter must be covered."""
+    ``named_parameters`` / ``set_value``. Every parameter must be covered,
+    and every leaf must have its parameter."""
+    name_of = families.of(cfg).parameter_name
     params = dict(model.named_parameters())
     todo = set(params)
 
-    def put(name, value):
+    def put(leaf, layer, value):
+        name = name_of(leaf, layer, scanned)
+        if name not in params:
+            raise RuntimeError(f"leaf {leaf!r}: the program's model has no "
+                               f"parameter {name!r}")
         params[name].set_value(value)
         todo.discard(name)
 
     for leaf, value in make_top(cfg, seed).items():
-        put(_TOP[leaf], value)
+        put(leaf, None, value)
     if scanned:
         for leaf, value in make_stack(cfg, seed).items():
-            put(f"model.layers_scanned.{_SCANNED[leaf]}", value)
+            put(leaf, None, value)
     else:
         for i in range(cfg["num_hidden_layers"]):
             for leaf, value in make_layer(cfg, seed, i).items():
-                put(f"model.layers.{i}.{_UNROLLED[leaf]}", value)
+                put(leaf, i, value)
     if todo:
         raise RuntimeError(f"parameters without seed weights: {sorted(todo)}")
