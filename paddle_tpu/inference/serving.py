@@ -823,6 +823,20 @@ class PagedContinuousBatcher(_BatcherBase):
 
         if policy not in ("reserve", "ondemand"):
             raise ValueError(f"unknown policy {policy!r}")
+        # a model whose cache is more than pages of K and V says so (see
+        # ``SambaYForCausalLM.paged_serving_contract``): per-slot state
+        # beside the pool, and the options it cannot honour
+        contract = getattr(model, "paged_serving_contract", dict)()
+        asked = dict(prefix_cache=prefix_cache, kv_quant=kv_quant,
+                     cache_quant=cache_quant, tier_quant=tier_quant,
+                     draft_model=draft_model,
+                     fused_admission=fused_admission,
+                     session_store=session_store)
+        for option, why in contract.get("unsupported", {}).items():
+            if asked.get(option):
+                raise ValueError(
+                    f"{option} is not supported for "
+                    f"{type(model).__name__}: {why}")
         if promo_slots < 1:
             raise ValueError("promo_slots must be >= 1")
         if promo_chunk_blocks is not None and promo_chunk_blocks < 1:
@@ -1088,9 +1102,12 @@ class PagedContinuousBatcher(_BatcherBase):
         self.cache_quant = cache_quant
         self.kv_quant = kv_quant
         self.tier_quant = tier_quant
+        self._slot_state = bool(contract.get("slot_state"))
         pool = model.paged_alloc(
             n_pages + 1, block_size,
-            cache_dtype="int8" if cache_quant else None)
+            cache_dtype="int8" if cache_quant else None,
+            **({"max_batch": max_batch} if self._slot_state else {}))
+        self._init_slot_state_series(contract, pool)
         # paged_alloc auto-allocates int8 pages whenever the model
         # carries calibrated static scales — kv_quant='int8' is the
         # explicit contract (validated above), but the gauge reflects
@@ -1248,10 +1265,13 @@ class PagedContinuousBatcher(_BatcherBase):
             # one fixed-width append executable serves EVERY prompt
             # length (vLLM chunked prefill); without it each distinct
             # prompt length costs a fresh prefill compile
-            def _chunk(ids, layers, bt_row, dec, at):
+            # ``slot_args``: a model with per-slot state is told its slot
+            # and how many of the chunk's rows are real (``_slot_args``);
+            # the pad rows of a fixed-width chunk must leave the state alone
+            def _chunk(ids, layers, bt_row, dec, at, **slot_args):
                 return model.paged_prefill_into(
                     ids, layers, bt_row, block_size, dec_base=dec,
-                    logits_at=at)
+                    logits_at=at, **slot_args)
             if compile:
                 from .. import jit
                 # donate the pool (arg 1) exactly like the decode step —
@@ -1291,7 +1311,68 @@ class PagedContinuousBatcher(_BatcherBase):
                     self._chunk_dyn_first_fn = _chunk_dyn_first
                     self._chunk_dyn_rest_fn = _chunk_dyn_rest
 
+    # -- per-slot state beside the pool ---------------------------------------
+    def _init_slot_state_series(self, contract: dict, pool):
+        """Bytes of state a slot holds whatever its length (0 where the
+        cache is pages alone), the rows and rings of the model's window
+        layers, and the registry series that follow them. The host never
+        touches that state: a chunk at row 0 starts it from zero inside the
+        executable, a released or preempted slot's is abandoned."""
+        import jax
+        from ..core.tensor import Tensor
+        from ..observability.metrics import get_registry
+        leaves = jax.tree_util.tree_leaves(
+            pool["slots"], is_leaf=lambda t: isinstance(t, Tensor)) \
+            if self._slot_state else []
+        total = sum(int(np.prod(t.shape)) * t._data.dtype.itemsize
+                    for t in leaves)
+        self._slot_state_bytes = total // self.max_batch
+        self._window_rows = int(contract.get("window_rows", 0))
+        self._window_rings = int(contract.get("window_rings", 0))
+        reg = get_registry()
+        reg.gauge("serving.recurrent_state_bytes",
+                  "bytes of per-slot state (recurrent state, window rings) "
+                  "allocated beside the page pool").set(total)
+        self._state_reset_c = reg.counter(
+            "serving.state_resets",
+            "admissions that started a slot's state from zero")
+        self._window_drop_c = reg.counter(
+            "serving.window_rows_overwritten",
+            "rows the window rings dropped past the window, all rings "
+            "together")
+        self._window_read_c = reg.counter(
+            "serving.window_rows_read",
+            "rows inside the window that decode steps read, one ring's")
+
+    def _count_slot_state_admit(self, n_rows: int):
+        if self._slot_state:
+            self._state_reset_c.inc()
+            self._window_drop_c.inc(
+                max(0, n_rows - self._window_rows) * self._window_rings)
+
+    def _count_slot_state_step(self):
+        """A decode step writes row ``dec`` of every running slot."""
+        if not self._window_rings:
+            return
+        dec = self._dec[list(self._slot_req)]
+        self._window_read_c.inc(int(np.minimum(dec + 1,
+                                               self._window_rows).sum()))
+        self._window_drop_c.inc(int((dec >= self._window_rows).sum())
+                                * self._window_rings)
+
+    def _slot_args(self, slot: int, n_valid: int) -> dict:
+        """What ``paged_prefill_into`` takes besides, where the model keeps
+        per-slot state."""
+        if not self._slot_state:
+            return {}
+        import paddle_tpu as paddle
+        return {"slot": paddle.to_tensor(np.array([slot], np.int32)),
+                "n_valid": paddle.to_tensor(np.array([n_valid], np.int32))}
+
     # -- page accounting ----------------------------------------------------
+    # A block-table page backs ``block_size`` rows of whatever the model
+    # keeps in its pool: every layer's K and V, or one layer's that others
+    # read. Layers that hold a window or a recurrent state hold no pages.
     def _pages_for(self, n_rows: int) -> int:
         return -(-n_rows // self.block_size)
 
@@ -1864,7 +1945,8 @@ class PagedContinuousBatcher(_BatcherBase):
                     self.prefix_cache.unpin(matched)
                 break
             with _span("serving.admit", rid=req.rid,
-                       prompt_tokens=len(ids_np), hit_tokens=m_rows):
+                       prompt_tokens=len(ids_np), hit_tokens=m_rows,
+                       state_bytes=self._slot_state_bytes, carried=0):
                 with self._intake:
                     self._pending.pop(0)
                 self._promo_denied.discard(req.rid)
@@ -1876,6 +1958,7 @@ class PagedContinuousBatcher(_BatcherBase):
                                        "passed but allocation failed")
                 self._trace_admit_begin(req)
                 self._trace_prefill_begin(req)
+                self._count_slot_state_admit(L)
                 bt_row = paddle.to_tensor(self._bt[slot:slot + 1])
                 S = L - m_rows
                 with paddle.no_grad():
@@ -1908,13 +1991,15 @@ class PagedContinuousBatcher(_BatcherBase):
                                 dec_base=paddle.to_tensor(
                                     np.array([m_rows], np.int32)),
                                 logits_at=paddle.to_tensor(
-                                    np.array([S - 1], np.int32)))
+                                    np.array([S - 1], np.int32)),
+                                **self._slot_args(slot, S))
                     else:
                         ids = paddle.to_tensor(ids_np[None, :])
                         logits, self._state["layers"] = \
                             self.model.paged_prefill_into(
                                 ids, self._state["layers"], bt_row,
-                                self.block_size)
+                                self.block_size,
+                                **self._slot_args(slot, L))
                     if self.draft_model is not None:
                         # mirror the suffix into the DRAFT pool (same block-
                         # table row, its own physical pages); cached pages
@@ -2019,13 +2104,16 @@ class PagedContinuousBatcher(_BatcherBase):
             w = min(C, padded_len - dec)     # tail shortens at capacity
             has_last = 0 <= (L - 1) - dec < w
             at = (L - 1) - dec if has_last else 0
-            with _span("serving.prefill_chunk", rid=rid):
+            with _span("serving.prefill_chunk", rid=rid,
+                       state_bytes=self._slot_state_bytes,
+                       carried=int(self._slot_state and dec0 + dec > 0)):
                 ids_t = paddle.to_tensor(padded[None, dec:dec + w])
                 dec_t = paddle.to_tensor(np.array([dec0 + dec], np.int32))
                 at_t = paddle.to_tensor(np.array([at], np.int32))
                 if not self.cache_quant:
                     lg, self._state["layers"] = self._chunk_fn(
-                        ids_t, self._state["layers"], bt_row, dec_t, at_t)
+                        ids_t, self._state["layers"], bt_row, dec_t, at_t,
+                        **self._slot_args(slot, min(L - dec, w)))
                 elif scales is None:
                     first_nvalid = min(L - dec, w)
                     nvalid = paddle.to_tensor(
@@ -2399,6 +2487,7 @@ class PagedContinuousBatcher(_BatcherBase):
         t0 = _time.perf_counter()
         with _span("serving.launch"), paddle.no_grad():
             self._decode_launch_c.inc()
+            self._count_slot_state_step()
             tok_t = paddle.to_tensor(self._last_tok)
             logits, self._state = self._step_fn(tok_t, self._state)
         self._advance_decoders(logits, finished)

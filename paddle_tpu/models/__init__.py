@@ -16,6 +16,7 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
 from .gpt import GPT2Config, GPT2ForCausalLM, GPT2Model, gpt2_124m_config
 from .resnet import (BasicBlock, BottleneckBlock, ResNet, resnet18, resnet34,
                      resnet50, resnet101, resnet152)
+from .sambay import SambaYConfig, SambaYForCausalLM, sambay_tiny_config
 from .unet import (UNetConfig, UNetModel, ddim_sample, ddpm_loss,
                    sd_unet_config, unet_tiny_config)
 
@@ -27,6 +28,7 @@ __all__ = [
     "BertForMaskedLM", "bert_base_config", "bert_tiny_config", "shard_bert",
     "ResNet", "BasicBlock", "BottleneckBlock", "resnet18", "resnet34",
     "resnet50", "resnet101", "resnet152",
+    "SambaYConfig", "SambaYForCausalLM", "sambay_tiny_config",
     "UNetConfig", "UNetModel", "unet_tiny_config", "sd_unet_config",
     "ddpm_loss", "ddim_sample",
 ]

@@ -158,9 +158,10 @@ def _decode_kernel(lens_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret",
+                                             "scale"))
 def _decode_call(kv_len, block_tables, q, key_cache, value_cache, *,
-                 pages_per_block, interpret):
+                 pages_per_block, interpret, scale=None):
     """The launch, jitted on its own: a step calls it once a layer, and
     the eager first call of a ``to_static`` step would otherwise trace,
     lower and compile the kernel anew for every layer (57 s of set-up at
@@ -174,7 +175,7 @@ def _decode_call(kv_len, block_tables, q, key_cache, value_cache, *,
         functools.partial(_decode_kernel, batch=batch,
                           blocks_per_seq=blocks_per_seq,
                           pages_per_block=pages_per_block,
-                          scale=1.0 / float(hd) ** 0.5),
+                          scale=scale or 1.0 / float(hd) ** 0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(batch,),
@@ -201,11 +202,14 @@ def _decode_call(kv_len, block_tables, q, key_cache, value_cache, *,
 
 
 def paged_attention_decode(q, key_cache, value_cache, block_tables, kv_len,
-                           *, pages_per_block=None, interpret=False):
+                           *, pages_per_block=None, interpret=False,
+                           scale=None):
     """q [B, H, D] (after RoPE) against the pool ``[n_pages, KV, block, D]``
     through ``block_tables [B, blocks_per_seq]``; ``kv_len [B]`` rows of
     each sequence count, this step's row among them (it is in the pool
-    already). Returns [B, H, D] in q's dtype."""
+    already). ``scale`` multiplies the scores (``D ** -0.5`` where None: a
+    caller whose rows are wider than its heads says so). Returns [B, H, D]
+    in q's dtype."""
     batch, heads, hd = q.shape
     _, kvh, bs, _ = key_cache.shape
     if pages_per_block is None:
@@ -215,7 +219,7 @@ def paged_attention_decode(q, key_cache, value_cache, block_tables, kv_len,
                                      _BUFFER_BYTES // (4 * page_bytes)))
     out = kernel_call(
         functools.partial(_decode_call, pages_per_block=pages_per_block,
-                          interpret=interpret),
+                          interpret=interpret, scale=scale),
         kv_len.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1),
         q.reshape(batch, kvh, heads // kvh, hd), key_cache, value_cache)
     return out.reshape(batch, heads, hd)

@@ -307,3 +307,48 @@ def test_scanned_decoder_layer_fwd_bwd_compiles(chip, monkeypatch):
                         sds((SEQ, HEAD_DIM // 2)), sds((SEQ, HEAD_DIM // 2)))
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert _kernel_calls(compiled, kernel), kernel
+
+
+def test_sambay_decode_blocks_compile_with_the_kernel(chip, monkeypatch):
+    """The window and the shared-pool decode blocks of SambaY at the cell
+    ``phi4flash-reason-open``'s shapes (64 slots, 40/20 heads of 64 as 10
+    key groups of 128, rings of 512 rows = 32 pages a slot, 16,385 pool
+    pages of 16 rows, 256 pages a sequence): the row write (whole pages
+    read, changed and scattered back along the first axis) and PR 26's
+    kernel over the ring and over the pool, with queries zero-padded to the
+    group's width. Rings and pool keep their layout: nothing their size is
+    left as a temporary."""
+    import paddle_tpu.ops.pallas as pallas_tier
+    from paddle_tpu.models import sambay
+
+    monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
+    slots, d, ffn, block, window, pages = 64, 2560, 10240, 16, 512, 16385
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    block_w = {"ln1_g": sds((d,)), "ln1_b": sds((d,)), "ln2_g": sds((d,)),
+               "ln2_b": sds((d,)), "mlp_w1": sds((d, 2 * ffn)),
+               "mlp_w2": sds((ffn, d)), "o_w": sds((d, d)), "o_b": sds((d,)),
+               "lq1": sds((64,)), "lk1": sds((64,)), "lq2": sds((64,)),
+               "lk2": sds((64,)), "sub_g": sds((128,)),
+               "qkv_w": sds((d, 2 * d)), "qkv_b": sds((2 * d,))}
+    x, dec = sds((slots, d)), sds((slots,), jnp.int32)
+    lam0 = sds((), jnp.float32)
+
+    ring = sds((slots * window // block, 10, block, 128))
+    win = jax.jit(
+        lambda p, x, rk, rv, dec, lam: sambay._window_block_tok(
+            p, x, rk, rv, dec, lam, 1e-5, window),
+        donate_argnums=(2, 3)).lower(block_w, x, ring, ring, dec,
+                                     lam0).compile()
+    pool = sds((pages, 10, block, 128))
+    full = jax.jit(
+        lambda p, x, kc, vc, bt, dec, lam: sambay._pool_block_tok(
+            p, x, kc, vc, bt, dec, lam, 1e-5, False),
+        donate_argnums=(2, 3)).lower(block_w, x, pool, pool,
+                                     sds((slots, 256), jnp.int32), dec,
+                                     lam0).compile()
+    for compiled in (win, full):
+        assert len(_kernel_calls(compiled, "paged_attention_decode")) == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
