@@ -12,10 +12,17 @@ Reference surface:
 
 TPU design: these are the serving-side attention ops. The general
 paged-cache read is a gather over the block table (every slot's whole
-timeline, whatever is cached); cache writes are scatters at static positions
-per step. The decode step (one token a sequence) has an entry of its own,
-``block_gqa_decode_attention``, which on the chip reads only the pages a
-sequence holds through the Pallas kernel of ``ops/pallas/paged_attention``.
+timeline, whatever is cached); the general cache write is a row scatter
+(``_scatter_paged``). The decode step (one token a sequence) has an entry of
+its own, ``block_gqa_decode_attention``, which on the chip reads only the
+pages a sequence holds through the Pallas kernel of
+``ops/pallas/paged_attention``. Where the call site knows that a float pool
+takes one row a sequence (that entry) or one run of rows of one sequence (a
+prompt's chunk), whole pages are read, changed and written back along the
+pool's first axis (``write_page_rows``, ``_write_page_run``): a row scatter
+indexes axes 0 and 2 of ``[pages, KV, block, D]``, XLA:TPU gives it a layout
+of its own, and every pool array was copied into that layout and back each
+step.
 Quantized-cache args (qkv_out_scale, cache_k_quant_scales, ...) are gated —
 the quantization tier on TPU lives in paddle_tpu.quantization.
 """
@@ -208,6 +215,43 @@ def _scatter_paged(kc, vc, bt, seq_of, pos, kt, vt, block_size,
     off = pos % block_size
     return (kc.at[phys, :, off].set(kt.astype(kc.dtype)),
             vc.at[phys, :, off].set(vt.astype(vc.dtype)))
+
+
+def write_page_rows(pool, page, off, rows):
+    """One row a sequence into the page layout: rows [B, G, D] at
+    (page [B], off [B]). Whole pages are read, changed and scattered back
+    along the pool's first axis, which leaves the pool's layout alone.
+
+    Leans on: no two sequences name the same ``page`` with rows that both
+    count. A row scatter kept both rows of a page written at two offsets;
+    here the page scattered last wins whole and the other row is lost. The
+    paged batchers hold that: the prefix cache shares FULL pages only and a
+    sequence writes at or after its first unmatched row, so a page being
+    written has one owner; parked slots all name the scratch page, which
+    nothing reads (tests/test_paged_batching.py holds the batcher to it).
+    """
+    cur = pool[page]
+    hit = (jnp.arange(pool.shape[2])[None, :] == off[:, None])
+    return pool.at[page].set(jnp.where(hit[:, None, :, None],
+                                       rows[:, :, None, :].astype(pool.dtype),
+                                       cur))
+
+
+def _write_page_run(pool, table, line, dec, run):
+    """One sequence's run of rows ``run`` [T, KV, D] at rows dec .. dec + T
+    of its timeline ``line`` [KV, S_kv, D] (its pages ``table``
+    [blocks_per_seq] as ``_gather_paged`` reads them): the run is laid over
+    the timeline and the pages are scattered back along the pool's first
+    axis (no layout of its own, as above). The caller keeps
+    dec + T within the table, as for ``_scatter_paged``. Table entries that
+    are not backed all name the scratch page: which of the duplicates lands
+    there decides nothing. Returns the pool and the timeline with the run
+    in it, which is what the scores read."""
+    n, (_, kvh, block, hd) = table.shape[0], pool.shape
+    line = jax.lax.dynamic_update_slice_in_dim(
+        line, jnp.moveaxis(run, 0, 1).astype(pool.dtype), dec, 1)
+    pages = jnp.moveaxis(line.reshape(kvh, n, block, hd), 0, 1)
+    return pool.at[table].set(pages), line
 
 
 def _gather_paged(kc, vc, bt, heads, k_dequant=None, v_dequant=None,
@@ -443,6 +487,27 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_encoder,
     return result
 
 
+def _grouped_scores(qt, tk, tv, pos, kv_len):
+    """Causal grouped-query attention of packed tokens qt [T, H, D], token t
+    at row pos[t] of a timeline of which kv_len[t] rows count, against
+    unexpanded K / V: one timeline a token, tk / tv [T, KV, S, D], or one
+    for all of them, [KV, S, D]. Float32 throughout. Returns [T, KV, rep,
+    D]."""
+    token_num, nh, hd = qt.shape
+    kvh, s_kv = tk.shape[-3], tk.shape[-2]
+    timeline = "tgsd" if tk.ndim == 4 else "gsd"
+    # q regrouped [T, KV, rep, D] against the timeline [T, KV, S, D]
+    qg = qt.reshape(token_num, kvh, nh // kvh, hd).astype(jnp.float32)
+    kv_pos = jnp.arange(s_kv)[None, None, None, :]
+    ok = (kv_pos <= pos[:, None, None, None]) \
+        & (kv_pos < kv_len[:, None, None, None])
+    scores = jnp.einsum(f"tgrd,{timeline}->tgrs", qg,
+                        tk.astype(jnp.float32)) * (1.0 / float(hd) ** 0.5)
+    probs = jax.nn.softmax(jnp.where(ok, scores, _NEG), axis=-1)
+    return jnp.einsum(f"tgrs,{timeline}->tgrd", probs,
+                      tv.astype(jnp.float32))
+
+
 def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
                         seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
                         block_tables, block_size=64, rope_cos=None,
@@ -496,7 +561,6 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
     bsz, blocks_per_seq = bt.shape
     kvh, bs_, hd = kc.shape[1], kc.shape[2], kc.shape[3]
     token_num, nh, _ = qt.shape
-    rep = nh // kvh
 
     seq_of, local, pos = _token_timeline(cu_q, dec, token_num)
 
@@ -520,37 +584,34 @@ def block_gqa_attention(q, k, v, key_cache, value_cache, seq_lens_encoder,
                                              valid_mask)
         kq, vq, kdq, vdq = (new_scales["kq"], new_scales["vq"],
                             new_scales["kdq"], new_scales["vdq"])
+    kv_len = jnp.where(enc > 0, enc, dec + this)
     with jax.named_scope("paged_attention"):
-        with jax.named_scope("kv_scatter"):
-            kc, vc = _scatter_paged(kc, vc, bt, seq_of, pos, kt, vt, bs_,
-                                    k_quant=kq, v_quant=vq)
-        kv_len = jnp.where(enc > 0, enc, dec + this)
-        with jax.named_scope("kv_gather"):
-            gk, gv, s_kv = _gather_paged(kc, vc, bt, kvh, k_dequant=kdq,
-                                         v_dequant=vdq, out_dtype=qt.dtype)
+        if bsz == 1 and kq is None:
+            # One sequence and no scales, so a float pool (_cachekv_scales
+            # refuses an int8 pool without them), which is how the batcher
+            # admits a prompt, whole or a chunk: the slot's pages are read
+            # once, as the timeline to attend, and the run of rows is
+            # written into them by the page.
+            with jax.named_scope("kv_gather"):
+                gk, gv, _ = _gather_paged(kc, vc, bt, kvh)
+            with jax.named_scope("kv_scatter"):
+                kc, tk = _write_page_run(kc, bt[0], gk[0], dec[0], kt)
+                vc, tv = _write_page_run(vc, bt[0], gv[0], dec[0], vt)
+        else:
+            with jax.named_scope("kv_scatter"):
+                kc, vc = _scatter_paged(kc, vc, bt, seq_of, pos, kt, vt, bs_,
+                                        k_quant=kq, v_quant=vq)
+            with jax.named_scope("kv_gather"):
+                gk, gv, _ = _gather_paged(kc, vc, bt, kvh, k_dequant=kdq,
+                                          v_dequant=vdq, out_dtype=qt.dtype)
+            # One sequence: every token attends the same timeline. Indexing
+            # it per token copies it T times when the op runs eagerly (10.7
+            # GB for a 640-token prompt at 32 x 128 heads and a 2048-row
+            # slot: the chip ran out of memory); XLA folds that gather away
+            # only inside one jit program.
+            tk, tv = (gk[0], gv[0]) if bsz == 1 else (gk[seq_of], gv[seq_of])
         with jax.named_scope("scores"):
-            # grouped scores: q regrouped [T, KV, rep, D] vs timeline
-            # [T, KV, S, D]
-            qg = qt.reshape(token_num, kvh, rep, hd).astype(jnp.float32)
-            scale = 1.0 / float(hd) ** 0.5
-            kv_pos = jnp.arange(s_kv)[None, None, None, :]
-            ok = (kv_pos <= pos[:, None, None, None]) \
-                & (kv_pos < kv_len[seq_of][:, None, None, None])
-            if bsz == 1:
-                # One sequence, which is how the batcher admits a prompt:
-                # every token attends the same timeline. Indexing it per
-                # token copies it T times when the op runs eagerly (10.7 GB
-                # for a 640-token prompt at 32 x 128 heads and a 2048-row
-                # slot — the chip ran out of memory); XLA folds that gather
-                # away only inside one jit program.
-                tk, tv, timeline = gk[0], gv[0], "gsd"
-            else:
-                tk, tv, timeline = gk[seq_of], gv[seq_of], "tgsd"
-            scores = jnp.einsum(f"tgrd,{timeline}->tgrs", qg,
-                                tk.astype(jnp.float32)) * scale
-            probs = jax.nn.softmax(jnp.where(ok, scores, _NEG), axis=-1)
-            out = jnp.einsum(f"tgrs,{timeline}->tgrd", probs,
-                             tv.astype(jnp.float32))
+            out = _grouped_scores(qt, tk, tv, pos, kv_len[seq_of])
     result = (Tensor(out.reshape(token_num, nh * hd).astype(qt.dtype)),
               Tensor(kc), Tensor(vc))
     if new_scales is not None:
@@ -564,13 +625,22 @@ def decode_attention_path(pool_shape, pool_dtype, q_heads) -> str:
     """Which route ``block_gqa_decode_attention`` takes for a page pool:
     ``"kernel"`` (ops/pallas/paged_attention) on the chip over a pool that
     is not quantized and whose pages Mosaic takes as they lie, ``"gather"``
-    (``block_gqa_attention``) otherwise. Decided from what can be observed
-    — the backend and the pool — like every kernel of ops/pallas."""
+    (the gathered timelines of ``block_gqa_attention``) otherwise. Decided
+    from what can be observed — the backend and the pool — like every kernel
+    of ops/pallas."""
     from ....ops import pallas as _pl
     from ....ops.pallas.paged_attention import supported
     if _pl.on_tpu() and supported(pool_shape, pool_dtype, q_heads):
         return "kernel"
     return "gather"
+
+
+def decode_kv_writer(pool_dtype) -> str:
+    """How ``block_gqa_decode_attention`` writes the step's rows into a
+    pool: ``"page"`` (``write_page_rows``) for a float pool, on every
+    backend; ``"row"`` (``_scatter_paged``, inside the general op) for an
+    int8 pool, whose rows are quantized on the way in."""
+    return "page" if jnp.issubdtype(pool_dtype, jnp.floating) else "row"
 
 
 def block_gqa_decode_attention(q, k, v, key_cache, value_cache,
@@ -585,26 +655,27 @@ def block_gqa_decode_attention(q, k, v, key_cache, value_cache,
     ``seq_lens_this_time == 1``) this computes. That every sequence adds
     one token is not a fact the general op can read off a traced
     ``seq_lens_this_time``, so the caller that knows it (the model's
-    ``paged_decode_step``) says so by calling this entry. On the chip the
-    pages a sequence holds are read in place by the Pallas kernel; where
-    ``decode_attention_path`` says ``"gather"``, or cache-quantization
-    scales are passed (``cachekv_quant``: the general op's keywords), the
-    general op runs. Returns (out [B, H*D], key_cache_out, value_cache_out).
+    ``paged_decode_step``) says so by calling this entry: RoPE, the row
+    written by the page (``write_page_rows``), then on the chip the Pallas
+    kernel reading the pages a sequence holds in place, and the gathered
+    timelines where ``decode_attention_path`` says ``"gather"``. With
+    cache-quantization scales (``cachekv_quant``: the general op's
+    keywords) or an int8 pool the general op runs, row scatter and all.
+    Returns (out [B, H*D], key_cache_out, value_cache_out).
     """
     qt, kt, vt = _arr(q), _arr(k), _arr(v)
     kc, vc = _arr(key_cache), _arr(value_cache)
     bsz, nh, hd = qt.shape
-    if cachekv_quant or decode_attention_path(kc.shape, kc.dtype,
-                                              nh) == "gather":
+    if cachekv_quant or decode_kv_writer(kc.dtype) == "row":
         ones = jnp.ones((bsz,), jnp.int32)
         return block_gqa_attention(
             q, k, v, key_cache, value_cache, jnp.zeros_like(ones),
             seq_lens_decoder, ones, jnp.arange(bsz + 1, dtype=jnp.int32),
             block_tables, rope_cos=rope_cos, rope_sin=rope_sin,
             **cachekv_quant)
-    from ....ops.pallas.paged_attention import paged_attention_decode
     dec = _arr(seq_lens_decoder).reshape(-1).astype(jnp.int32)
     bt = _arr(block_tables).astype(jnp.int32)
+    block = kc.shape[2]
     if rope_cos is not None:
         with jax.named_scope("qkv_rope"):
             cos_t = _arr(rope_cos)[dec].astype(jnp.float32)    # [B, D/2]
@@ -613,11 +684,21 @@ def block_gqa_decode_attention(q, k, v, key_cache, value_cache,
                 _rope_pairs(kt, cos_t, sin_t)
     with jax.named_scope("paged_attention"):
         with jax.named_scope("kv_scatter"):
-            kc, vc = _scatter_paged(kc, vc, bt, jnp.arange(bsz), dec, kt,
-                                    vt, kc.shape[2])
-        with jax.named_scope("scores"):
-            # this step's row is in the pool: dec + 1 rows count
-            out = paged_attention_decode(qt, kc, vc, bt, dec + 1)
+            page = jnp.take_along_axis(bt, (dec // block)[:, None],
+                                       axis=1)[:, 0]
+            kc = write_page_rows(kc, page, dec % block, kt)
+            vc = write_page_rows(vc, page, dec % block, vt)
+        # this step's row is in the pool: dec + 1 rows count
+        if decode_attention_path(kc.shape, kc.dtype, nh) == "kernel":
+            from ....ops.pallas.paged_attention import paged_attention_decode
+            with jax.named_scope("scores"):
+                out = paged_attention_decode(qt, kc, vc, bt, dec + 1)
+        else:
+            with jax.named_scope("kv_gather"):
+                gk, gv, _ = _gather_paged(kc, vc, bt, kc.shape[1])
+            with jax.named_scope("scores"):
+                out = _grouped_scores(qt, gk, gv, dec, dec + 1).astype(
+                    qt.dtype)
     return Tensor(out.reshape(bsz, nh * hd)), Tensor(kc), Tensor(vc)
 
 
@@ -657,6 +738,7 @@ def variable_length_memory_efficient_attention(query, key, value, seq_lens,
 
 __all__ = ["masked_multihead_attention", "block_multihead_attention",
            "block_gqa_attention", "block_gqa_decode_attention",
-           "decode_attention_path", "cachekv_scales_from_dense",
+           "decode_attention_path", "decode_kv_writer", "write_page_rows",
+           "cachekv_scales_from_dense",
            "cachekv_scale_kwargs",
            "variable_length_memory_efficient_attention"]

@@ -1138,9 +1138,13 @@ class PagedContinuousBatcher(_BatcherBase):
         # the route the decode step's attention takes over this pool
         # ("kernel": the Pallas paged kernel; "gather": the XLA gather),
         # as the model that builds the executable decides it; every launch
-        # of that executable is counted under it
+        # of that executable is counted under it, and under how the step
+        # writes its K/V rows ("page": whole pages along the pool's first
+        # axis; "row": the row scatter)
         self._decode_path, self._decode_launch_c = \
             self._decode_attention_series(model, pool)
+        self._kv_writer, self._kv_write_c = self._kv_write_series(model,
+                                                                  pool)
         if cache_quant:
             # per-(slot, kv-head) dynamic scales, host-owned like the
             # block table; each sequence's prefill fills its slot row
@@ -2224,10 +2228,28 @@ class PagedContinuousBatcher(_BatcherBase):
             labelnames=("engine", "path")).labels(
                 engine=self._engine, path=path)
 
+    def _kv_write_series(self, model, pool):
+        """(writer, counter) beside ``_decode_attention_series``: the word
+        is the model's (``paged_kv_writer``; a family without it scatters
+        rows), the counter ``serving_kv_write_launches_total{writer=...}``."""
+        from ..observability.metrics import get_registry
+        word = getattr(model, "paged_kv_writer", None)
+        writer = word(pool) if word is not None else "row"
+        return writer, get_registry().counter(
+            "serving_kv_write_launches_total",
+            "launches of a decode-step executable, by how it writes the "
+            "step's K/V rows (page: whole pages read, changed and written "
+            "back; row: a row scatter)",
+            labelnames=("engine", "writer")).labels(
+                engine=self._engine, writer=writer)
+
     def stats(self) -> Dict[str, float]:
-        """The base counters, and ``decode_attention_path``: the label of
-        this batcher's ``serving_decode_attention_launches_total``."""
-        return dict(super().stats(), decode_attention_path=self._decode_path)
+        """The base counters, and ``decode_attention_path`` and
+        ``kv_writer``: the labels of this batcher's
+        ``serving_decode_attention_launches_total`` and
+        ``serving_kv_write_launches_total``."""
+        return dict(super().stats(), decode_attention_path=self._decode_path,
+                    kv_writer=self._kv_writer)
 
     def _sync_tables(self):
         import paddle_tpu as paddle
@@ -2487,6 +2509,7 @@ class PagedContinuousBatcher(_BatcherBase):
         t0 = _time.perf_counter()
         with _span("serving.launch"), paddle.no_grad():
             self._decode_launch_c.inc()
+            self._kv_write_c.inc()
             self._count_slot_state_step()
             tok_t = paddle.to_tensor(self._last_tok)
             logits, self._state = self._step_fn(tok_t, self._state)
@@ -2550,6 +2573,7 @@ class PagedContinuousBatcher(_BatcherBase):
         t0 = _time.perf_counter()
         with _span("serving.launch"), paddle.no_grad():
             self._decode_launch_c.inc()
+            self._kv_write_c.inc()
             tok_t = paddle.to_tensor(self._last_tok)
             toks, self._state = self._block_fn(tok_t, self._state)
         with _span("serving.fetch"):

@@ -1082,12 +1082,22 @@ class LlamaForCausalLM(Layer):
         return decode_attention_path(tuple(kc.shape), kc._data.dtype,
                                      self.config.num_attention_heads)
 
+    def paged_kv_writer(self, pool) -> str:
+        """``"page"`` or ``"row"``: how ``paged_decode_step`` writes a
+        step's K/V rows into ``pool`` (whole pages along the first axis for
+        a float pool, the row scatter for an int8 one) — what the batcher
+        labels ``serving_kv_write_launches_total`` with."""
+        from ..incubate.nn.functional.decode_attention import \
+            decode_kv_writer
+        return decode_kv_writer(pool[0][0]._data.dtype)
+
     def paged_decode_step(self, tok, state):
         """One token per sequence through the paged GQA cache. tok: [B].
         Static shapes — ``jit.to_static(model.paged_decode_step)`` serves
         every step with one executable. Decode by construction, so the
-        attention is the decode-only entry (the Pallas paged kernel on the
-        chip), not the general ``block_gqa_attention``."""
+        attention is the decode-only entry (rows written by the page, the
+        Pallas paged kernel on the chip), not the general
+        ``block_gqa_attention``."""
         from ..incubate.nn.functional.decode_attention import (
             block_gqa_decode_attention, cachekv_scale_kwargs)
 

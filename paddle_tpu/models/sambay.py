@@ -55,6 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..incubate.nn.functional.decode_attention import \
+    write_page_rows as _write_rows
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer import Layer
@@ -293,17 +295,6 @@ def _rows_to_pages(rows, block):
     """[P * block, G, D] -> [P, G, block, D]."""
     t, g, d = rows.shape
     return jnp.moveaxis(rows.reshape(t // block, block, g, d), 2, 1)
-
-
-def _write_rows(pool, page, off, rows):
-    """One row a sequence into the page layout: rows [B, G, D] at
-    (page [B], off [B]). Whole pages are read, changed and scattered back
-    along the pool's first axis, which leaves the pool's layout alone."""
-    cur = pool[page]
-    hit = (jnp.arange(pool.shape[2])[None, :] == off[:, None])
-    return pool.at[page].set(jnp.where(hit[:, None, :, None],
-                                       rows[:, :, None, :].astype(pool.dtype),
-                                       cur))
 
 
 def decode_route(pool) -> str:
@@ -852,6 +843,10 @@ class SambaYForCausalLM(Layer):
         decides all three."""
         route = decode_route(cache["pool"][0]._data)
         return f"window={route},full={route},cross={route}"
+
+    def paged_kv_writer(self, cache) -> str:
+        """Rings and pool are float and take their rows by the page."""
+        return "page"
 
     def paged_prefill_into(self, input_ids, layers, block_tables,
                            block_size=16, dec_base=None, logits_at=None,

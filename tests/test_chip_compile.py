@@ -221,6 +221,20 @@ def test_paged_attention_step_compiles(chip, tokens):
     assert mem.temp_size_in_bytes < 4 << 30, mem
 
 
+def _pool_arrays(compiled, pool):
+    """(layouts, copies) of the arrays of ``pool``'s shape and dtype in the
+    optimized HLO: every layout such an array is given (its tiling with
+    it), and the ``copy`` instructions that produce one."""
+    import re
+    dims = ",".join(str(d) for d in pool.shape)
+    name = {"bfloat16": "bf16", "float32": "f32"}[pool.dtype.name]
+    array = re.escape(f"{name}[{dims}]") + r"\{[^}]*\}"
+    text = compiled.as_text()
+    layouts = {m for m in re.findall(array, text) if ":" in m}
+    copies = re.findall(rf"= {array} copy\(", text)
+    return layouts, copies
+
+
 @pytest.mark.parametrize("kv_heads,dtype", [
     (8, jnp.bfloat16), (32, jnp.bfloat16), (8, jnp.float32)],
     ids=["gqa32_8_bf16", "mha32_bf16", "gqa32_8_f32"])
@@ -229,11 +243,15 @@ def test_paged_decode_entry_compiles_with_the_kernel(chip, monkeypatch,
     """The decode step's attention entry at the serving cell's shapes
     (mistral7b-*: 32 slots, 32/8 heads of 128, 16-token pages, 256 pages a
     sequence, 3,073 pages; and the same with 32 kv heads, chip_smoke's
-    Llama-2 widths, and in float32): RoPE, the scatter and the Pallas
-    kernel ``paged_attention_decode`` reading the pool in place. Nothing
-    the size of the gathered timelines is left: at these shapes
-    ``_gather_paged`` made 1 GB of them (32 x 8 x 4096 x 128 bf16 for K and
-    for V, and their transposed copies).
+    Llama-2 widths, and in float32), two layers chained with their pools
+    donated, as the decode executable holds them: RoPE, the page writer and
+    the Pallas kernel ``paged_attention_decode`` reading the pool in place.
+    The pools keep the layout they enter with: no ``copy`` of a pool array,
+    one layout for all of them, nothing their size among the temporaries.
+    (A row scatter indexed on axes 0 and 2 had XLA:TPU copy every pool
+    array into a layout of its own and back, 64% of the cell's decode step:
+    PERF.md, PR 29.) Nor is anything the size of the gathered timelines
+    left: at these shapes ``_gather_paged`` made 1 GB of them.
     The entry asks ``on_tpu()``, which sees this sandbox's CPU, so the test
     steers it: the compile is for the described chip."""
     import paddle_tpu.ops.pallas as pallas_tier
@@ -243,10 +261,14 @@ def test_paged_decode_entry_compiles_with_the_kernel(chip, monkeypatch,
     monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
     slots, block, blocks_per_seq, n_pages = 32, 16, 256, 3073
 
-    def step(q, k, v, kc, vc, dec, bt, cos, sin):
-        out, kc, vc = block_gqa_decode_attention(
-            q, k, v, kc, vc, dec, bt, rope_cos=cos, rope_sin=sin)
-        return out._data, kc._data, vc._data
+    def step(q, k, v, pools, dec, bt, cos, sin):
+        out = []
+        for kc, vc in pools:
+            att, kc, vc = block_gqa_decode_attention(
+                q, k, v, kc, vc, dec, bt, rope_cos=cos, rope_sin=sin)
+            q = att._data.reshape(q.shape)
+            out.append((kc._data, vc._data))
+        return q, out
 
     def sds(shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
@@ -254,18 +276,62 @@ def test_paged_decode_entry_compiles_with_the_kernel(chip, monkeypatch,
     row = sds((slots, kv_heads, HEAD_DIM))
     pool = sds((n_pages, kv_heads, block, HEAD_DIM))
     rope = sds((block * blocks_per_seq, HEAD_DIM // 2), jnp.float32)
-    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
-        sds((slots, HEADS, HEAD_DIM)), row, row, pool, pool,
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        sds((slots, HEADS, HEAD_DIM)), row, row, [(pool, pool)] * 2,
         sds((slots,), jnp.int32), sds((slots, blocks_per_seq), jnp.int32),
         rope, rope).compile()
-    assert len(_kernel_calls(compiled, "paged_attention_decode")) == 1
-    mem = compiled.memory_analysis()
-    # what is left is the scatter's relayout of a pool array (ROADMAP S8):
-    # none at the cell's shapes, one array's worth at the others
-    pool_bytes = n_pages * kv_heads * block * HEAD_DIM * dtype.dtype.itemsize
-    limit = (64 << 20) if (kv_heads, dtype) == (8, jnp.bfloat16) \
-        else pool_bytes + (16 << 20)
-    assert mem.temp_size_in_bytes < limit, mem
+    assert len(_kernel_calls(compiled, "paged_attention_decode")) == 2
+    layouts, copies = _pool_arrays(compiled, pool)
+    assert not copies, copies
+    assert len(layouts) == 1, layouts
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("tokens,first_row", [(256, "dec"), (1024, 0)],
+                         ids=["chunk_256", "whole_prompt_1k"])
+def test_paged_chunk_keeps_the_pools_layout(chip, tokens, first_row):
+    """A prompt's chunk as the batcher admits it (one sequence, 256 rows at
+    a traced ``dec``; and a whole prompt of 1,024 in encoder mode) through
+    the general op at the cell's shapes, two layers chained, pools donated:
+    the run is laid over the slot's pages (8 MB an array) and the pages
+    are scattered back along the first axis, so no pool array is copied and
+    all keep one layout. What is left are the transposes of the slot's
+    pages to a timeline a head and back."""
+    from paddle_tpu.incubate.nn.functional.decode_attention import \
+        block_gqa_attention
+
+    kv_heads, block, blocks_per_seq, n_pages = 8, 16, 256, 3073
+
+    def step(q, k, v, pools, dec, bt, cos, sin):
+        this = jnp.full_like(dec, tokens)
+        enc, dec = (jnp.zeros_like(dec), dec) if first_row == "dec" \
+            else (this, jnp.zeros_like(dec))
+        out = []
+        for kc, vc in pools:
+            att, kc, vc = block_gqa_attention(
+                q, k, v, kc, vc, enc, dec, this,
+                jnp.array([0, tokens], jnp.int32), bt, block_size=block,
+                rope_cos=cos, rope_sin=sin)
+            q = att._data.reshape(q.shape)
+            out.append((kc._data, vc._data))
+        return q, out
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    row = sds((tokens, kv_heads, HEAD_DIM))
+    pool = sds((n_pages, kv_heads, block, HEAD_DIM))
+    rope = sds((block * blocks_per_seq, HEAD_DIM // 2), jnp.float32)
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        sds((tokens, HEADS, HEAD_DIM)), row, row, [(pool, pool)] * 2,
+        sds((1,), jnp.int32), sds((1, blocks_per_seq), jnp.int32),
+        rope, rope).compile()
+    layouts, copies = _pool_arrays(compiled, pool)
+    assert not copies, copies
+    assert len(layouts) == 1, layouts
+    # the float32 scores of one layer, and less than a pool array (100 MB)
+    scores = tokens * HEADS * block * blocks_per_seq * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < scores + (64 << 20)
 
 
 def test_scanned_decoder_layer_fwd_bwd_compiles(chip, monkeypatch):

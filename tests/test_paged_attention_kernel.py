@@ -183,8 +183,9 @@ def test_lengths_outside_the_slot_are_clamped():
 
 
 def test_decode_entry_takes_the_gather_route_off_the_chip():
-    """On the CPU the entry is the general op, bit for bit: tier-1's
-    token-equality tests see the program they saw."""
+    """On the CPU the entry writes its rows by the page and then attends
+    the gathered timelines: output and pools are the general op's, bit for
+    bit, so tier-1's token-equality tests see the numbers they saw."""
     q, kc, vc, bt = _case(3, 8, 2, jnp.float32, seed=5)
     assert decode_attention_path(kc.shape, kc.dtype, 8) == "gather"
     rng = np.random.default_rng(6)
@@ -204,9 +205,9 @@ def test_decode_entry_takes_the_gather_route_off_the_chip():
 
 
 def test_decode_entry_kernel_route_matches_the_gather_route(monkeypatch):
-    """The entry's kernel branch (RoPE, scatter, kernel) against its gather
-    branch, the kernel interpreted: what the chip runs against what tier-1
-    runs, from the same q, k, v and pool."""
+    """The entry's kernel branch (RoPE, the page writer, the kernel) against
+    its gather branch, the kernel interpreted: what the chip runs against
+    what tier-1 runs, from the same q, k, v and pool."""
     import functools
 
     from paddle_tpu.ops import pallas as pl_ops
@@ -228,7 +229,7 @@ def test_decode_entry_kernel_route_matches_the_gather_route(monkeypatch):
     got = block_gqa_decode_attention(*args, rope_cos=cos, rope_sin=sin)
     np.testing.assert_allclose(np.asarray(got[0]._data),
                                np.asarray(want[0]._data), atol=1e-5, rtol=0)
-    for g, w in zip(got[1:], want[1:]):          # the pools, scattered into
+    for g, w in zip(got[1:], want[1:]):          # the pools, written into
         np.testing.assert_array_equal(np.asarray(g._data),
                                       np.asarray(w._data))
 

@@ -674,3 +674,160 @@ def _decode_block_llama_body():
     for r1, r2 in zip(rids, rids2):
         np.testing.assert_array_equal(outs[r2], expected[r1])
     assert blk.stats()["decode_blocks"] > 0
+
+
+# -- the page-granular K/V writer's invariant ------------------------------
+
+def _kv_write_launches(engine="paged"):
+    from paddle_tpu.observability.metrics import get_registry
+    family = get_registry().get("serving_kv_write_launches_total")
+    return {w: family.labels(engine=engine, writer=w).value
+            for w in ("page", "row")}
+
+
+def _watch_written_pages(b):
+    """Wrap the batcher's decode launches: before each one, the pages the
+    running slots are about to write (one row a step, ``decode_block`` rows
+    a block; an unbacked entry is scratch, which nothing reads) must be
+    pairwise distinct, in no other slot's table and not the prefix cache's.
+    Returns the list the launches are logged to."""
+    seen = []
+
+    def check(k_steps):
+        live = sorted(b._slot_req)
+        cached = set(b.prefix_cache.pages()) if b.prefix_cache else set()
+        writes = {}
+        for slot in live:
+            rows = int(b._dec[slot]) + np.arange(k_steps)
+            rows = rows[rows < b.blocks_per_seq * b.block_size]
+            pages = set(int(p) for p in b._bt[slot, rows // b.block_size])
+            writes[slot] = pages - {b._scratch}
+            assert writes[slot], f"slot {slot} writes no backed page"
+            assert not writes[slot] & cached, (slot, writes[slot] & cached)
+        for slot in live:
+            for other in range(b.max_batch):
+                if other != slot:
+                    held = set(int(p) for p in b._bt[other])
+                    assert not writes[slot] & held, (slot, other)
+        # parked slots name nothing but scratch
+        for slot in set(range(b.max_batch)) - set(live):
+            assert set(int(p) for p in b._bt[slot]) == {b._scratch}
+        seen.append((k_steps, len(live)))
+
+    def wrap(fn, k_steps):
+        def launch(tok, state):
+            check(k_steps)
+            return fn(tok, state)
+        return launch
+
+    b._step_fn = wrap(b._step_fn, 1)
+    if b.decode_block:
+        b._block_fn = wrap(b._block_fn, b.decode_block)
+    return seen
+
+
+def _row_scatter_route(monkeypatch):
+    """Put the Llama family back on the row scatter, decode step and chunk:
+    what the page writers' tokens are held against."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.incubate.nn.functional import decode_attention as da
+
+    def row_run(pool, table, line, dec, run):
+        rows = dec + jnp.arange(run.shape[0])
+        block = pool.shape[2]
+        pool = pool.at[table[rows // block], :, rows % block].set(
+            run.astype(pool.dtype))
+        return pool, da._gather_paged(pool, pool, table[None],
+                                      pool.shape[1])[0][0]
+    monkeypatch.setattr(da, "decode_kv_writer", lambda dtype: "row")
+    monkeypatch.setattr(da, "_write_page_run", row_run)
+
+
+# documents of whole and part pages, each asked several times with another
+# question behind it; block_size 4
+def _document_sessions(rng, n_docs=2, asks=3):
+    docs = [rng.randint(0, 128, (n,)) for n in (16, 22)[:n_docs]]
+    return [np.concatenate([docs[i % n_docs], rng.randint(0, 128, (q,))])
+            for i, q in enumerate(rng.randint(1, 6, (n_docs * asks,)))]
+
+
+@pytest.mark.parametrize("options", [
+    dict(), dict(policy="ondemand", n_pages=13), dict(decode_block=3),
+    dict(prefill_chunk=8)],
+    ids=["reserve", "ondemand_preempting", "decode_block", "chunked"])
+def test_no_two_sequences_write_one_page(options, monkeypatch):
+    """The page writer's invariant, held under the prefix cache: documents
+    asked several times share their FULL pages, and at every decode launch
+    the pages the running slots write are pairwise distinct, in nobody
+    else's table and not the cache's. Tokens equal the row-scatter route's
+    and the solo reference's; ``audit_pages()`` is clean; every launch is
+    counted under ``writer="page"``."""
+    m = _llama()
+    prompts = _document_sessions(np.random.RandomState(11))
+    budgets = [7, 5, 9, 6, 8, 5]
+    kw = dict(max_batch=3, s_max=48, block_size=4, compile=False,
+              prefix_cache=True, **options)
+
+    def serve(watch):
+        b = PagedContinuousBatcher(m, **kw)
+        seen = _watch_written_pages(b) if watch else None
+        before = _kv_write_launches()
+        rids = [b.submit(p, n) for p, n in zip(prompts, budgets)]
+        outs = b.run_until_done()
+        assert b.audit_pages() == 0
+        s = dict(b.stats(), hit_tokens=b.prefix_cache.hit_tokens)
+        counted = {w: n - before[w]
+                   for w, n in _kv_write_launches().items()}
+        b.close()
+        return [outs[r] for r in rids], s, counted, seen
+
+    got, s, counted, seen = serve(watch=True)
+    assert s["kv_writer"] == "page" and counted["row"] == 0
+    assert counted["page"] == len(seen) > 0
+    assert s["hit_tokens"] > 0, "no page was shared"
+    assert max(n for _, n in seen) > 1, "no two sequences ever ran together"
+    if options.get("decode_block"):
+        assert s["decode_blocks"] > 0
+        assert counted["page"] == s["steps"] - 2 * s["decode_blocks"]
+    else:
+        assert counted["page"] == s["steps"]
+    if options.get("policy") == "ondemand":
+        assert s["preemptions"] > 0, "the pool never ran dry"
+    for p, n, out in zip(prompts, budgets, got):
+        ids = paddle.to_tensor(np.asarray(p, np.int64)[None, :])
+        with paddle.no_grad():
+            np.testing.assert_array_equal(
+                out, m.generate(ids, max_new_tokens=n).numpy()[0])
+
+    _row_scatter_route(monkeypatch)
+    want, s, counted, _ = serve(watch=False)
+    assert s["kv_writer"] == "row" and counted["page"] == 0
+    assert counted["row"] > 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_an_int8_pool_keeps_the_row_scatter():
+    """``cache_quant`` allocates int8 pools: rows are quantized on the way
+    in by the general op, and the launches are counted under ``row``."""
+    m = _llama()
+    rng = np.random.RandomState(12)
+    b = PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
+                               compile=False, cache_quant="dynamic_int8")
+    assert b.stats()["kv_writer"] == "row"
+    before = _kv_write_launches()
+    for _ in range(2):
+        b.submit(rng.randint(0, 128, (5,)), 6)
+    b.run_until_done()
+    after = _kv_write_launches()
+    assert after["row"] - before["row"] == b.stats()["steps"] > 0
+    assert after["page"] == before["page"]
+
+
+def test_kv_writer_of_a_family_without_the_word_is_row():
+    """GPT-2's paged step (``block_multihead_attention``) scatters rows and
+    says nothing: the batcher's default."""
+    b = PagedContinuousBatcher(_model(), max_batch=2, s_max=32,
+                               block_size=8, compile=False)
+    assert b.stats()["kv_writer"] == "row"
