@@ -64,13 +64,12 @@ def main():
     print(f"paged generate (token-exact match): {paged_dt:.2f}s")
 
     # 4) the full serving engine: paged continuous batching with chunked
-    #    prefill + FUSED admission — decode slots keep producing tokens
-    #    while a new prompt's chunks stream through the same executable
+    #    prefill — a new prompt streams through one fixed-width executable
+    #    at a step boundary, then joins the running decode batch
     from paddle_tpu.inference import PagedContinuousBatcher
     batcher = PagedContinuousBatcher(model, max_batch=4, s_max=256,
                                      block_size=32, prefill_chunk=64,
-                                     policy="ondemand",
-                                     fused_admission=True)
+                                     policy="ondemand")
     rng = np.random.RandomState(0)
     reqs = [rng.randint(0, model.config.vocab_size, (n,))
             for n in (37, 100, 180, 64)]
@@ -101,7 +100,7 @@ def main():
         solos = run_solos()
         for o, s in zip(outs, solos):
             assert o.tolist() == s.tolist(), \
-                "fused continuous batching must be token-exact vs solo"
+                "continuous batching must be token-exact vs solo"
     stats = batcher.stats()
     print(f"continuous batching: {stats['completed_requests']} requests, "
           f"{stats['generated_tokens']} tokens, "

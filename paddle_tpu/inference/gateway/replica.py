@@ -105,10 +105,9 @@ class Replica:
     # -- load/capacity the router reads --------------------------------------
     @property
     def load(self) -> int:
-        """In-flight request count: queued + active (+ mid-admission)."""
+        """In-flight request count: queued + active."""
         b = self.batcher
-        return (b.active + b.pending
-                + (1 if getattr(b, "_admitting", None) else 0))
+        return b.active + b.pending
 
     @property
     def free_slots(self) -> int:

@@ -81,8 +81,6 @@ class _ServingStats:
                            "requests preempted back to the queue")
         self.tokens_c = c("serving_tokens_total", "tokens generated")
         self.steps_c = c("serving_steps_total", "decode steps")
-        self.blocks_c = c("serving_decode_blocks_total",
-                          "K-step decode blocks dispatched")
         self.queue_depth = g("serving_queue_depth",
                              "pending requests right now")
         self.active_slots = g("serving_active_slots",
@@ -91,8 +89,6 @@ class _ServingStats:
                       "submit to first generated token")
         self.step_seconds = h("serving_step_seconds",
                               "one decode dispatch wall time")
-        self.token_seconds = h("serving_per_token_seconds",
-                               "per-token decode latency")
         self.shed_c = c("requests_shed_total",
                         "requests rejected at admission (queue full)")
         self.expired_c = c("serving_deadline_expired_total",
@@ -110,7 +106,6 @@ class _ServingStats:
         self.cachekv_elems = 0
         self.cachekv_clipped = 0
         self.warned_cachekv_clip = False
-        self.decode_blocks = 0
         self.shed = 0
         self.expired = 0
         self.t0 = _time.perf_counter()
@@ -129,17 +124,15 @@ class _ServingStats:
         if len(req.tokens) == 1 and req.submit_t:
             self.ttft.observe(_time.perf_counter() - req.submit_t)
 
-    def on_step(self, substeps: int = 1):
-        self.steps += substeps
-        self.steps_c.inc(substeps)
+    def on_step(self):
+        self.steps += 1
+        self.steps_c.inc()
 
     def on_occupancy(self, n: int):
         self.occupancy_sum += n
 
-    def on_decode_time(self, dt: float, substeps: int = 1,
-                       tokens: int = 0):
+    def on_decode_time(self, dt: float, tokens: int = 0):
         self.step_seconds.observe(dt)
-        self.token_seconds.observe(dt / max(substeps, 1))
         if tokens:
             # join the dispatch against the roofline's serving token
             # bound (roofline.serving.* gauges; no-op without a model)
@@ -153,10 +146,6 @@ class _ServingStats:
     def on_preempt(self):
         self.preempted += 1
         self.preempt_c.inc()
-
-    def on_decode_block(self):
-        self.decode_blocks += 1
-        self.blocks_c.inc()
 
     def on_shed(self):
         self.shed += 1
@@ -192,7 +181,6 @@ class _ServingStats:
             "elapsed_s": dt,
             "cachekv_clip_rate": (self.cachekv_clipped
                                   / max(self.cachekv_elems, 1)),
-            "decode_blocks": self.decode_blocks,
             "requests_shed": self.shed,
             "deadline_expired": self.expired,
         }
@@ -424,15 +412,6 @@ class _BatcherBase:
                     f"request {req.rid} expired after "
                     f"{len(req.tokens)} tokens"))
                 self._tele.on_deadline_expired()
-        adm = getattr(self, "_admitting", None)
-        if adm is not None and expired(adm["req"]):
-            # in-flight fused admission: pages back to the pool
-            self._release_row(adm["row"])
-            self._free_slots.append(adm["slot"])
-            self._admitting = None
-            self._fail(adm["req"], DeadlineExceeded(
-                f"request {adm['req'].rid} expired during admission"))
-            self._tele.on_deadline_expired()
 
     def step(self) -> List[int]:
         """Expire deadlines, then run one engine step (subclass
@@ -532,19 +511,16 @@ class _BatcherBase:
         return len(self._pending)
 
     def request(self, rid: int) -> Optional[Request]:
-        """The live ``Request`` record for ``rid`` — queued, active,
-        mid-admission, or finished-but-unpopped; None once popped or
-        failed. Read-only view for fronting layers (the gateway polls
-        ``.tokens`` off it for streaming delivery)."""
+        """The live ``Request`` record for ``rid`` — queued, active, or
+        finished-but-unpopped; None once popped or failed. Read-only view
+        for fronting layers (the gateway polls ``.tokens`` off it for
+        streaming delivery)."""
         for req in self._pending:
             if req.rid == rid:
                 return req
         for req in self._slot_req.values():
             if req.rid == rid:
                 return req
-        adm = getattr(self, "_admitting", None)
-        if adm is not None and adm["req"].rid == rid:
-            return adm["req"]
         return self._finished.get(rid)
 
     def failure(self, rid: int) -> Optional[Exception]:
@@ -557,11 +533,10 @@ class _BatcherBase:
         caller re-owns it (the gateway's drain-requeue path moves the
         request to a survivor and resumes token-exact from
         ``prompt ⧺ delivered``). Pending requests leave the queue;
-        active ones release their slot (and cache rows); a mid-admission
-        paged request releases its pages, same mechanics as deadline
-        expiry. Returns True when something was withdrawn; False for an
-        unknown rid or a terminal request (finished results stay
-        poppable, failures stay raised by ``pop_result``)."""
+        active ones release their slot (and cache rows), same mechanics
+        as deadline expiry. Returns True when something was withdrawn;
+        False for an unknown rid or a terminal request (finished results
+        stay poppable, failures stay raised by ``pop_result``)."""
         with self._intake:
             for req in list(self._pending):
                 if req.rid == rid:
@@ -573,12 +548,6 @@ class _BatcherBase:
                 self._release_slot(slot)
                 req.slot = None
                 return True
-        adm = getattr(self, "_admitting", None)
-        if adm is not None and adm["req"].rid == rid:
-            self._release_row(adm["row"])
-            self._free_slots.append(adm["slot"])
-            self._admitting = None
-            return True
         return False
 
 
@@ -771,15 +740,6 @@ class PagedContinuousBatcher(_BatcherBase):
     reserved SCRATCH page (pool row n_pages) with dec_len 0, so their
     garbage decode writes land in scratch and never touch a live page.
 
-    decode_block=K (greedy only): pure-decode phases run K steps as ONE
-    compiled executable with on-device argmax feedback — one dispatch
-    and one K*B-token download per K tokens instead of K dispatches
-    each hauling [B, V] logits to the host. Where per-dispatch latency
-    dominates a small model's decode compute this is the
-    serving-throughput lever (not measured on the chip). Token-exact vs the
-    per-step path; EOS/budget overshoot inside a block is discarded on
-    the host and its K/V rows land in the slot's own pages or scratch.
-
     policy:
       * ``"reserve"`` — admission reserves the worst-case page count
         (ceil((prompt+max_new)/bs)) up front; head-of-line blocks when
@@ -802,11 +762,9 @@ class PagedContinuousBatcher(_BatcherBase):
                  cache_quant: Optional[str] = None,
                  kv_quant: Optional[str] = None,
                  tier_quant: Optional[str] = None,
-                 fused_admission: bool = False,
                  do_sample: bool = False, temperature: float = 1.0,
                  top_k: int = 0, top_p: Optional[float] = None,
                  seed: Optional[int] = None,
-                 decode_block: Optional[int] = None,
                  max_queue_depth: Optional[int] = None,
                  default_deadline_s: Optional[float] = None,
                  prefix_cache: bool = False,
@@ -829,9 +787,7 @@ class PagedContinuousBatcher(_BatcherBase):
         contract = getattr(model, "paged_serving_contract", dict)()
         asked = dict(prefix_cache=prefix_cache, kv_quant=kv_quant,
                      cache_quant=cache_quant, tier_quant=tier_quant,
-                     draft_model=draft_model,
-                     fused_admission=fused_admission,
-                     session_store=session_store)
+                     draft_model=draft_model, session_store=session_store)
         for option, why in contract.get("unsupported", {}).items():
             if asked.get(option):
                 raise ValueError(
@@ -848,22 +804,10 @@ class PagedContinuousBatcher(_BatcherBase):
                 "cachekv quant scales are per-request, so a shared page "
                 "would replay with the wrong scales — use static "
                 "calibration or disable one")
-        if prefix_cache and fused_admission:
-            raise ValueError(
-                "prefix_cache is not supported with fused_admission "
-                "(the fused chunk streams the FULL prompt at a fixed "
-                "offset grid; a cached-prefix suffix start would need a "
-                "second executable per offset)")
         if draft_model is not None:
             if do_sample:
                 raise ValueError("speculative decoding is greedy-only "
                                  "(draft_model requires do_sample=False)")
-            if decode_block:
-                raise ValueError("draft_model and decode_block are both "
-                                 "decode-dispatch amortizers; pick one")
-            if fused_admission:
-                raise ValueError("draft_model is not supported with "
-                                 "fused_admission")
             if cache_quant:
                 raise ValueError("draft_model is not supported with "
                                  "dynamic cachekv quant")
@@ -877,15 +821,6 @@ class PagedContinuousBatcher(_BatcherBase):
                 raise ValueError(
                     f"draft vocab {draft_model.config.vocab_size} != "
                     f"target vocab {model.config.vocab_size}")
-        if decode_block is not None:
-            if decode_block < 2:
-                raise ValueError("decode_block must be >= 2 (1 is the "
-                                 "plain per-step path)")
-            if do_sample:
-                # the in-block feedback is an on-device argmax; sampled
-                # selection stays on the host path
-                raise ValueError("decode_block requires greedy decoding "
-                                 "(do_sample=False)")
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         if cache_quant not in (None, "dynamic_int8"):
@@ -934,24 +869,9 @@ class PagedContinuousBatcher(_BatcherBase):
             raise ValueError("cache_quant='dynamic_int8' needs "
                              "prefill_chunk >= 2 (a 1-token chunk is "
                              "indistinguishable from a decode step)")
-        if fused_admission and not prefill_chunk:
-            raise ValueError("fused_admission needs prefill_chunk (the "
-                             "chunk width of the fused executable)")
-        if fused_admission and cache_quant:
-            raise ValueError("fused_admission + dynamic cachekv quant is "
-                             "not supported; use static calibration")
         if prefill_chunk is not None and prefill_chunk > s_max:
             raise ValueError(f"prefill_chunk={prefill_chunk} exceeds "
                              f"s_max={s_max}")
-        if fused_admission:
-            cap = -(-s_max // block_size) * block_size
-            if cap % prefill_chunk:
-                # the fused chunk is FIXED-width; a capacity-clamped tail
-                # would re-pad past the block table and (via jnp's index
-                # clamping) overwrite the sequence's real last page
-                raise ValueError(
-                    f"fused_admission needs the slot capacity ({cap}) to "
-                    f"be a multiple of prefill_chunk ({prefill_chunk})")
         cfg = model.config
         self._check_window(cfg, s_max)
         self.model = model
@@ -1218,24 +1138,6 @@ class PagedContinuousBatcher(_BatcherBase):
             from ..perf.buckets import BucketLadder
             self._cu_ladder = BucketLadder.pow2(hi=s_max)
         self.prefill_chunk = prefill_chunk
-        self.fused_admission = fused_admission
-        self._admitting: Optional[dict] = None
-        if fused_admission:
-            # idle chunk inputs are byte-identical every step: build once
-            self._idle_chunk = (
-                paddle.to_tensor(np.zeros((prefill_chunk,), np.int64)),
-                paddle.to_tensor(np.full((1, self.blocks_per_seq),
-                                         self._scratch, np.int32)),
-                paddle.to_tensor(np.array([0], np.int32)),
-                paddle.to_tensor(np.array([0], np.int32)))
-        if fused_admission:
-            if compile:
-                from .. import jit
-                self._fused_fn = jit.to_static(model.paged_fused_step,
-                                               donate_args=(5,))
-                self._fused_fn._opprof_label = "serving.fused"
-            else:
-                self._fused_fn = model.paged_fused_step
         if compile:
             from .. import jit
             # donate the state pytree (arg 1): the page pool is the big
@@ -1245,26 +1147,6 @@ class PagedContinuousBatcher(_BatcherBase):
             self._step_fn._opprof_label = "serving.paged_decode"
         else:
             self._step_fn = model.paged_decode_step
-        self.decode_block = decode_block
-        if decode_block:
-            # K decode steps unrolled into ONE executable with on-device
-            # greedy feedback: one dispatch (and one host round trip for
-            # K*B token ids instead of K full [B, V] logits downloads)
-            # per K tokens.
-            def _block_body(tok, state, _K=decode_block, _m=model):
-                toks = []
-                for _ in range(_K):
-                    logits, state = _m.paged_decode_step(tok, state)
-                    tok = paddle.argmax(logits, axis=-1)
-                    toks.append(tok)
-                return paddle.stack(toks), state          # [K, B]
-            if compile:
-                from .. import jit
-                self._block_fn = jit.to_static(_block_body,
-                                               donate_args=(1,))
-                self._block_fn._opprof_label = "serving.decode_block"
-            else:
-                self._block_fn = _block_body
         if prefill_chunk is not None:
             # one fixed-width append executable serves EVERY prompt
             # length (vLLM chunked prefill); without it each distinct
@@ -1380,12 +1262,13 @@ class PagedContinuousBatcher(_BatcherBase):
     def _pages_for(self, n_rows: int) -> int:
         return -(-n_rows // self.block_size)
 
-    def _alloc_pages_row(self, row: np.ndarray, upto_row: int) -> bool:
-        """Grow a block-table row (a view into self._bt or a detached
-        admission row) so rows [0, upto_row) are backed. A dry free list
-        LRU-evicts unpinned prefix-cache chains first (cached-but-idle
-        pages are reclaimable capacity, not occupancy). Returns False
-        (allocating nothing) if even that can't cover it."""
+    def _alloc_pages(self, slot: int, upto_row: int) -> bool:
+        """Grow the slot's block-table row so rows [0, upto_row) are
+        backed. A dry free list LRU-evicts unpinned prefix-cache chains
+        first (cached-but-idle pages are reclaimable capacity, not
+        occupancy). Returns False (allocating nothing) if even that can't
+        cover it."""
+        row = self._bt[slot]
         need_blocks = self._pages_for(upto_row)
         have = int(np.sum(row != self._scratch))
         grow = need_blocks - have
@@ -1398,9 +1281,6 @@ class PagedContinuousBatcher(_BatcherBase):
         for b in range(have, need_blocks):
             row[b] = self._free_pages.pop()
         return True
-
-    def _alloc_pages(self, slot: int, upto_row: int) -> bool:
-        return self._alloc_pages_row(self._bt[slot], upto_row)
 
     def _available_pages(self) -> int:
         """Pages an allocation could obtain right now: the free list plus
@@ -1687,24 +1567,22 @@ class PagedContinuousBatcher(_BatcherBase):
         if self._promoter is not None:
             self._promoter.close()
 
-    def _release_row(self, row: np.ndarray, keep=()):
-        """Reset a block-table row to scratch, returning its pages to the
-        free list — except ``keep`` (pages the prefix cache owns: the
-        cache's refcounts, not this row, decide their lifetime)."""
-        for b in range(self.blocks_per_seq):
-            if row[b] != self._scratch:
-                if int(row[b]) not in keep:
-                    self._free_pages.append(int(row[b]))
-                row[b] = self._scratch
-
     def _release_slot(self, slot: int):
+        """Reset the slot's block-table row to scratch, returning its pages
+        to the free list — except those the prefix cache owns (the cache's
+        refcounts, not this row, decide their lifetime)."""
         keep = ()
         if self.prefix_cache is not None:
             nodes = self._slot_nodes.pop(slot, None)
             if nodes:
                 self.prefix_cache.unpin(nodes)
                 keep = {n.page for n in nodes}
-        self._release_row(self._bt[slot], keep)
+        row = self._bt[slot]
+        for b in range(self.blocks_per_seq):
+            if row[b] != self._scratch:
+                if int(row[b]) not in keep:
+                    self._free_pages.append(int(row[b]))
+                row[b] = self._scratch
         self._dec[slot] = 0
         if self.draft_model is not None:
             self._ddec[slot] = 0
@@ -1730,11 +1608,6 @@ class PagedContinuousBatcher(_BatcherBase):
         used = set()
         for slot in range(self.max_batch):
             for b in self._bt[slot]:
-                if b != self._scratch:
-                    used.add(int(b))
-        adm = self._admitting
-        if adm is not None:
-            for b in adm["row"]:
                 if b != self._scratch:
                     used.add(int(b))
         if self._promo is not None:
@@ -2288,8 +2161,7 @@ class PagedContinuousBatcher(_BatcherBase):
 
     def _grow_for_step(self):
         """ondemand: every active slot is about to write kv row dec[slot];
-        back it with a page, preempting (slots, then any in-flight fused
-        admission) if the pool is dry."""
+        back it with a page, preempting if the pool is dry."""
         with _span("serving.grow"):
             for slot in list(self._admit_order):
                 if slot not in self._slot_req:
@@ -2303,23 +2175,13 @@ class PagedContinuousBatcher(_BatcherBase):
                         continue
                     if self._preempt_latest(protect=slot):
                         continue
-                    if self._admitting is not None:
-                        # the admission's detached row holds pages too —
-                        # evict it rather than failing a live decode
-                        self._abort_admission()
-                        continue
                     raise RuntimeError(
                         f"page pool exhausted: slot {slot} needs a page at "
                         f"row {int(self._dec[slot])}, no free pages and no "
                         f"other request to preempt (n_pages={self.n_pages})")
 
-    # -- fused admission (vLLM unified scheduling) --------------------------
-    def _has_work(self) -> bool:
-        return bool(self._pending or self._slot_req or self._admitting)
-
     def _admission_plan(self, req: Request, m_rows: int = 0):
-        """The ONE home of the resume-ids / chunk-padding / page-budget
-        arithmetic (used by synchronous admission and the fused path).
+        """Admission's resume-ids / chunk-padding / page-budget arithmetic.
         ``m_rows`` is the cached-prefix row count: only the SUFFIX is
         prefilled, so chunk/ladder padding applies to the suffix and is
         clamped to the capacity left after the cached rows (pad rows past
@@ -2344,120 +2206,6 @@ class PagedContinuousBatcher(_BatcherBase):
         else:
             upto = max(padded_len, L + 1)
         return ids_np, L, padded_len, upto
-
-    def _start_admission(self) -> bool:
-        """Reserve a slot + pages for the next pending request; its
-        prompt then streams through the fused step one chunk per step
-        while the other slots keep decoding."""
-        if self._admitting or not self._pending or not self._free_slots:
-            return False
-        req = self._pending[0]
-        ids_np, L, padded_len, upto = self._admission_plan(req)
-        if self._pages_for(upto) > len(self._free_pages):
-            return False
-        with self._intake:
-            self._pending.pop(0)
-        slot = self._free_slots.pop(0)
-        row = np.full((self.blocks_per_seq,), self._scratch, np.int32)
-        if not self._alloc_pages_row(row, upto):
-            raise RuntimeError("page accounting bug: admission gate "
-                               "passed but allocation failed")
-        padded = np.zeros((padded_len,), np.int64)
-        padded[:L] = ids_np
-        # the slot's MAIN row stays scratch until admission completes, so
-        # its garbage decode writes land in the scratch page instead of
-        # the rows the chunks are filling
-        self._admitting = {"req": req, "slot": slot, "row": row,
-                           "ids": padded, "L": L, "offset": 0}
-        self._trace_admit_begin(req)
-        self._trace_prefill_begin(req)
-        return True
-
-    def _abort_admission(self):
-        """Preempt the in-flight admission: pages back to the pool, the
-        request to the FRONT of the queue (offset resets; recompute on
-        resume is exact, same as slot preemption)."""
-        adm = self._admitting
-        self._release_row(adm["row"])
-        self._free_slots.append(adm["slot"])
-        self._pending.insert(0, adm["req"])
-        self._admitting = None
-        self._trace_close(adm["req"], preempted=1)
-        self._tele.on_preempt()
-        self.audit_pages()
-
-    def _fused_chunk_inputs(self):
-        import paddle_tpu as paddle
-        adm = self._admitting
-        if adm is None:
-            return self._idle_chunk
-        C = self.prefill_chunk
-        o = adm["offset"]
-        ids = adm["ids"][o:o + C]   # always full width: cap % C == 0
-        at = adm["L"] - 1 - o
-        at = at if 0 <= at < C else 0
-        return (paddle.to_tensor(ids),
-                paddle.to_tensor(adm["row"][None, :]),
-                paddle.to_tensor(np.array([o], np.int32)),
-                paddle.to_tensor(np.array([at], np.int32)))
-
-    def _finish_admission(self, chunk_logits, finished: List[int]):
-        """Advance the in-flight admission by one chunk; on the final
-        chunk, install the block-table row and promote the request to a
-        decoding slot."""
-        adm = self._admitting
-        if adm is None:
-            return
-        C = self.prefill_chunk
-        o, L = adm["offset"], adm["L"]
-        had_last = o <= L - 1 < o + C
-        adm["offset"] = o + C
-        if not had_last:
-            return
-        req, slot = adm["req"], adm["slot"]
-        self._trace_prefill_end(req, prompt_tokens=L, fused=1)
-        with _span("serving.fetch"):
-            chunk_np = np.asarray(chunk_logits._data)
-        tok = int(self._pick(chunk_np)[0])
-        self._bt[slot] = adm["row"]
-        self._dec[slot] = L
-        self._last_tok[slot] = tok
-        req.slot = slot
-        req.tokens.append(tok)
-        self._tele.on_admit()
-        self._tele.on_token(req)
-        self._slot_req[slot] = req
-        self._admit_order.append(slot)
-        self._admitting = None
-        self._trace_admit_end(req, slot)
-        if self._maybe_finish(req, tok):
-            finished.append(req.rid)
-
-    def _step_fused(self) -> List[int]:
-        """One fused executable call: every decode slot advances AND the
-        in-flight admission streams its next chunk — decode throughput
-        never pauses for a prefill. With NO admission in flight the plain
-        decode executable runs instead: an idle chunk would still compute
-        C token positions through the model for nothing."""
-        import paddle_tpu as paddle
-        finished: List[int] = []
-        self._start_admission()
-        if self._admitting is None:
-            self._decode_tail(finished)
-            return finished
-        self._step_prologue()
-        n_active = len(self._slot_req)
-        t0 = _time.perf_counter()
-        with _span("serving.launch"), paddle.no_grad():
-            tok_t = paddle.to_tensor(self._last_tok)
-            ids_t, row_t, dec_t, at_t = self._fused_chunk_inputs()
-            dec_logits, chunk_logits, self._state = self._fused_fn(
-                tok_t, ids_t, row_t, dec_t, at_t, self._state)
-        self._advance_decoders(dec_logits, finished)
-        self._finish_admission(chunk_logits, finished)
-        self._tele.on_decode_time(_time.perf_counter() - t0,
-                                  tokens=n_active)
-        return finished
 
     def _advance_decoders(self, logits, finished: List[int]):
         """Consume a step's decode logits: advance timelines, append the
@@ -2491,18 +2239,13 @@ class PagedContinuousBatcher(_BatcherBase):
         self._sync_tables()
 
     def _decode_tail(self, finished: List[int]):
-        """The decode-only step body (shared by the plain engine and the
-        fused engine's idle steps)."""
+        """The step's decode launch: a speculative round where a draft
+        model was given and the round can run, else one plain step."""
         import paddle_tpu as paddle
         if not self._slot_req:
             return
         if self.draft_model is not None \
                 and self._speculative_tail(finished):
-            return
-        if self.decode_block and not self._pending \
-                and self._admitting is None \
-                and self._block_backed(self.decode_block):
-            self._decode_block_tail(finished)
             return
         self._step_prologue()
         n_active = len(self._slot_req)
@@ -2516,86 +2259,6 @@ class PagedContinuousBatcher(_BatcherBase):
         self._advance_decoders(logits, finished)
         self._tele.on_decode_time(_time.perf_counter() - t0,
                                   tokens=n_active)
-
-    def _block_backed(self, K: int) -> bool:
-        """A K-step block is safe when, for every active slot, the rows
-        it will KEEP are page-backed and dec+K stays inside the slot
-        window. Rows a slot writes past its remaining budget (it gets
-        evicted at max_new anyway) or past its backed pages land in the
-        SCRATCH page (unbacked block-table entries stay scratch), so
-        only the keep-rows need real pages. Growth here never preempts —
-        a dry pool falls back to the per-step path, whose preemption
-        logic stays the single source of that policy. Feasibility is
-        probed for ALL slots before ANY page moves: a declined block
-        must not leave earlier slots hoarding pages they will not use
-        for K more steps (that would push the per-step path into
-        preemptions the probe itself caused)."""
-        cap = self.blocks_per_seq * self.block_size
-        plan = []                      # (slot, upto) to allocate on pass
-        need = 0
-        for slot in list(self._admit_order):
-            req = self._slot_req.get(slot)
-            if req is None:
-                continue
-            if int(self._dec[slot]) + K > cap:
-                return False
-            keep = min(K, req.max_new_tokens - len(req.tokens))
-            if keep <= 0:
-                continue
-            upto = int(self._dec[slot]) + keep
-            have = int(np.sum(self._bt[slot] != self._scratch))
-            need += max(0, self._pages_for(upto) - have)
-            plan.append((slot, upto))
-        if self.policy != "ondemand":
-            return True                # reserve backed everything upfront
-        if need > self._available_pages():
-            return False
-        for slot, upto in plan:
-            if not self._alloc_pages(slot, upto):   # pragma: no cover
-                raise RuntimeError("page accounting bug: block probe "
-                                   "passed but allocation failed")
-        return True
-
-    def _decode_block_tail(self, finished: List[int]):
-        """Run one compiled K-step decode block and consume its K*B
-        tokens on the host: per sub-step, append to each still-live
-        request, finishing/evicting exactly as the per-step path would.
-        A slot that finishes mid-block decoded garbage for the remaining
-        sub-steps — those tokens are discarded here, and their K/V rows
-        went to its own (about-to-be-freed) pages or scratch."""
-        import paddle_tpu as paddle
-        K = self.decode_block
-        self._tele.on_step(K)
-        self._tele.on_decode_block()
-        self._tele.set_gauges(len(self._pending), len(self._slot_req))
-        self._sync_tables()
-        n_active = len(self._slot_req)
-        t0 = _time.perf_counter()
-        with _span("serving.launch"), paddle.no_grad():
-            self._decode_launch_c.inc()
-            self._kv_write_c.inc()
-            tok_t = paddle.to_tensor(self._last_tok)
-            toks, self._state = self._block_fn(tok_t, self._state)
-        with _span("serving.fetch"):
-            toks_np = np.asarray(toks._data)              # [K, B]
-        self._tele.on_decode_time(_time.perf_counter() - t0, K,
-                                  tokens=K * n_active)
-        with _span("serving.pick"):
-            # survivors consumed all K rows; evicted slots' counters are
-            # reset at their next admission
-            self._dec += K * np.asarray(self._slot_active_mask(), np.int32)
-            for k in range(K):
-                # occupancy at each sub-step's ENTRY (post prior
-                # evictions), matching the per-step path's
-                # _step_prologue accounting
-                self._tele.on_occupancy(len(self._slot_req))
-                for slot, req in list(self._slot_req.items()):
-                    tok = int(toks_np[k, slot])
-                    req.tokens.append(tok)
-                    self._tele.on_token(req)
-                    self._last_tok[slot] = tok
-                    if self._maybe_finish(req, tok):
-                        finished.append(req.rid)
 
     # -- in-batcher speculative decoding ------------------------------------
     def _sync_draft_tables(self):
@@ -2648,8 +2311,8 @@ class PagedContinuousBatcher(_BatcherBase):
                 self.spec_stats["fallback_steps"] += 1
                 return False
         if self.policy == "ondemand":
-            # probe-then-alloc over ALL slots (the _block_backed rule): a
-            # declined round must not strand pages it already moved
+            # probe-then-alloc over ALL slots: a declined round must not
+            # strand pages it already moved
             plan = []
             need = 0
             for slot, _ in reqs:
@@ -2760,8 +2423,6 @@ class PagedContinuousBatcher(_BatcherBase):
     def _step_impl(self) -> List[int]:
         """Admit, grow pages (ondemand), decode one token per active slot,
         evict finished. Returns rids finishing during THIS call."""
-        if self.fused_admission:
-            return self._step_fused()
         finished = self._admit()
         self._decode_tail(finished)
         return finished

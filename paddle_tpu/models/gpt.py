@@ -642,8 +642,7 @@ class GPT2ForCausalLM(Layer):
         ``draft_k`` tokens per round, this model verifies them in one
         forward — token-exact vs ``generate``/``generate_paged`` while
         spending 1 target dispatch per 1..draft_k+1 accepted tokens (the
-        dispatch-latency lever, complementary to decode_block which
-        amortizes dispatches without a draft). See _speculative_loop."""
+        dispatch-latency lever). See _speculative_loop."""
         return self._speculative_loop(self, draft_model, input_ids,
                                       max_new_tokens, draft_k, block_size,
                                       eos_id, compile, return_stats)
@@ -711,63 +710,6 @@ class GPT2ForCausalLM(Layer):
         return self._paged_generate_loop(self, input_ids, max_new_tokens,
                                          block_size, blocks_per_seq,
                                          decode_fn)
-
-    def paged_fused_step(self, tok, chunk_ids, chunk_bt, chunk_dec,
-                         chunk_at, state):
-        """ONE packed call advancing every decode slot AND one admission
-        chunk (vLLM unified scheduling; see the Llama twin's docstring
-        for the layout). Returns (decode_logits [B, V], chunk_logits
-        [1, V], new_state)."""
-        import paddle_tpu as paddle
-        from .. import ops
-        from ..incubate.nn.functional.decode_attention import \
-            block_multihead_attention
-
-        b = tok.shape[0]
-        c = chunk_ids.shape[0]
-        t = state["dec_lens"]
-        bt = ops.concat([state["block_tables"], chunk_bt], axis=0)
-        enc = paddle.to_tensor(np.zeros((b + 1,), np.int32))
-        this = paddle.to_tensor(
-            np.concatenate([np.ones((b,), np.int32), [c]]).astype(np.int32))
-        dec_call = ops.concat([t, chunk_dec], axis=0)
-        cu_q = paddle.to_tensor(np.concatenate(
-            [np.arange(b + 1, dtype=np.int32), [b + c]]).astype(np.int32))
-        if state.get("cache_scales") is not None:
-            raise NotImplementedError(
-                "fused admission + dynamic cachekv quant: use static "
-                "calibration (calibrate_cachekv_int8)")
-
-        all_tok = ops.concat([tok.reshape([b]), chunk_ids.reshape([c])],
-                             axis=0)
-        # positions: decode rows at t, chunk rows at chunk_dec + local
-        pos = ops.concat([t.reshape([b]),
-                          (chunk_dec.reshape([1]) + paddle.to_tensor(
-                              np.arange(c, dtype=np.int32))).reshape([c])],
-                         axis=0)
-        hidden = self.transformer.wte(all_tok) + self.transformer.wpe(pos)
-        hidden = self.transformer.drop(hidden)
-        new_layers = []
-        for li, (blk, (kc, vc)) in enumerate(zip(self.transformer.h,
-                                                 state["layers"])):
-            x = blk.ln_1(hidden)
-            qkv = blk.attn.c_attn(x)                     # [B+C, 3*H*D]
-            out, _, kc, vc = block_multihead_attention(
-                qkv, kc, vc, enc, dec_call, this, None, None, cu_q, cu_q,
-                bt, block_size=state["block_size"],
-                **_cache_scale_kwargs(self._cachekv_scales, li))
-            hidden = hidden + blk.attn.resid_dropout(blk.attn.c_proj(out))
-            hidden = hidden + blk.mlp(blk.ln_2(hidden))
-            new_layers.append((kc, vc))
-        hidden = self.transformer.ln_f(hidden)
-        dec_logits = self._logits(hidden[:b])            # [B, V]
-        chunk_h = hidden[b:]                             # [C, E]
-        oh = F.one_hot(chunk_at.reshape([1]).astype("int64"),
-                       c).astype(chunk_h.dtype)
-        chunk_logits = self._logits(
-            paddle.einsum("oc,ce->oe", oh, chunk_h))     # [1, V]
-        new_state = dict(state, layers=new_layers, dec_lens=t + 1)
-        return dec_logits, chunk_logits, new_state
 
     @staticmethod
     def _select_token(logits_np, do_sample, temperature, top_k, top_p, rng):

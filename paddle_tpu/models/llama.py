@@ -1147,82 +1147,6 @@ class LlamaForCausalLM(Layer):
         new_state = dict(state, layers=new_layers, dec_lens=t + 1)
         return logits, new_state
 
-    def paged_fused_step(self, tok, chunk_ids, chunk_bt, chunk_dec,
-                         chunk_at, state):
-        """ONE packed call advancing every decode slot AND one admission
-        chunk (vLLM unified/continuous scheduling: decode never stalls
-        while a prompt prefills).
-
-        tok [B]: this step's decode tokens (parked slots carry garbage).
-        chunk_ids [C]: the admission chunk (zeros when nothing admits).
-        chunk_bt [1, bps]: the admitting sequence's block-table row (all
-        scratch when idle). chunk_dec [1]: rows already written by prior
-        chunks. chunk_at [1]: position of the last real token within this
-        chunk (for its logits). The packed batch is B+1 sequences /
-        B+C tokens: sequences 0..B-1 decode (this=1), sequence B is the
-        chunk (this=C); ONE executable serves every occupancy and every
-        prompt length. Returns (decode_logits [B, V], chunk_logits
-        [1, V], new_state).
-        """
-        import paddle_tpu as paddle
-        from .. import ops
-        from ..incubate.nn.functional.decode_attention import \
-            block_gqa_attention
-
-        self._check_paged_servable()
-        cfg = self.config
-        b = tok.shape[0]
-        c = chunk_ids.shape[0]
-        h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        t = state["dec_lens"]
-        bt = ops.concat([state["block_tables"], chunk_bt], axis=0)
-        enc = paddle.to_tensor(np.zeros((b + 1,), np.int32))
-        this = paddle.to_tensor(
-            np.concatenate([np.ones((b,), np.int32), [c]]).astype(np.int32))
-        dec_call = ops.concat([t, chunk_dec], axis=0)
-        cu_q = paddle.to_tensor(np.concatenate(
-            [np.arange(b + 1, dtype=np.int32), [b + c]]).astype(np.int32))
-        model = self.model
-        cos_tab, sin_tab = model._cos, model._sin
-
-        all_tok = ops.concat([tok.reshape([b]), chunk_ids.reshape([c])],
-                             axis=0)
-        hidden = model.embed_tokens(all_tok)              # [B+C, E]
-        dyn = state.get("cache_scales")
-        new_layers = []
-        for li, (layer, (kc, vc)) in enumerate(zip(model.layers,
-                                                   state["layers"])):
-            attn = layer.self_attn
-            x = layer.input_layernorm(hidden)
-            q = attn.q_proj(x).reshape([b + c, h, d])
-            k = attn.k_proj(x).reshape([b + c, kvh, d])
-            v = attn.v_proj(x).reshape([b + c, kvh, d])
-            if dyn is not None:
-                # the chunk sequence (row B) has no per-slot scale row;
-                # the batcher gates this combination up front
-                raise NotImplementedError(
-                    "fused admission + dynamic cachekv quant: use static "
-                    "calibration (calibrate_cachekv_int8)")
-            kwargs = self._layer_cache_scales(li)
-            out, kc, vc = block_gqa_attention(
-                q, k, v, kc, vc, enc, dec_call, this, cu_q, bt,
-                block_size=state["block_size"], rope_cos=Tensor(cos_tab),
-                rope_sin=Tensor(sin_tab), **kwargs)
-            hidden = hidden + attn.o_proj(out.reshape([b + c, h * d]))
-            hidden = hidden + layer.mlp(
-                layer.post_attention_layernorm(hidden))
-            new_layers.append((kc, vc))
-        hidden = model.norm(hidden)
-        dec_logits = self._lm_logits(hidden[:b])          # [B, V]
-        chunk_h = hidden[b:]                              # [C, E]
-        oh = F.one_hot(chunk_at.reshape([1]).astype("int64"),
-                       c).astype(chunk_h.dtype)           # [1, C]
-        chunk_logits = self._lm_logits(
-            paddle.einsum("oc,ce->oe", oh, chunk_h))      # [1, V]
-        new_state = dict(state, layers=new_layers, dec_lens=t + 1)
-        return dec_logits, chunk_logits, new_state
-
     def generate_paged(self, input_ids, max_new_tokens, block_size=64,
                        blocks_per_seq=None, decode_fn=None):
         """Greedy decode over the paged GQA cache (shared driver with

@@ -789,8 +789,6 @@ class SambaYForCausalLM(Layer):
                 "tier_quant": "needs prefix_cache",
                 "draft_model": "a rejected proposal would have to roll the "
                                "recurrent state back",
-                "fused_admission": "the fused step has no slot state to "
-                                   "start or carry",
                 "session_store": f"a paused session resumes from cached "
                                  f"pages; {pages}",
             }}

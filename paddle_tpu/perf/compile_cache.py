@@ -6,11 +6,10 @@ Two layers, one goal: recompiles become rare AND measurable.
     on-disk compilation cache (XLA executables survive process restarts).
     It is the one place in the repo that configures that cache: the
     entry points that compile at real sizes (``bench.py``,
-    ``chip_smoke.py``, ``benchmarks/bench_decode.py``, the TPU test tier)
-    call it before their first compile. The directory is
-    ``JAX_COMPILATION_CACHE_DIR`` where that is set (JAX reads it itself)
-    and ``<checkout>/.jax_cache`` otherwise — always a fixed path, because
-    a cache that moves never hits.
+    ``chip_smoke.py``, the TPU test tier) call it before their first
+    compile. The directory is ``JAX_COMPILATION_CACHE_DIR`` where that is
+    set (JAX reads it itself) and ``<checkout>/.jax_cache`` otherwise —
+    always a fixed path, because a cache that moves never hits.
 
   * **Dispatch-cache counters** — every program cache the framework keeps
     (``jit.StaticFunction`` signatures, ``jit.TrainStep`` entries, the

@@ -375,8 +375,8 @@ def test_scanned_decoder_layer_fwd_bwd_compiles(chip, monkeypatch):
         assert _kernel_calls(compiled, kernel), kernel
 
 
-def test_sambay_decode_blocks_compile_with_the_kernel(chip, monkeypatch):
-    """The window and the shared-pool decode blocks of SambaY at the cell
+def test_sambay_decoder_blocks_compile_with_the_kernel(chip, monkeypatch):
+    """The window and the shared-pool decoder blocks of SambaY at the cell
     ``phi4flash-reason-open``'s shapes (64 slots, 40/20 heads of 64 as 10
     key groups of 128, rings of 512 rows = 32 pages a slot, 16,385 pool
     pages of 16 rows, 256 pages a sequence): the row write (whole pages

@@ -248,17 +248,3 @@ def test_speculative_with_prefix_cache_composes():
     assert b.spec_stats["rounds"] > 0
     b.audit_pages()
     assert _pages_leaked() == 0
-
-
-def test_speculative_composition_gates():
-    m = _model()
-    dm = _model(1)
-    with pytest.raises(ValueError):
-        PagedContinuousBatcher(m, compile=False, draft_model=dm,
-                               draft_k=0)
-    with pytest.raises(ValueError):
-        PagedContinuousBatcher(m, compile=False, draft_model=dm,
-                               do_sample=True)
-    with pytest.raises(ValueError):
-        PagedContinuousBatcher(m, compile=False, prefix_cache=True,
-                               cache_quant="dynamic_int8")

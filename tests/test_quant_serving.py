@@ -136,15 +136,21 @@ def test_cachekv_int8_close_to_fp_cache():
     assert "int8" not in str(state2["layers"][0][0].dtype)
 
 
-def test_cachekv_int8_serving_algebra_exact():
+@pytest.mark.parametrize("options", [
+    dict(compile=False), dict(prefill_chunk=8, compile=True)],
+    ids=["eager", "chunked_compiled"])
+def test_cachekv_int8_serving_algebra_exact(options):
     """Quantized-cache generate_paged vs the quantized-cache batcher must
-    be token-exact (the int8 cache changes logits, never the scheduler)."""
+    be token-exact (the int8 cache changes logits, never the scheduler):
+    static calibrated pages, whole-prompt and chunked admission, slots
+    reused."""
+    from test_paged_batching import _retry_load_flake
     m = _llama_eval()
     rng = np.random.RandomState(1)
     calib = paddle.to_tensor(rng.randint(0, 128, (2, 10)).astype(np.int64))
     with paddle.no_grad():
         m.calibrate_cachekv_int8(calib)
-    prompts = [rng.randint(0, 128, (s,)) for s in (5, 8)]
+    prompts = [rng.randint(0, 128, (s,)) for s in (5, 11, 8)]
 
     def solo(p, n):
         ids = paddle.to_tensor(np.asarray(p, np.int64)[None])
@@ -152,13 +158,17 @@ def test_cachekv_int8_serving_algebra_exact():
             return m.generate_paged(ids, max_new_tokens=n,
                                     block_size=8).numpy()[0]
 
-    b = PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
-                               compile=False)
-    assert str(b._state["layers"][0][0].dtype).endswith("int8")
-    rids = [b.submit(p, 5) for p in prompts]
-    outs = b.run_until_done()
-    for rid, p in zip(rids, prompts):
-        np.testing.assert_array_equal(outs[rid], solo(p, 5))
+    def body():
+        b = PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
+                                   **options)
+        assert str(b._state["layers"][0][0].dtype).endswith("int8")
+        rids = [b.submit(p, 5) for p in prompts]
+        outs = b.run_until_done()
+        for rid, p in zip(rids, prompts):
+            np.testing.assert_array_equal(outs[rid], solo(p, 5))
+        assert b.audit_pages() == 0
+
+    _retry_load_flake(body, attempts=3 if options["compile"] else 1)
 
 
 def test_cachekv_int8_mha_functional():
@@ -589,53 +599,3 @@ def test_chunked_int8_clip_telemetry():
     assert not [w for w in caught2
                 if issubclass(w.category, RuntimeWarning)
                 and "top quantization bin" in str(w.message)]
-
-
-def test_dynamic_int8_rejects_bad_configs():
-    m = _llama_eval()
-    with pytest.raises(ValueError, match="unknown cache_quant"):
-        PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
-                               cache_quant="int4", compile=False)
-    with pytest.raises(ValueError, match="not supported"):
-        PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
-                               cache_quant="dynamic_int8", prefill_chunk=8,
-                               fused_admission=True, compile=False)
-    with pytest.raises(ValueError, match="prefill_chunk >= 2"):
-        PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
-                               cache_quant="dynamic_int8", prefill_chunk=1,
-                               compile=False)
-
-
-def test_static_cachekv_int8_with_fused_admission_token_exact():
-    """The fused-admission executable already threads STATIC per-head
-    cache scales (paged_fused_step passes _cachekv_scales); pin the whole
-    combination end-to-end: a calibrated model served through the fused
-    decode+prefill batcher is token-exact vs its own solo paged generate
-    (dynamic x fused remains excluded; static calibration is the
-    documented route)."""
-    from test_paged_batching import _retry_load_flake
-    m = _llama_eval()
-    rng = np.random.RandomState(17)
-    calib = paddle.to_tensor(rng.randint(0, 128, (2, 12)).astype(np.int64))
-    with paddle.no_grad():
-        m.calibrate_cachekv_int8(calib)
-    try:
-        prompts = [rng.randint(0, 128, (s,)) for s in (5, 11, 8)]
-
-        def body():
-            b = PagedContinuousBatcher(m, max_batch=2, s_max=32,
-                                       block_size=8, prefill_chunk=8,
-                                       fused_admission=True, compile=True)
-            assert str(b._state["layers"][0][0].dtype).endswith("int8")
-            rids = [b.submit(p, 5) for p in prompts]
-            outs = b.run_until_done()
-            for rid, p in zip(rids, prompts):
-                ids = paddle.to_tensor(np.asarray(p, np.int64)[None])
-                with paddle.no_grad():
-                    ref = m.generate_paged(ids, max_new_tokens=5,
-                                           block_size=8).numpy()[0]
-                np.testing.assert_array_equal(outs[rid], ref)
-
-        _retry_load_flake(body, attempts=3)
-    finally:
-        m.calibrate_cachekv_int8(None)

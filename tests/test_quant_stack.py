@@ -280,32 +280,6 @@ def test_serving_quantize_mesh_placement_is_numerically_inert():
 
 # -- kv_quant: int8 KV pages --------------------------------------------------
 
-def test_kv_quant_constructor_guards(sharp_lm):
-    def mk(**kw):
-        kw.setdefault("max_batch", 2)
-        kw.setdefault("s_max", 64)
-        kw.setdefault("block_size", BLOCK)
-        kw.setdefault("compile", False)
-        return PagedContinuousBatcher(sharp_lm, **kw)
-
-    with pytest.raises(ValueError, match="unknown kv_quant"):
-        mk(kv_quant="int4")
-    with pytest.raises(ValueError, match="calibrate_cachekv_int8"):
-        mk(kv_quant="int8")      # no calibrated scales on the model
-    with pytest.raises(ValueError, match="pick one"):
-        mk(kv_quant="int8", cache_quant="dynamic_int8")
-    with pytest.raises(ValueError, match="unknown tier_quant"):
-        mk(tier_quant="fp8")
-    with pytest.raises(ValueError, match="prefix_cache"):
-        mk(tier_quant="int8")    # tier blobs need the tiered cache
-    sharp_lm.calibrate_cachekv_int8(
-        np.random.RandomState(0).randint(0, 128, (2, 32)))
-    with pytest.raises(ValueError, match="redundant"):
-        mk(tier_quant="int8", prefix_cache=True, host_kv_gib=0.01)
-    with pytest.raises(ValueError, match="draft_model"):
-        mk(kv_quant="int8", draft_model=sharp_lm)
-
-
 def test_kv_quant_int8_pages_match_fp_within_bound(sharp_lm):
     rng = np.random.RandomState(11)
     prompts = [rng.randint(0, 128, (20,)).astype(np.int64)

@@ -291,8 +291,7 @@ def test_chunked_scan_from_a_carried_state_matches_token_by_token(cut, pad):
 
 REFUSED = {"prefix_cache": True, "kv_quant": "int8",
            "cache_quant": "dynamic_int8", "tier_quant": "int8",
-           "draft_model": "a model", "fused_admission": True,
-           "session_store": "/tmp/never-made"}
+           "draft_model": "a model", "session_store": "/tmp/never-made"}
 
 
 @pytest.mark.parametrize("option", list(REFUSED))

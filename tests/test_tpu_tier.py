@@ -469,58 +469,3 @@ def test_paged_exactness_retry_free_on_tpu():
         outs = b.run_until_done()
         for rid, p in zip(rids, prompts):
             np.testing.assert_array_equal(outs[rid], solo(p, 8))
-
-
-def test_fused_serving_on_tpu():
-    """Fused-admission continuous batching (decode + prefill chunks in
-    one executable) token-exact on the chip. Validated on the CPU mesh
-    only so far; `pytest -m tpu` gives it its first on-chip run."""
-    _require_tpu()
-    import time
-
-    import paddle_tpu as paddle
-    from paddle_tpu.inference.serving import PagedContinuousBatcher
-    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
-
-    paddle.seed(0)
-    cfg = llama_tiny_config(vocab_size=1024, hidden_size=256,
-                            num_hidden_layers=4,
-                            max_position_embeddings=512)
-    m = LlamaForCausalLM(cfg)
-    m.eval()
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(0, 1024, (s,)) for s in (17, 64, 128, 41)]
-    b = PagedContinuousBatcher(m, max_batch=4, s_max=256, block_size=32,
-                               prefill_chunk=64, fused_admission=True,
-                               compile=True)
-    rids = [b.submit(p, 16) for p in prompts]
-    t0 = time.perf_counter()
-    outs = b.run_until_done()
-    dt = time.perf_counter() - t0
-    for rid, p in zip(rids, prompts):
-        ids = paddle.to_tensor(np.asarray(p, np.int64)[None])
-        with paddle.no_grad():
-            ref = m.generate(ids, max_new_tokens=16).numpy()[0]
-        np.testing.assert_array_equal(outs[rid], ref)
-    s = b.stats()
-    print(f"[tpu] fused serving: {s['generated_tokens']} tokens in "
-          f"{dt:.1f}s ({s['generated_tokens']/dt:.1f} tok/s), "
-          f"occupancy {s['mean_active_slots']:.2f}")
-
-    # decode_block=8: the K-step executable (on-device argmax feedback)
-    # gets its first hardware compile here; token-exact vs the per-step
-    # result above (bench_decode enables it on TPU)
-    bb = PagedContinuousBatcher(m, max_batch=4, s_max=256, block_size=32,
-                                prefill_chunk=64, fused_admission=True,
-                                decode_block=8, compile=True)
-    rids_b = [bb.submit(p, 16) for p in prompts]
-    t0 = time.perf_counter()
-    outs_b = bb.run_until_done()
-    dt_b = time.perf_counter() - t0
-    for rid, rid_b in zip(rids, rids_b):
-        np.testing.assert_array_equal(outs_b[rid_b], outs[rid])
-    sb = bb.stats()
-    print(f"[tpu] fused serving decode_block=8: "
-          f"{sb['generated_tokens']} tokens in {dt_b:.1f}s "
-          f"({sb['generated_tokens']/dt_b:.1f} tok/s vs "
-          f"{s['generated_tokens']/dt:.1f} per-step)")

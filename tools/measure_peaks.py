@@ -71,8 +71,7 @@ def measure_matmul(iters, sizes=(2048, 4096, 6144, 8192)):
 
 def measure_dispatch(iters):
     """Median wall time of a trivially-small jitted op, i.e. the
-    per-dispatch overhead. This is the cost the serving engine's
-    decode_block=K amortizes: with per-token dispatch the ceiling is
+    per-dispatch overhead: with per-token dispatch the ceiling is
     1/dispatch_latency tokens/s/slot regardless of model size."""
     import jax
     import jax.numpy as jnp
