@@ -759,7 +759,12 @@ class Gateway:
             raise self._failed.pop(gid)
         out = self.result(gid)
         del self._finished[gid]
-        self._sessions.pop(gid, None)
+        sess = self._sessions.pop(gid, None)
+        if sess is not None:
+            # the request is over and its result handed out: a client that
+            # keeps the session keeps its tokens, not the gateway, its
+            # replicas and their page pools on the device
+            sess._gw = None
         return out
 
     def run_until_done(self, max_steps: int = 10000) -> Dict[int, np.ndarray]:

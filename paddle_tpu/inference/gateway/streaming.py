@@ -87,7 +87,8 @@ class StreamingSession:
         sp = self._req.spans.pop("stream", None)
         if sp is not None:
             sp.end(delivered=len(self._req.delivered))
-        self._gw._on_session_closed(self)
+        if self._gw is not None:        # None: the result was popped
+            self._gw._on_session_closed(self)
 
     def __iter__(self) -> Iterator[int]:
         return self

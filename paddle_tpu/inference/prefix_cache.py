@@ -351,20 +351,26 @@ class RadixPrefixCache:
     def evictable_pages(self) -> int:
         """Pages evict() could free right now — ONE walk sharing evict()'s
         victim rule (a device node frees when its entire device subtree is
-        unpinned and promotion-free), so the two can never drift."""
-        def walk(n: _Node) -> Tuple[int, bool]:
-            count = 0
-            free = n.ref == 0 and n.promo is None
-            for c in n.children.values():
-                if c.residency != "device":
-                    continue
-                sub, sub_free = walk(c)
-                count += sub
-                free = free and sub_free
-            return count + (1 if free else 0), free
-
-        return sum(walk(c)[0] for c in self._root.children.values()
-                   if c.residency == "device")
+        unpinned and promotion-free), so the two can never drift. The walk
+        keeps its own stack: a document of 16k tokens is a chain of a
+        thousand blocks, deeper than Python recurses."""
+        order: List[_Node] = []
+        stack = [c for c in self._root.children.values()
+                 if c.residency == "device"]
+        while stack:
+            n = stack.pop()
+            order.append(n)
+            stack.extend(c for c in n.children.values()
+                         if c.residency == "device")
+        pinned_below: Dict[int, bool] = {}     # id(node): a child is held
+        count = 0
+        for n in reversed(order):              # children before parents
+            free = n.ref == 0 and n.promo is None \
+                and not pinned_below.get(id(n), False)
+            count += free
+            if not free:
+                pinned_below[id(n.parent)] = True
+        return count
 
     # -- the serving hot path ------------------------------------------------
     def _blocks(self, tokens) -> List[Tuple[int, ...]]:

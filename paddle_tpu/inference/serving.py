@@ -782,12 +782,16 @@ class PagedContinuousBatcher(_BatcherBase):
         if policy not in ("reserve", "ondemand"):
             raise ValueError(f"unknown policy {policy!r}")
         # a model whose cache is more than pages of K and V says so (see
-        # ``SambaYForCausalLM.paged_serving_contract``): per-slot state
-        # beside the pool, and the options it cannot honour
+        # ``SambaYForCausalLM.paged_serving_contract``, and
+        # ``GlmDsaForCausalLM``'s): per-slot state beside the pool, and the
+        # options it cannot honour
         contract = getattr(model, "paged_serving_contract", dict)()
         asked = dict(prefix_cache=prefix_cache, kv_quant=kv_quant,
                      cache_quant=cache_quant, tier_quant=tier_quant,
-                     draft_model=draft_model, session_store=session_store)
+                     draft_model=draft_model, session_store=session_store,
+                     host_kv_gib=host_kv_gib or float(_os.environ.get(
+                         "PADDLE_KV_HOST_GIB", "0") or 0.0),
+                     disk_kv_dir=disk_kv_dir)
         for option, why in contract.get("unsupported", {}).items():
             if asked.get(option):
                 raise ValueError(
@@ -1028,6 +1032,7 @@ class PagedContinuousBatcher(_BatcherBase):
             cache_dtype="int8" if cache_quant else None,
             **({"max_batch": max_batch} if self._slot_state else {}))
         self._init_slot_state_series(contract, pool)
+        self._init_step_counts_series(contract)
         # paged_alloc auto-allocates int8 pages whenever the model
         # carries calibrated static scales — kv_quant='int8' is the
         # explicit contract (validated above), but the gauge reflects
@@ -1247,13 +1252,79 @@ class PagedContinuousBatcher(_BatcherBase):
                                 * self._window_rings)
 
     def _slot_args(self, slot: int, n_valid: int) -> dict:
-        """What ``paged_prefill_into`` takes besides, where the model keeps
-        per-slot state."""
-        if not self._slot_state:
+        """What ``paged_prefill_into`` takes besides: its slot where the
+        model keeps per-slot state, and how many of the chunk's rows are
+        real there and where the model counts what its steps did."""
+        if not (self._slot_state or self._step_counts):
             return {}
         import paddle_tpu as paddle
-        return {"slot": paddle.to_tensor(np.array([slot], np.int32)),
-                "n_valid": paddle.to_tensor(np.array([n_valid], np.int32))}
+        args = {"slot": paddle.to_tensor(np.array([slot], np.int32))} \
+            if self._slot_state else {}
+        args["n_valid"] = paddle.to_tensor(np.array([n_valid], np.int32))
+        return args
+
+    # -- what a model's steps chose -------------------------------------------
+    _STEP_COUNTS = ("local", "assigned", "touched", "fullest", "scored",
+                    "selected")
+
+    def _init_step_counts_series(self, contract: dict):
+        """A model whose steps choose (the rows its attention reads, the
+        experts a token goes to) keeps ``step_counts`` in its cache, int32
+        [2, layers, 6]: a layer's assignments to experts held here, the
+        assignments its router made, the held experts touched, the fullest
+        one's tokens, the (query, row) pairs its indexer scored and those
+        its attention read. [0] is the last decode step's; [1] all the
+        chunks' so far (real rows alone; it wraps). Both come with the
+        step's logits, and these series follow them."""
+        self._step_counts = bool(contract.get("step_counts"))
+        if not self._step_counts:
+            return
+        from ..observability.metrics import get_registry
+        reg = get_registry()
+        self._chunk_counts_seen = 0
+
+        def by_phase(name, text):
+            c = reg.counter(name, text, labelnames=("phase",))
+            return {ph: c.labels(phase=ph) for ph in ("decode", "prefill")}
+
+        self._step_counts_c = {
+            "scored": by_phase(
+                "serving.dsa_rows_scored_total",
+                "(query, row) pairs the indexer scored, all layers "
+                "together"),
+            "selected": by_phase(
+                "serving.dsa_rows_selected_total",
+                "(query, row) pairs attention read after selection, all "
+                "layers together"),
+            "assigned": by_phase(
+                "serving.moe_assignments_total",
+                "token-to-expert assignments the routers made, all expert "
+                "layers together"),
+            "local": by_phase(
+                "serving.moe_assignments_local_total",
+                "assignments to experts held here"),
+        }
+        self._experts_touched_c = reg.counter(
+            "serving.moe_experts_touched_total",
+            "held experts a decode step multiplied through, all expert "
+            "layers together")
+        self._expert_fullest_h = reg.histogram(
+            "serving.moe_expert_tokens_max",
+            "tokens of the fullest held expert, a decode step and expert "
+            "layer")
+
+    def _add_step_counts(self, counts: np.ndarray):
+        step, chunks = counts.astype(np.int64)
+        new = (chunks - self._chunk_counts_seen) % (1 << 32)  # int32 wraps
+        self._chunk_counts_seen = chunks
+        for phase, got in (("decode", step), ("prefill", new)):
+            of = dict(zip(self._STEP_COUNTS, got.sum(0)))
+            for name, series in self._step_counts_c.items():
+                series[phase].inc(int(of[name]))
+        of = dict(zip(self._STEP_COUNTS, step.T))
+        self._experts_touched_c.inc(int(of["touched"].sum()))
+        for fullest in of["fullest"][of["assigned"] > 0]:
+            self._expert_fullest_h.observe(float(fullest))
 
     # -- page accounting ----------------------------------------------------
     # A block-table page backs ``block_size`` rows of whatever the model
@@ -2211,8 +2282,15 @@ class PagedContinuousBatcher(_BatcherBase):
         """Consume a step's decode logits: advance timelines, append the
         picked tokens, evict finished slots."""
         with _span("serving.fetch"):
-            # the host waits here for the device, then copies [B, V]
+            # the host waits here for the device, then copies [B, V]; a
+            # model's step counts come in the same wait
+            counts = self._state["layers"]["step_counts"]._data \
+                if self._step_counts else None
+            if counts is not None:
+                counts.copy_to_host_async()
             logits_np = np.asarray(logits._data)
+        if counts is not None:
+            self._add_step_counts(np.asarray(counts))
         with _span("serving.pick"):
             self._dec += np.asarray(self._slot_active_mask(), np.int32)
             next_tok = self._pick(logits_np)

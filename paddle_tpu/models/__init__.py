@@ -13,6 +13,7 @@ from .bert import (BertConfig, BertForMaskedLM,
                    bert_base_config, bert_tiny_config, shard_bert)
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     llama2_7b_config, llama_tiny_config, shard_llama)
+from .glm_dsa import GlmDsaConfig, GlmDsaForCausalLM, glm_dsa_tiny_config
 from .gpt import GPT2Config, GPT2ForCausalLM, GPT2Model, gpt2_124m_config
 from .resnet import (BasicBlock, BottleneckBlock, ResNet, resnet18, resnet34,
                      resnet50, resnet101, resnet152)
@@ -29,6 +30,7 @@ __all__ = [
     "ResNet", "BasicBlock", "BottleneckBlock", "resnet18", "resnet34",
     "resnet50", "resnet101", "resnet152",
     "SambaYConfig", "SambaYForCausalLM", "sambay_tiny_config",
+    "GlmDsaConfig", "GlmDsaForCausalLM", "glm_dsa_tiny_config",
     "UNetConfig", "UNetModel", "unet_tiny_config", "sd_unet_config",
     "ddpm_loss", "ddim_sample",
 ]

@@ -418,3 +418,64 @@ def test_sambay_decoder_blocks_compile_with_the_kernel(chip, monkeypatch):
     for compiled in (win, full):
         assert len(_kernel_calls(compiled, "paged_attention_decode")) == 1
         assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("entry", ["decode", "chunk"])
+def test_glm_dsa_expert_block_compiles_at_published_widths(chip, entry):
+    """One expert block of ``models/glm_dsa.py`` at the cell
+    ``glm5-longdoc-sessions``'s shapes (hidden 6144, 64 heads over one
+    latent row of 512 + 64, 32 index heads of 128, 2,048 rows kept, 16 of
+    256 experts held, 16 slots of 32,768 rows over 16,385 pages of 16): a
+    decode step (every held row scored, rows gathered by token, absorbed
+    attention, the grouped product over the experts touched) and a chunk of
+    512 (held rows read 2,048 at a time). Both pools keep their place
+    (donated, aliased), and what a block needs beside them stays well
+    under a gigabyte."""
+    from paddle_tpu.models import glm_dsa
+    d, ql, heads, experts, f = 6144, 2048, 64, 16, 2048
+    slots, block, s_max, pages = 16, 16, 32768, 16385
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    w = {"ln1_g": sds((d,)), "q_a_w": sds((d, ql)), "q_a_g": sds((ql,)),
+         "q_b_w": sds((ql, heads * 256)), "kv_a_w": sds((d, 576)),
+         "kv_a_g": sds((512,)), "kv_b_w": sds((512, heads * 448)),
+         "o_w": sds((heads * 256, d)), "iq_w": sds((ql, 32 * 128)),
+         "ik_w": sds((d, 128)), "ik_g": sds((128,)), "ik_b": sds((128,)),
+         "iw_w": sds((d, 32)), "ln2_g": sds((d,)),
+         "router_w": sds((d, 256)), "router_b": sds((256,)),
+         "exp_w1": sds((experts, d, 2 * f)), "exp_w2": sds((experts, f, d)),
+         "sh_w1": sds((d, 2 * f)), "sh_w2": sds((f, d))}
+    static = dict(eps=1e-5, ieps=1e-6, heads=heads, topk=2048,
+                  held=(0, experts), top_k=8, scaling=2.5)
+    # the latent row is held whole lanes wide: declared 576 wide, the
+    # runtime gives the pool a page-minor layout and every executable
+    # copies all of it in and out (PERF.md section 6, PR 36)
+    assert glm_dsa.lane_width(576) == 640
+    latent, index = sds((pages, 1, block, 640)), sds((pages, 1, block, 128))
+    angles = sds((s_max, 32), jnp.float32)
+    if entry == "decode":
+        compiled = jax.jit(
+            lambda p, x, lat, idx, table, dec, cos, sin: glm_dsa._block_tok(
+                p, x, lat, idx, table, dec, cos, sin, **static),
+            donate_argnums=(2, 3)).lower(
+                w, sds((slots, d)), latent, index,
+                sds((slots, s_max // block), jnp.int32),
+                sds((slots,), jnp.int32), angles, angles).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, x, lat, idx, table, dec, real, cos, sin:
+            glm_dsa._block_chunk(p, x, lat, idx, table, dec, real, cos, sin,
+                                 kb=2048, **static),
+            donate_argnums=(2, 3)).lower(
+                w, sds((512, d)), latent, index,
+                sds((s_max // block,), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.int32), angles, angles).compile()
+    memory = compiled.memory_analysis()
+    pools = pages * block * (640 + 128) * 2
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.temp_size_in_bytes < 512 << 20
+    text = compiled.as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "bf16[16385,1,16," in ln]
