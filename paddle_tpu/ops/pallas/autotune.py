@@ -88,6 +88,17 @@ def synth_like(q, k, v, attn_mask):
     return mk(q), mk(k), mk(v), mk(attn_mask)
 
 
+def candidates_for(q, k, causal, has_mask, dropout_p) -> List[Tuple[int, int]]:
+    """CANDIDATES plus the grid tiles `flash_tiling` derives from this
+    call's shape (what the kernels run with the flag off): a sweep measures
+    the default with the rest, so it can never pick worse than it."""
+    from .flash_attention import Tilings, flash_tiling
+    _, s, _, d = q.shape
+    derived = [flash_tiling(kn, s, d, q.dtype.itemsize, causal, has_mask,
+                            bool(dropout_p))[:2] for kn in Tilings._fields]
+    return list(dict.fromkeys(CANDIDATES + derived))
+
+
 def _filter_candidates(s: int, candidates) -> List[Tuple[int, int]]:
     """Keep tilings the kernel will actually run at this length: the
     kernel pads sequences to lcm(block_q, block_k) and SHRINKS blocks
@@ -114,7 +125,8 @@ def tune_flash_blocks(q, k, v, causal: bool = True, attn_mask=None,
         raise RuntimeError("tune_flash_blocks times real kernels; it is "
                            "meaningless off TPU")
     s = q.shape[1]
-    cands = _filter_candidates(s, candidates or CANDIDATES)
+    cands = _filter_candidates(s, candidates or candidates_for(
+        q, k, causal, attn_mask is not None, dropout_p))
     if not cands:
         raise RuntimeError(
             f"sequence length {s} below every candidate tiling's lcm — "

@@ -92,6 +92,71 @@ def test_flash_attention_compiles_at_7b_widths(chip, kv_heads, bwd):
         assert _kernel_calls(compiled, "flash_bwd_dkv")
 
 
+# (b, s, hq, hkv, d, dtype, causal, mask, kv_seqlens, dropout, blocks): what
+# the shape-derived tiling (ops/pallas/flash_attention.py::flash_tiling) hands
+# Mosaic at real sizes. The interpreter accepts any tiling; only this compile
+# refuses one over the kernel's VMEM or with a misaligned slice.
+FLASH_VARIANTS = {
+    "smollm2_cell": (4, 2048, 32, 32, 64, jnp.bfloat16, True, False, False,
+                     0.0, None),
+    "mistral_4k": (1, 4096, 32, 8, 128, jnp.bfloat16, True, False, False,
+                   0.0, None),
+    "f32_d128_4k": (1, 4096, 4, 4, 128, jnp.float32, True, False, False,
+                    0.0, None),
+    "f32_d64_1k": (2, 1024, 4, 4, 64, jnp.float32, True, False, False, 0.0,
+                   None),
+    "gpt2_mask": (2, 1024, 12, 12, 64, jnp.bfloat16, False, True, False,
+                  0.0, None),
+    "mask_f32_8k": (1, 8192, 2, 2, 128, jnp.float32, True, True, False, 0.0,
+                    None),
+    "seqlens": (2, 2048, 4, 4, 64, jnp.bfloat16, True, False, True, 0.0,
+                None),
+    "dropout": (2, 1024, 4, 4, 64, jnp.bfloat16, True, False, False, 0.1,
+                None),
+    "dropout_f32_d128": (1, 2048, 2, 2, 128, jnp.float32, False, False,
+                         False, 0.5, None),
+    "pads_2176": (1, 2176, 4, 4, 64, jnp.bfloat16, True, False, False, 0.0,
+                  None),
+    "pads_200": (1, 200, 2, 2, 64, jnp.float32, True, False, False, 0.0,
+                 None),
+    "short_48": (2, 48, 2, 2, 64, jnp.bfloat16, True, False, False, 0.0,
+                 None),
+    "8k_d128": (1, 8192, 8, 8, 128, jnp.bfloat16, True, False, False, 0.0,
+                None),
+    "explicit_1024x128": (1, 2048, 4, 4, 64, jnp.bfloat16, True, False,
+                          False, 0.0, (1024, 128)),
+    "explicit_256x512": (1, 2048, 4, 4, 64, jnp.bfloat16, True, False,
+                         False, 0.0, (256, 512)),
+    "explicit_128x128": (1, 2048, 4, 4, 64, jnp.bfloat16, True, False,
+                         False, 0.0, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("variant", list(FLASH_VARIANTS))
+def test_flash_variants_compile_fwd_bwd(chip, variant):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_pallas
+    (b, s, hq, hkv, d, dtype, causal, has_mask, has_lens, dropout,
+     blocks) = FLASH_VARIANTS[variant]
+    bq, bk = blocks or (None, None)
+
+    def loss(q, k, v, mask, lens):
+        return flash_attention_pallas(
+            q, k, v, causal=causal, attn_mask=mask, kv_seqlens=lens,
+            dropout_p=dropout, seed=3, block_q=bq, block_k=bk,
+        ).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((b, s, hq, d), dtype, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), dtype, sharding=chip)
+    mask = jax.ShapeDtypeStruct((b, 1, s, s), jnp.float32,
+                                sharding=chip) if has_mask else None
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32,
+                                sharding=chip) if has_lens else None
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, mask,
+                        lens)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert _kernel_calls(compiled, name), name
+
+
 @pytest.mark.parametrize("hidden", [2048, 4096, 8192])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])

@@ -4,7 +4,8 @@ Timing only means something on real hardware — `pytest -m tpu` runs the
 actual sweep (test_tpu_tier.py). Here we pin the pure machinery:
 candidate filtering, the cache, and `_resolve_blocks` (explicit blocks
 win; cached tilings are adopted; short sequences and interpret mode skip
-the consult) — without ever running a Mosaic kernel.
+the consult and get the shape-derived tiling: tests/test_flash_tiling.py)
+— without ever running a Mosaic kernel.
 """
 import numpy as np
 import pytest
@@ -14,9 +15,21 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.ops.pallas import autotune
 from paddle_tpu.ops.pallas.flash_attention import (DEFAULT_BLOCK_K,
-                                                   DEFAULT_BLOCK_Q,
-                                                   _resolve_blocks,
-                                                   flash_attention_pallas)
+                                                   Tilings, _resolve_blocks,
+                                                   flash_attention_pallas,
+                                                   flash_tiling)
+
+
+def _grid_tiles(tiles):
+    """(block_q, block_k) of the three kernels' tilings: one pair where a
+    caller or the tuner named it, the shape-derived ones otherwise."""
+    return {t[:2] for t in tiles}
+
+
+def _derived(q):
+    _, s, _, d = q.shape
+    return Tilings(*(flash_tiling(kn, s, d, q.dtype.itemsize, True, False,
+                                  False) for kn in Tilings._fields))
 
 
 def _rand(shape, seed=0):
@@ -67,22 +80,22 @@ def test_resolve_blocks_explicit_always_wins(_flag_on):
     q, k, v = _rand((1, 512, 2, 64), 3), _rand((1, 512, 2, 64), 4), \
         _rand((1, 512, 2, 64), 5)
     autotune.set_best(q, k, True, False, 0.0, (256, 256))
-    assert _resolve_blocks(q, k, v, True, None, 0.0, 128, 128,
-                           False) == (128, 128)
-    assert _resolve_blocks(q, k, v, True, None, 0.0, 256, None,
-                           False) == (256, DEFAULT_BLOCK_K)
+    assert _grid_tiles(_resolve_blocks(q, k, v, True, None, 0.0, 128, 128,
+                                       False)) == {(128, 128)}
+    assert _grid_tiles(_resolve_blocks(q, k, v, True, None, 0.0, 256, None,
+                                       False)) == {(256, DEFAULT_BLOCK_K)}
 
 
 def test_resolve_blocks_adopts_cached_tiling(_flag_on):
     q, k, v = _rand((1, 512, 2, 64), 6), _rand((1, 512, 2, 64), 7), \
         _rand((1, 512, 2, 64), 8)
     autotune.set_best(q, k, True, False, 0.0, (256, 128))
-    assert _resolve_blocks(q, k, v, True, None, 0.0, None, None,
-                           False) == (256, 128)
-    # flag off: defaults
+    assert _grid_tiles(_resolve_blocks(q, k, v, True, None, 0.0, None, None,
+                                       False)) == {(256, 128)}
+    # flag off: the tiling computed from the shape
     paddle.set_flags({"FLAGS_flash_autotune": False})
     assert _resolve_blocks(q, k, v, True, None, 0.0, None, None,
-                           False) == (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+                           False) == _derived(q)
     paddle.set_flags({"FLAGS_flash_autotune": True})
 
 
@@ -93,12 +106,12 @@ def test_resolve_blocks_skips_short_seq_and_interpret(_flag_on):
         _rand((1, 64, 2, 64), 11)
     autotune.set_best(q, k, True, False, 0.0, (256, 128))
     assert _resolve_blocks(q, k, v, True, None, 0.0, None, None,
-                           False) == (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+                           False) == _derived(q)
     q2, k2, v2 = _rand((1, 512, 2, 64), 12), _rand((1, 512, 2, 64), 13), \
         _rand((1, 512, 2, 64), 14)
     autotune.set_best(q2, k2, True, False, 0.0, (256, 128))
     assert _resolve_blocks(q2, k2, v2, True, None, 0.0, None, None,
-                           True) == (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+                           True) == _derived(q2)
 
 
 def test_block_choice_is_numerics_neutral():

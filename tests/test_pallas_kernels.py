@@ -18,8 +18,11 @@ from paddle_tpu.ops.pallas.flash_attention import (flash_attention_pallas,
                                                    supported)
 
 
-def _dense(q, k, v, causal, mask=None, seqlens=None):
+def _dense(q, k, v, causal, mask=None, seqlens=None, neg=-jnp.inf):
+    """Dense float32 reference. ``neg``: what a masked score becomes; a
+    finite one keeps the gradients of wholly masked rows finite."""
     d = q.shape[-1]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     hq, hkv = q.shape[2], k.shape[2]
     if hkv != hq:  # GQA reference: expand kv heads
         rep = hq // hkv
@@ -33,53 +36,117 @@ def _dense(q, k, v, causal, mask=None, seqlens=None):
         s = s + mask
     if causal:
         n = q.shape[1]
-        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -jnp.inf)
+        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, neg)
     if seqlens is not None:
         n = q.shape[1]
         cols = jnp.arange(n)[None, None, None, :]
         rows = jnp.arange(n)[None, None, :, None]
         sl = seqlens[:, None, None, None]
-        s = jnp.where((cols < sl) & (rows < sl), s, -jnp.inf)
+        s = jnp.where((cols < sl) & (rows < sl), s, neg)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows
     out = jnp.einsum("bhqk,bhkd->bhqd", p, vt)
     return jnp.einsum("bhsd->bshd", out)
 
 
-def _rand(shape, seed=0):
-    return jnp.asarray(np.random.RandomState(seed).randn(*shape), jnp.float32)
+def _rand(shape, seed=0, dtype=jnp.float32):
+    return jnp.asarray(np.random.RandomState(seed).randn(*shape),
+                       jnp.float32).astype(dtype)
+
+
+BF16 = jnp.bfloat16
+
+# (b, s, hq, hkv, d, dtype, (block_q, block_k) or None for the shape-derived
+# tiling, additive mask, kv_seqlens). The float32 rows keep the tolerances
+# the kernel has always been held to; the rectangular ones put several K
+# tiles under one Q tile and the reverse, which is where the loop bounds at
+# the diagonal can go wrong.
+CASES = {
+    "f32": (2, 256, 2, 2, 64, jnp.float32, None, False, None),
+    "f32_d32": (1, 256, 1, 1, 32, jnp.float32, None, False, None),
+    "f32_s1024": (1, 1024, 1, 1, 64, jnp.float32, None, False, None),
+    "f32_256x512": (1, 1024, 1, 1, 64, jnp.float32, (256, 512), False, None),
+    "f32_512x256": (1, 1024, 1, 1, 64, jnp.float32, (512, 256), False, None),
+    "f32_1024x128": (1, 1024, 1, 1, 64, jnp.float32, (1024, 128), False,
+                     None),
+    "f32_mask_seqlens_gqa": (2, 512, 4, 2, 32, jnp.float32, None, True,
+                             (500, 130)),
+    "bf16_d64": (1, 1024, 2, 2, 64, BF16, None, False, None),
+    "bf16_d128": (1, 1024, 1, 1, 128, BF16, None, False, None),
+    "bf16_d64_pads": (1, 1100, 1, 1, 64, BF16, None, False, None),
+    "bf16_d128_pads": (1, 600, 1, 1, 128, BF16, None, False, None),
+    "bf16_256x512": (1, 1024, 1, 1, 64, BF16, (256, 512), False, None),
+    "bf16_1024x128": (1, 1024, 1, 1, 64, BF16, (1024, 128), False, None),
+    "bf16_mask": (1, 1024, 2, 2, 64, BF16, None, True, None),
+    "bf16_seqlens": (2, 1024, 1, 1, 64, BF16, None, False, (1000, 300)),
+    "bf16_gqa": (1, 1024, 4, 2, 64, BF16, None, False, None),
+}
+
+
+def _case(name, seed):
+    b, s, hq, hkv, d, dtype, blocks, has_mask, lens = CASES[name]
+    q = _rand((b, s, hq, d), seed, dtype)
+    k, v = _rand((b, s, hkv, d), seed + 1, dtype), \
+        _rand((b, s, hkv, d), seed + 2, dtype)
+    mask = _rand((b, 1, s, s), seed + 3) * 2 if has_mask else None
+    lens = None if lens is None else jnp.asarray(lens, jnp.int32)
+    kw = dict(attn_mask=mask, kv_seqlens=lens, interpret=True)
+    if blocks:
+        kw.update(block_q=blocks[0], block_k=blocks[1])
+    # only rows under a sequence's length are defined: weigh the rest by 0
+    valid = jnp.ones((b, s, 1, 1), jnp.float32) if lens is None else \
+        (jnp.arange(s)[None, :] < lens[:, None]).astype(
+            jnp.float32)[:, :, None, None]
+    return q, k, v, mask, lens, valid, kw
+
+
+def _assert_close(got, ref, dtype, f32_tol):
+    """float32 inputs: elementwise, at the tolerances the tests always had.
+    bfloat16 inputs: the kernel rounds its output, ``p`` and ``ds`` to 8
+    bits of mantissa (2^-9 = 2e-3 relative each, as the dense path's
+    ``probs.astype`` does), so an element may be off by a few of those
+    against a float32 reference on the same inputs, while the error as a
+    whole (independent roundings) stays under 2^-7 of the result's norm."""
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, ref, **f32_tol)
+        return
+    assert np.linalg.norm(got - ref) <= 2.0 ** -7 * np.linalg.norm(ref)
+    np.testing.assert_allclose(got, ref, rtol=3e-2, atol=3e-2 * np.abs(
+        ref).max())
 
 
 @pytest.mark.quick
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_matches_dense(causal):
-    b, s, h, d = 2, 256, 2, 64
-    q, k, v = _rand((b, s, h, d), 0), _rand((b, s, h, d), 1), \
-        _rand((b, s, h, d), 2)
-    out = flash_attention_pallas(q, k, v, causal=causal, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(_dense(q, k, v, causal)),
-                               rtol=1e-5, atol=1e-5)
+@pytest.mark.parametrize("case", list(CASES))
+def test_flash_forward_matches_dense(case, causal):
+    q, k, v, mask, lens, valid, kw = _case(case, 0)
+    out = flash_attention_pallas(q, k, v, causal=causal, **kw)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    ref = _dense(q, k, v, causal, mask=mask, seqlens=lens)
+    _assert_close(out.astype(jnp.float32) * valid, ref * valid, q.dtype,
+                  dict(rtol=1e-5, atol=1e-5))
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_grads_match_dense(causal):
-    b, s, h, d = 1, 256, 1, 32
-    q, k, v = _rand((b, s, h, d), 3), _rand((b, s, h, d), 4), \
-        _rand((b, s, h, d), 5)
+@pytest.mark.parametrize("case", list(CASES))
+def test_flash_grads_match_dense(case, causal):
+    q, k, v, mask, lens, valid, kw = _case(case, 3)
+    w = _rand(q.shape, 9) * valid       # a cotangent with no structure
 
     def f(q, k, v):
-        return flash_attention_pallas(q, k, v, causal=causal,
-                                      interpret=True).sum()
+        return (flash_attention_pallas(q, k, v, causal=causal, **kw
+                                       ).astype(jnp.float32) * w).sum()
 
     def g(q, k, v):
-        return _dense(q, k, v, causal).sum()
+        return (_dense(q, k, v, causal, mask=mask, seqlens=lens,
+                       neg=-1e30) * w).sum()
 
     got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
     ref = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(got, ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=1e-4, atol=1e-5)
+        assert a.dtype == q.dtype
+        _assert_close(a, b_, q.dtype, dict(rtol=1e-4, atol=1e-5))
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -246,6 +313,43 @@ def test_flash_dropout():
         q, k, v, causal=False, dropout_p=0.3, seed=7,
         interpret=True).sum())(q)
     np.testing.assert_array_equal(np.asarray(g), np.asarray(g2))
+
+
+@pytest.mark.parametrize("blocks", [None, (256, 128)],
+                         ids=["derived", "256x128"])
+def test_flash_dropout_backward_redraws_the_forwards_mask(blocks):
+    """The mask is drawn per score tile from the tile's coordinates, so the
+    three kernels must agree on the score tile: under dropout
+    ``_resolve_blocks`` hands all three ONE tiling (the shape-derived one
+    that fits the hungriest of them, or the caller's). With the seed fixed
+    the masked function is smooth, so a central difference along each
+    gradient must give its norm; a backward that drew another mask returns
+    a vector the function does not rise along."""
+    from paddle_tpu.ops.pallas.flash_attention import _resolve_blocks
+    b, s, h, d = 1, 1024, 1, 64
+    q, k, v = _rand((b, s, h, d), 33), _rand((b, s, h, d), 34), \
+        _rand((b, s, h, d), 35)
+    bq, bk = blocks or (None, None)
+    tiles = _resolve_blocks(q, k, v, True, None, 0.3, bq, bk, True)
+    assert len(set(tiles)) == 1
+    assert s // tiles.fwd.sub_q > 1 or s // tiles.fwd.sub_k > 1
+    w = _rand((b, s, h, d), 36)
+
+    def f(*qkv):
+        return (flash_attention_pallas(
+            *qkv, causal=True, dropout_p=0.3, seed=7, block_q=bq,
+            block_k=bk, interpret=True) * w).sum()
+
+    grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    for i, g in enumerate(grads):
+        # along the gradient itself the slope is its norm, if it is the
+        # gradient of what the forward computed
+        norm = float(jnp.linalg.norm(g.ravel()))
+        u = g / norm
+        hi, lo = [q, k, v], [q, k, v]
+        hi[i], lo[i] = hi[i] + 0.1 * u, lo[i] - 0.1 * u
+        fd = float(f(*hi) - f(*lo)) / 0.2
+        assert abs(fd - norm) <= 0.02 * norm, (i, fd, norm)
 
 
 def test_block_sparse_attention_matches_dense_masked():

@@ -150,6 +150,81 @@ def test_flash_mosaic_arbitrary_and_short_seq():
                                    rtol=2e-2, atol=2e-2)
 
 
+# -- the shape-derived tiling at real tile sizes ----------------------------
+# (b, s, hq, hkv, d, dtype, (block_q, block_k) or None, mask, kv_seqlens):
+# the cases of tests/test_pallas_kernels.py at the sizes where the tiling is
+# what the train cell and the 7B widths get (grid tiles of 2,048, score tiles
+# of 512 x 512), which only Mosaic compiles and only the chip multiplies in
+# bfloat16.
+TILED_VARIANTS = {
+    "cell_d64": (1, 2048, 4, 4, 64, jnp.bfloat16, None, False, None),
+    "d128": (1, 2048, 2, 2, 128, jnp.bfloat16, None, False, None),
+    "gqa_d128_4k": (1, 4096, 8, 2, 128, jnp.bfloat16, None, False, None),
+    "pads_2176": (1, 2176, 2, 2, 64, jnp.bfloat16, None, False, None),
+    "f32_d64": (1, 1024, 2, 2, 64, jnp.float32, None, False, None),
+    "256x512": (1, 2048, 2, 2, 64, jnp.bfloat16, (256, 512), False, None),
+    "512x256": (1, 2048, 2, 2, 64, jnp.bfloat16, (512, 256), False, None),
+    "1024x128": (1, 2048, 2, 2, 64, jnp.bfloat16, (1024, 128), False, None),
+    "mask": (1, 2048, 2, 2, 64, jnp.bfloat16, None, True, None),
+    "seqlens": (2, 2048, 2, 2, 64, jnp.bfloat16, None, False, (2000, 700)),
+}
+
+
+def _dense_highest(q, k, v, causal, mask, lens):
+    """float32 reference whose products are float32 on the chip too."""
+    with jax.default_matmul_precision("highest"):
+        return _dense(*(x.astype(jnp.float32) for x in (q, k, v)), causal,
+                      mask=mask, seqlens=lens)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("variant", list(TILED_VARIANTS))
+def test_flash_mosaic_tiled_variants(variant, causal):
+    """Forward and the three gradients against the dense float32 reference.
+    The tolerance is bfloat16's: output, ``p`` and ``ds`` are rounded to 8
+    bits of mantissa (2^-9 relative each); as a whole the error stays under
+    2^-7 of the result's norm (measured 2.1e-3 to 3.3e-3 at the cell's
+    shape, PR 37). float32 inputs are held to the same: Mosaic multiplies
+    float32 operands at its default precision, which on the chip reads 2.9e-3
+    to 3.4e-3 against a `highest` reference (PR 37; the kernel before it
+    multiplied every input that way)."""
+    _require_tpu()
+    b, s, hq, hkv, d, dtype, blocks, has_mask, lens = TILED_VARIANTS[variant]
+    q = _rand((b, s, hq, d), 80).astype(dtype)
+    k = _rand((b, s, hkv, d), 81).astype(dtype)
+    v = _rand((b, s, hkv, d), 82).astype(dtype)
+    mask = _rand((b, 1, s, s), 83) * 2 if has_mask else None
+    lens = None if lens is None else jnp.asarray(lens, jnp.int32)
+    valid = jnp.ones((b, s, 1, 1), jnp.float32) if lens is None else (
+        jnp.arange(s)[None, :] < lens[:, None]).astype(
+            jnp.float32)[:, :, None, None]
+    w = _rand((b, s, hq, d), 84) * valid
+    kw = dict(attn_mask=mask, kv_seqlens=lens)
+    if blocks:
+        kw.update(block_q=blocks[0], block_k=blocks[1])
+
+    def f(q, k, v):
+        out = _flash(q, k, v, causal=causal, **kw).astype(jnp.float32)
+        return (out * w).sum(), out
+
+    def g(q, k, v):
+        out = _dense_highest(q, k, v, causal, mask, lens)
+        return (out * w).sum(), out
+
+    (_, out), got = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, ref_out), ref = jax.jit(jax.value_and_grad(
+        g, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    tol = 2.0 ** -7
+    for name, a, r in (("out", out * valid, ref_out * valid),
+                       ("dq", got[0], ref[0]), ("dk", got[1], ref[1]),
+                       ("dv", got[2], ref[2])):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        assert np.isfinite(a).all(), name
+        err = np.linalg.norm(a - r) / np.linalg.norm(r)
+        assert err <= tol, f"{variant} {name}: {err:.2e} > {tol:.2e}"
+
+
 # -- dropout on the hardware PRNG path --------------------------------------
 
 def test_flash_dropout_hw_prng_determinism_and_keep_rate():
