@@ -35,6 +35,16 @@ root->leaf path (eviction takes deepest device nodes first, promotion
 installs top-down), which is what lets ``match`` split any path into a
 device prefix + a promotable tail.
 
+Page groups (``PageGroup``): a model whose layers do not all keep every row
+(window layers beside full ones) holds its rows in more than one pool of
+pages. The tree's ``page`` is the page of the group that keeps every row;
+a node carries, besides, the page of each group that keeps a window of
+rows, *while it still has one*: a running sequence hands the pages behind
+its window back, they stay with their nodes, evictable, until they are
+reused, and are then reclaimed oldest release first from a queue (no walk
+of the tree). ``usable`` cuts a match back to the longest boundary at which
+a group still has every page of the window before it.
+
 Only FULL blocks are cached: a partially-filled page is still being
 appended to by its owner and cannot be shared. Generated tokens are
 cacheable too — a preempted/failed-over request resumes with
@@ -54,14 +64,15 @@ tree itself compares real token blocks).
 """
 from __future__ import annotations
 
+import collections
 import hashlib
 import os
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["RadixPrefixCache", "HostTier", "DiskTier", "chain_hashes",
-           "blob_nbytes"]
+__all__ = ["RadixPrefixCache", "HostTier", "DiskTier", "PageGroup",
+           "chain_hashes", "blob_nbytes"]
 
 _ROOT_HASH = 0
 
@@ -255,7 +266,7 @@ def _unskeletonize(skel, flat: Dict[str, np.ndarray]):
 
 class _Node:
     __slots__ = ("key", "page", "parent", "children", "ref", "last_use",
-                 "hash", "depth", "residency", "promo", "spin")
+                 "hash", "depth", "residency", "promo", "spin", "gpages")
 
     def __init__(self, key: Tuple[int, ...], page: int, parent, hash_: int,
                  depth: int):
@@ -270,11 +281,194 @@ class _Node:
         self.residency = "device"
         self.promo = None           # in-flight promotion record, if any
         self.spin = 0               # session pins (durable-session holds)
+        self.gpages = None          # {group: page} of the window groups
 
     def __repr__(self):            # pragma: no cover - debug aid
         return (f"_Node(depth={self.depth}, page={self.page}, "
                 f"ref={self.ref}, spin={self.spin}, tier={self.residency}, "
                 f"kids={len(self.children)})")
+
+
+class PageGroup:
+    """The pages of the layers that keep only the trailing ``rows`` rows of
+    a sequence: a pool and a page numbering of their own, beside the
+    group whose pages the block table and the tree's ``page`` name.
+
+    A running sequence ``slot`` holds the pages of blocks ``first[slot] ..
+    upto[slot]`` of its timeline; block ``j`` lies at entry ``j % ring`` of
+    its row of ``table`` (what the model is handed). ``advance`` lets the
+    blocks wholly behind the window go and takes pages for the rows about
+    to be written. A page that goes back and backs a prefix-cache node's
+    block stays with the node, in ``released``, evictable; ``take`` hands
+    out free pages first and then the oldest released one, from the queue's
+    head. A cached page may be held by several sequences (``ref``). Never
+    more than ``ring`` pages a slot: the batcher admits a sequence only
+    while every running one could hold that many."""
+
+    def __init__(self, name: str, rows: int, n_pages: int, block_size: int,
+                 ring: int, max_batch: int):
+        if rows < 1:
+            raise ValueError(f"page group {name!r} keeps {rows} rows")
+        self.name, self.rows, self.n_pages = name, int(rows), int(n_pages)
+        self.block_size, self.ring = block_size, int(ring)
+        self.scratch = self.n_pages          # the pool's reserved last row
+        self.blocks_back = -(-(self.rows - 1) // block_size)
+        self.free = list(range(self.n_pages))
+        self.table = np.full((max_batch, self.ring), self.scratch, np.int32)
+        self.first = np.zeros((max_batch,), np.int64)
+        self.upto = np.zeros((max_batch,), np.int64)
+        self.ref: Dict[int, int] = {}        # page -> sequences holding it
+        self.owner: Dict[int, _Node] = {}    # page -> the node it backs
+        self.released: "collections.OrderedDict[int, _Node]" = \
+            collections.OrderedDict()        # held by nobody, oldest first
+        self.released_total = 0              # pages sequences let go
+        self.reclaimed_total = 0             # released pages handed out anew
+
+    def of(self, node: _Node) -> int:
+        """The node's page of this group, -1 once it was reclaimed."""
+        return -1 if node.gpages is None else node.gpages.get(self.name, -1)
+
+    def held(self, slot: int) -> int:
+        return int(self.upto[slot] - self.first[slot])
+
+    def usable(self, path: Sequence[_Node]) -> int:
+        """The longest boundary m <= len(path) at which every block of the
+        window before row ``m * block_size`` still has its page."""
+        run, best = 0, 0
+        for i, node in enumerate(path):
+            run = run + 1 if self.of(node) >= 0 else 0
+            if run >= min(self.blocks_back, i + 1):
+                best = i + 1
+        return best
+
+    def take(self) -> Optional[int]:
+        """A page for a sequence to write: a free one, else the oldest
+        released (its node keeps its place in the tree and loses this
+        group's page). None when every page is held."""
+        if self.free:
+            page = self.free.pop()
+        elif self.released:
+            page, node = self.released.popitem(last=False)
+            del self.owner[page]
+            del node.gpages[self.name]
+            self.reclaimed_total += 1
+        else:
+            return None
+        self.ref[page] = 1
+        return page
+
+    def start(self, slot: int, path: Sequence[_Node]):
+        """A sequence enters ``slot`` behind the matched blocks ``path``
+        (``usable`` has passed them): it holds the cached pages of the
+        window before its first own row."""
+        m = len(path)
+        first = max(m - self.blocks_back, 0)
+        self.first[slot], self.upto[slot] = first, m
+        for j in range(first, m):
+            page = self.of(path[j])
+            if self.ref.get(page, 0) == 0:
+                del self.released[page]
+            self.ref[page] = self.ref.get(page, 0) + 1
+            self.table[slot, j % self.ring] = page
+
+    def _let_go(self, page: int):
+        self.ref[page] -= 1
+        if self.ref[page]:
+            return
+        del self.ref[page]
+        self.released_total += 1
+        node = self.owner.get(page)
+        if node is None:
+            self.free.append(page)
+        else:
+            self.released[page] = node
+
+    def advance(self, slot: int, dec: int, upto_row: int) -> bool:
+        """Before rows ``dec .. upto_row`` of the slot's timeline are
+        written: the blocks that lie wholly behind the window of row
+        ``dec`` go back, and the rows about to be written get pages. False
+        (with what could be taken, taken) when the pool is held whole."""
+        bs = self.block_size
+        keep = max(dec - (self.rows - 1), 0) // bs
+        first, upto = int(self.first[slot]), int(self.upto[slot])
+        need = -(-upto_row // bs)
+        if keep <= first and need <= upto:
+            return True
+        row = self.table[slot]
+        for j in range(first, min(keep, upto)):
+            self._let_go(int(row[j % self.ring]))
+            row[j % self.ring] = self.scratch
+        first = max(first, keep)
+        upto = max(upto, first)
+        self.first[slot] = first
+        if need - first > self.ring:
+            raise RuntimeError(
+                f"page group {self.name!r}: blocks {first} .. {need} of "
+                f"slot {slot} do not fit its ring of {self.ring}")
+        while upto < need:
+            page = self.take()
+            if page is None:
+                self.upto[slot] = upto
+                return False
+            row[upto % self.ring] = page
+            upto += 1
+        self.upto[slot] = upto
+        return True
+
+    def adopt(self, slot: int, block: int, node: _Node):
+        """The node of ``block`` of the slot's path takes the slot's page
+        of that block for its own, where it has none and the slot still
+        holds the block: the page then outlives the sequence."""
+        if not self.first[slot] <= block < self.upto[slot] \
+                or self.of(node) >= 0:
+            return
+        page = int(self.table[slot, block % self.ring])
+        if page in self.owner:
+            return
+        self.owner[page] = node
+        if node.gpages is None:
+            node.gpages = {}
+        node.gpages[self.name] = page
+
+    def drop_slot(self, slot: int):
+        row = self.table[slot]
+        for j in range(int(self.first[slot]), int(self.upto[slot])):
+            self._let_go(int(row[j % self.ring]))
+        row[:] = self.scratch
+        self.first[slot] = self.upto[slot] = 0
+
+    def forget(self, node: _Node):
+        """The node leaves the tree: its page, if nobody holds it, is
+        free; held, it goes free when its holders let it go."""
+        page = self.of(node)
+        if page < 0:
+            return
+        del node.gpages[self.name]
+        del self.owner[page]
+        if self.released.pop(page, None) is not None:
+            self.free.append(page)
+
+    def audit(self) -> int:
+        """Free list, held pages and released pages cover the pool exactly
+        once, the tables name what is held as often as it is held, and
+        every released page backs a node. Raises on an anomaly; returns the
+        pages unaccounted for (0 on the path that does not raise)."""
+        free, held, idle = set(self.free), set(self.ref), set(self.released)
+        named = collections.Counter(
+            int(p) for slot in range(self.table.shape[0])
+            for p in (self.table[slot, j % self.ring] for j in range(
+                int(self.first[slot]), int(self.upto[slot]))))
+        leaked = set(range(self.n_pages)) - free - held - idle
+        if len(free) != len(self.free) or free & held or free & idle \
+                or held & idle or leaked or dict(named) != self.ref \
+                or any(self.of(n) != p for p, n in self.owner.items()) \
+                or not idle <= set(self.owner):
+            raise RuntimeError(
+                f"page accounting bug in group {self.name!r}: "
+                f"leaked={sorted(leaked)} free-and-held={sorted(free & held)} "
+                f"free-and-released={sorted(free & idle)} "
+                f"held={dict(self.ref)} named={dict(named)}")
+        return len(leaked)
 
 
 class RadixPrefixCache:
@@ -317,6 +511,8 @@ class RadixPrefixCache:
         # cached routing advertisement (satellite: invalidate on mutation)
         self._summary_cache: Optional[Dict[str, object]] = None
         self._dirty = True
+        # the window groups whose pages nodes carry beside ``page``
+        self.groups: Dict[str, PageGroup] = {}
 
     # -- bookkeeping ---------------------------------------------------------
     def _touch(self, node: _Node):
@@ -373,11 +569,14 @@ class RadixPrefixCache:
         return count
 
     # -- the serving hot path ------------------------------------------------
-    def _blocks(self, tokens) -> List[Tuple[int, ...]]:
+    def _blocks(self, tokens, first: int = 0,
+                upto: Optional[int] = None) -> List[Tuple[int, ...]]:
         toks = np.asarray(tokens, np.int64).reshape(-1)
+        full = len(toks) // self.block_size
         return [tuple(int(t) for t in
                       toks[i * self.block_size:(i + 1) * self.block_size])
-                for i in range(len(toks) // self.block_size)]
+                for i in range(first, full if upto is None
+                               else min(upto, full))]
 
     def match(self, tokens, max_blocks: Optional[int] = None) -> List[_Node]:
         """Longest cached prefix of ``tokens`` as the node path (root
@@ -439,7 +638,9 @@ class RadixPrefixCache:
             self._touch(n)
 
     def insert(self, tokens, pages: Sequence[int],
-               start_block: int, n_blocks: int) -> List[_Node]:
+               start_block: int, n_blocks: int,
+               after: Optional[_Node] = None,
+               walked: Optional[List[_Node]] = None) -> List[_Node]:
         """Adopt blocks [start_block, n_blocks) of ``tokens`` into the
         tree. ``pages[i]`` is the physical page holding block i's rows
         (the slot's block-table row). New nodes take ownership of their
@@ -449,11 +650,19 @@ class RadixPrefixCache:
         off-device node with no promotion in flight is UPGRADED in place:
         it adopts the slot's freshly-prefilled page, its stale blob is
         discarded, and it joins the returned (pinned) list. Returns the
-        newly created/upgraded nodes."""
-        blocks = self._blocks(tokens)[:n_blocks]
-        node = self._root
+        newly created/upgraded nodes.
+
+        ``after``: the node of block ``start_block - 1`` (an admission that
+        inserts as its chunks complete): the walk starts there and reads
+        the tokens of blocks ``start_block ..`` alone. ``walked``: a list
+        that is given every node of those blocks, skipped ones too."""
+        if after is None:
+            first, node = 0, self._root
+        else:
+            first, node = start_block, after
+        blocks = self._blocks(tokens, first, n_blocks)
         created: List[_Node] = []
-        for i, blk in enumerate(blocks):
+        for i, blk in enumerate(blocks, first):
             child = node.children.get(blk)
             if child is None:
                 if i < start_block:
@@ -485,6 +694,8 @@ class RadixPrefixCache:
                 created.append(child)
                 self._invalidate()
             self._touch(child)
+            if walked is not None and i >= start_block:
+                walked.append(child)
             node = child
         return created
 
@@ -510,6 +721,8 @@ class RadixPrefixCache:
                 # by the rule, no off-device child without a tier)
                 if victim.spin > 0:
                     self.session_pin_drops += 1
+                for group in self.groups.values():
+                    group.forget(victim)
                 del victim.parent.children[victim.key]
                 self._nodes -= 1
                 self._dev_nodes -= 1

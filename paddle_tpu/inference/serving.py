@@ -883,6 +883,11 @@ class PagedContinuousBatcher(_BatcherBase):
         self.s_max = s_max
         self.block_size = block_size
         self.blocks_per_seq = -(-s_max // block_size)
+        # a model whose layers do not all keep every row names its page
+        # groups; ``n_pages`` then gives a page count for each
+        # (``_init_page_groups``); here it is the count of the group that
+        # keeps every row, which the block table and the prefix cache name
+        group_pages, n_pages = self._split_group_pages(contract, n_pages)
         if n_pages is None:
             n_pages = max_batch * self.blocks_per_seq
         self.n_pages = n_pages
@@ -1027,10 +1032,17 @@ class PagedContinuousBatcher(_BatcherBase):
         self.kv_quant = kv_quant
         self.tier_quant = tier_quant
         self._slot_state = bool(contract.get("slot_state"))
+        self._init_page_groups(contract, group_pages, prefill_chunk)
+        pool_pages = n_pages + 1                    # the scratch page too
+        if self._primary_group:
+            pool_pages = dict({self._primary_group: pool_pages}, **{
+                g.name: g.n_pages + 1 for g in self._groups.values()})
         pool = model.paged_alloc(
-            n_pages + 1, block_size,
+            pool_pages, block_size,
             cache_dtype="int8" if cache_quant else None,
             **({"max_batch": max_batch} if self._slot_state else {}))
+        if self._groups:
+            self._init_group_bytes()
         self._init_slot_state_series(contract, pool)
         self._init_step_counts_series(contract)
         # paged_alloc auto-allocates int8 pages whenever the model
@@ -1060,6 +1072,8 @@ class PagedContinuousBatcher(_BatcherBase):
             "cu_b": paddle.to_tensor(np.arange(max_batch + 1,
                                                dtype=np.int32)),
         }
+        if self._groups:
+            self._state["group_tables"] = self._group_tables()
         # the route the decode step's attention takes over this pool
         # ("kernel": the Pallas paged kernel; "gather": the XLA gather),
         # as the model that builds the executable decides it; every launch
@@ -1202,6 +1216,182 @@ class PagedContinuousBatcher(_BatcherBase):
                     self._chunk_dyn_first_fn = _chunk_dyn_first
                     self._chunk_dyn_rest_fn = _chunk_dyn_rest
 
+    # -- page groups ----------------------------------------------------------
+    @staticmethod
+    def _split_group_pages(contract: dict, n_pages):
+        """(page counts of the contract's groups or None, the page count of
+        the group that keeps every row)."""
+        groups = contract.get("page_groups")
+        if not groups:
+            if isinstance(n_pages, dict):
+                raise ValueError("n_pages gives page counts by group, and "
+                                 "the model's cache has no page groups")
+            return None, n_pages
+        whole = [g for g, spec in groups.items() if spec.get("rows") is None]
+        if len(whole) != 1:
+            raise ValueError("one page group keeps every row (the block "
+                             f"table's), not {whole}")
+        if not isinstance(n_pages, dict) or set(n_pages) != set(groups):
+            raise ValueError(
+                f"the model's cache is the page groups {sorted(groups)}: "
+                f"n_pages gives a page count for each, not {n_pages!r}")
+        return dict(n_pages), int(n_pages[whole[0]])
+
+    def _init_page_groups(self, contract: dict, group_pages,
+                          prefill_chunk):
+        """The groups that keep a window of rows (``prefix_cache.
+        PageGroup``), each with its pages, its tables and its series. A
+        slot's ring holds the window, the rows one prefill call writes and
+        the partial blocks at both ends; a sequence is admitted only while
+        every running one and it could hold a ring each, so a running
+        sequence never waits for a page of a window group. Without groups
+        nothing here is on any path."""
+        self._groups: Dict[str, "PageGroup"] = {}
+        self._primary_group = None
+        self._slot_path: Dict[int, list] = {}    # every node of a slot's
+        #   blocks so far (``_slot_nodes`` holds those it pins)
+        if not group_pages:
+            return
+        from ..observability.metrics import get_registry
+        from .prefix_cache import PageGroup
+        reg = get_registry()
+        width = prefill_chunk or self.s_max
+        for name, spec in contract["page_groups"].items():
+            if spec.get("rows") is None:
+                self._primary_group = name
+                continue
+            ring = min(self.blocks_per_seq,
+                       self._pages_for(spec["rows"] + width) + 3)
+            if ring > group_pages[name]:
+                raise ValueError(
+                    f"page group {name!r}: one sequence holds up to {ring} "
+                    f"pages, the group has {group_pages[name]}")
+            self._groups[name] = PageGroup(
+                name, spec["rows"], group_pages[name], self.block_size,
+                ring, self.max_batch)
+        if self.prefix_cache is not None:
+            self.prefix_cache.groups = self._groups
+        names = [self._primary_group] + list(self._groups)
+        held = reg.gauge(
+            "serving.kv_pages_held",
+            "pages of a page group that running sequences hold",
+            labelnames=("group",))
+        released = reg.counter(
+            "serving.kv_pages_released_total",
+            "pages of a page group that sequences let go (behind the "
+            "window while they ran, or at their end)",
+            labelnames=("group",))
+        reclaimed = reg.counter(
+            "serving.kv_pages_reclaimed_total",
+            "released pages the prefix cache still held that were handed "
+            "out anew", labelnames=("group",))
+        self._group_series = {
+            n: (held.labels(group=n), released.labels(group=n),
+                reclaimed.labels(group=n)) for n in names}
+        self._group_seen = {n: [0, 0] for n in self._groups}
+        self._row_bytes_h = reg.histogram(
+            "serving.kv_bytes_per_resident_row",
+            "bytes of all groups' pages that running sequences hold, as "
+            "allocated, over their resident rows, a decode step")
+        cut = reg.counter(
+            "serving.prefix_hits_cut_total",
+            "prefix matches cut back to a boundary at which a window "
+            "group still had the window's pages", labelnames=("why",))
+        self._hits_cut_c = cut.labels(why="window_pages_reclaimed")
+        self._matches_c = reg.counter(
+            "serving.prefix_matches_total",
+            "admissions whose prompt matched cached blocks, before any "
+            "cut")
+
+    def _init_group_bytes(self):
+        """Bytes a page of each group, all its layers, from what the model
+        says it allocated (``serving.kv_cache_bytes{group}``)."""
+        from ..observability.metrics import get_registry
+        kv_bytes = get_registry().get("serving.kv_cache_bytes")
+        self._page_bytes = {}
+        for n in [self._primary_group] + list(self._groups):
+            pages = (self.n_pages if n == self._primary_group
+                     else self._groups[n].n_pages) + 1
+            self._page_bytes[n] = (kv_bytes.labels(group=n).value
+                                   if kv_bytes else 0) / pages
+
+    def _group_tables(self, slot: Optional[int] = None) -> dict:
+        """Every window group's table (one slot's row of it) for the
+        device. A copy: on the CPU the device may read a numpy buffer in
+        place, and the next chunk's release writes the table while the
+        last chunk still runs."""
+        import paddle_tpu as paddle
+        rows = slice(None) if slot is None else slice(slot, slot + 1)
+        return {g.name: paddle.to_tensor(g.table[rows].copy())
+                for g in self._groups.values()}
+
+    def _cut_to_groups(self, matched: list) -> list:
+        """A prefix match, cut back to the longest boundary at which every
+        window group still has the pages of the window before it."""
+        if not self._groups or not matched:
+            return matched
+        self._matches_c.inc()
+        m = len(matched)
+        while True:
+            cut = min(g.usable(matched[:m]) for g in self._groups.values())
+            if cut == m:
+                break
+            m = cut
+        if m < len(matched):
+            self._hits_cut_c.inc()
+        return matched[:m]
+
+    def _group_rows(self, slot: int, dec: int, upto_row: int):
+        """Rows ``dec .. upto_row`` of the slot are about to be written:
+        every window group hands back what lies behind the window and backs
+        the new rows."""
+        for g in self._groups.values():
+            if not g.advance(slot, dec, upto_row):
+                raise RuntimeError(
+                    f"page group {g.name!r} exhausted: slot {slot} needs a "
+                    f"page at row {dec} (n_pages={g.n_pages}, "
+                    f"{len(self._slot_req)} running)")
+
+    def _group_insert(self, slot: int, ids_np, done_rows: int):
+        """The slot's full blocks up to ``done_rows`` enter the prefix
+        cache as its chunks complete, so that a block is the tree's before
+        it falls behind the window: its window-group page then stays with
+        the node when the sequence lets it go."""
+        if self.prefix_cache is None:
+            return
+        path = self._slot_path[slot]
+        n_blocks = min(done_rows, len(ids_np)) // self.block_size
+        if n_blocks <= len(path):
+            return
+        start = len(path)
+        created = self.prefix_cache.insert(
+            ids_np, self._bt[slot], start, n_blocks,
+            after=path[-1] if path else None, walked=path)
+        self._slot_nodes[slot].extend(created)
+        for j in range(start, n_blocks):
+            for g in self._groups.values():
+                g.adopt(slot, j, path[j])
+
+    def _count_groups(self):
+        """The groups' series, once a decode step."""
+        running = list(self._slot_req)
+        held = {self._primary_group: int(np.sum(
+            self._bt[running] != self._scratch))}
+        for name, g in self._groups.items():
+            held[name] = int(np.sum(g.upto[running] - g.first[running]))
+            seen = self._group_seen[name]
+            _, released, reclaimed = self._group_series[name]
+            released.inc(g.released_total - seen[0])
+            reclaimed.inc(g.reclaimed_total - seen[1])
+            seen[:] = g.released_total, g.reclaimed_total
+        for name, pages in held.items():
+            self._group_series[name][0].set(pages)
+        rows = int(self._dec[running].sum())
+        if rows:
+            self._row_bytes_h.observe(sum(
+                pages * self._page_bytes[n]
+                for n, pages in held.items()) / rows)
+
     # -- per-slot state beside the pool ---------------------------------------
     def _init_slot_state_series(self, contract: dict, pool):
         """Bytes of state a slot holds whatever its length (0 where the
@@ -1253,14 +1443,18 @@ class PagedContinuousBatcher(_BatcherBase):
 
     def _slot_args(self, slot: int, n_valid: int) -> dict:
         """What ``paged_prefill_into`` takes besides: its slot where the
-        model keeps per-slot state, and how many of the chunk's rows are
-        real there and where the model counts what its steps did."""
-        if not (self._slot_state or self._step_counts):
+        model keeps per-slot state, how many of the chunk's rows are real
+        there and where the model counts what its steps did, and the
+        slot's row of every window group's table where it has page
+        groups."""
+        if not (self._slot_state or self._step_counts or self._groups):
             return {}
         import paddle_tpu as paddle
         args = {"slot": paddle.to_tensor(np.array([slot], np.int32))} \
             if self._slot_state else {}
         args["n_valid"] = paddle.to_tensor(np.array([n_valid], np.int32))
+        if self._groups:
+            args["group_tables"] = self._group_tables(slot)
         return args
 
     # -- what a model's steps chose -------------------------------------------
@@ -1274,8 +1468,10 @@ class PagedContinuousBatcher(_BatcherBase):
         assignments its router made, the held experts touched, the fullest
         one's tokens, the (query, row) pairs its indexer scored and those
         its attention read. [0] is the last decode step's; [1] all the
-        chunks' so far (real rows alone; it wraps). Both come with the
-        step's logits, and these series follow them."""
+        chunks' so far (real rows alone; it wraps). A family without an
+        indexer (``mellum``) leaves the last two columns zero, one without
+        experts would leave the first four. Both come with the step's
+        logits, and these series follow them."""
         self._step_counts = bool(contract.get("step_counts"))
         if not self._step_counts:
             return
@@ -1308,6 +1504,10 @@ class PagedContinuousBatcher(_BatcherBase):
             "serving.moe_experts_touched_total",
             "held experts a decode step multiplied through, all expert "
             "layers together")
+        self._experts_touched_prefill_c = reg.counter(
+            "serving.moe_experts_touched_prefill_total",
+            "held experts a prefill chunk multiplied through, all expert "
+            "layers together")
         self._expert_fullest_h = reg.histogram(
             "serving.moe_expert_tokens_max",
             "tokens of the fullest held expert, a decode step and expert "
@@ -1321,6 +1521,8 @@ class PagedContinuousBatcher(_BatcherBase):
             of = dict(zip(self._STEP_COUNTS, got.sum(0)))
             for name, series in self._step_counts_c.items():
                 series[phase].inc(int(of[name]))
+        self._experts_touched_prefill_c.inc(
+            int(new[:, self._STEP_COUNTS.index("touched")].sum()))
         of = dict(zip(self._STEP_COUNTS, step.T))
         self._experts_touched_c.inc(int(of["touched"].sum()))
         for fullest in of["fullest"][of["assigned"] > 0]:
@@ -1655,6 +1857,9 @@ class PagedContinuousBatcher(_BatcherBase):
                     self._free_pages.append(int(row[b]))
                 row[b] = self._scratch
         self._dec[slot] = 0
+        for g in self._groups.values():
+            g.drop_slot(slot)
+        self._slot_path.pop(slot, None)
         if self.draft_model is not None:
             self._ddec[slot] = 0
         if self.cache_quant:
@@ -1673,8 +1878,8 @@ class PagedContinuousBatcher(_BatcherBase):
         prefixes — but free ∩ anything is a double-free). Publishes
         ``serving.pages_leaked`` and raises on any anomaly, so a leak
         fails the releasing operation instead of surfacing as OOM much
-        later. Returns the leak count (always 0 on the non-raising
-        path)."""
+        later. Returns the leak count, all page groups together (always 0
+        on the non-raising path)."""
         free_set = set(self._free_pages)
         used = set()
         for slot in range(self.max_batch):
@@ -1706,7 +1911,8 @@ class PagedContinuousBatcher(_BatcherBase):
             # accounting must reconcile exactly too
             rep = self.prefix_cache.audit_tiers()
             self._host_bytes_g.set(rep.get("host_bytes", 0))
-        return 0
+        # every window group reconciles its own pool the same way
+        return sum(g.audit() for g in self._groups.values())
 
     @property
     def free_page_count(self) -> int:
@@ -1878,6 +2084,7 @@ class PagedContinuousBatcher(_BatcherBase):
                     # request already burned its promotion): prefill it
                     # fresh — insert() upgrades the stale nodes in place
                     matched = dev
+                matched = self._cut_to_groups(matched)
                 if matched:
                     # pin BEFORE the availability gate: the gate may
                     # admit on the promise of evicting OTHER chains, and
@@ -1888,7 +2095,9 @@ class PagedContinuousBatcher(_BatcherBase):
             need = self._pages_for(upto) - len(matched)
             if need > len(self._free_pages) + (
                     self.prefix_cache.evictable_pages()
-                    if self.prefix_cache is not None else 0):
+                    if self.prefix_cache is not None else 0) or any(
+                    (len(self._slot_req) + 1) * g.ring > g.n_pages
+                    for g in self._groups.values()):
                 if matched:
                     self.prefix_cache.unpin(matched)
                 break
@@ -1904,6 +2113,13 @@ class PagedContinuousBatcher(_BatcherBase):
                 if not self._alloc_pages(slot, upto):
                     raise RuntimeError("page accounting bug: admission gate "
                                        "passed but allocation failed")
+                if self._groups:
+                    for g in self._groups.values():
+                        g.start(slot, matched)
+                    self._slot_path[slot] = list(matched)
+                    self._slot_nodes[slot] = list(matched)
+                    if not self.prefill_chunk:
+                        self._group_rows(slot, m_rows, padded_len)
                 self._trace_admit_begin(req)
                 self._trace_prefill_begin(req)
                 self._count_slot_state_admit(L)
@@ -1913,7 +2129,7 @@ class PagedContinuousBatcher(_BatcherBase):
                     if self.prefill_chunk:
                         logits = self._prefill_chunked(
                             ids_np[m_rows:], bt_row, slot, dec0=m_rows,
-                            rid=req.rid)
+                            rid=req.rid, ids_full=ids_np)
                     elif self.cache_quant:
                         ids = paddle.to_tensor(ids_np[None, :])
                         logits, self._state["layers"], seq_scales = \
@@ -1976,10 +2192,13 @@ class PagedContinuousBatcher(_BatcherBase):
                     for t in src_tiers:
                         self._tier_hit_c.labels(tier=t).inc(self.block_size)
                     self.prefix_cache.host_hit_tokens += promoted_rows
-                    new_nodes = self.prefix_cache.insert(
-                        ids_np, self._bt[slot], len(matched),
-                        L // self.block_size)
-                    self._slot_nodes[slot] = list(matched) + new_nodes
+                    if self._groups:
+                        self._group_insert(slot, ids_np, L)
+                    else:
+                        new_nodes = self.prefix_cache.insert(
+                            ids_np, self._bt[slot], len(matched),
+                            L // self.block_size)
+                        self._slot_nodes[slot] = list(matched) + new_nodes
                 end_tags = dict(prompt_tokens=len(ids_np), pages=need,
                                 prefix_hit=m_rows, padded_to=padded_len)
                 if promoted_rows:
@@ -2012,7 +2231,7 @@ class PagedContinuousBatcher(_BatcherBase):
             labelnames=("rung",)).labels(rung=str(rung)).inc(waste)
 
     def _prefill_chunked(self, ids_np, bt_row, slot, dec0: int = 0,
-                         rid: int = -1):
+                         rid: int = -1, ids_full=None):
         """Feed the prompt through fixed-width append chunks (ONE compiled
         executable for every prompt length). The tail chunk is zero-padded;
         pad rows land past the true timeline and are overwritten by decode
@@ -2035,6 +2254,11 @@ class PagedContinuousBatcher(_BatcherBase):
         chunks append after ``dec0`` existing rows (prefix-cache hits;
         always 0 on the quantized path, which is gated off prefix reuse).
         ``rid`` tags each chunk's ``serving.prefill_chunk`` span.
+        ``ids_full``: the whole prompt, cached prefix and all, where the
+        model has page groups: before a chunk the window groups hand back
+        what fell behind the window and back the chunk's rows
+        (``serving.release_window``), after it the blocks completed enter
+        the prefix cache.
         """
         import paddle_tpu as paddle
         C = self.prefill_chunk
@@ -2052,6 +2276,9 @@ class PagedContinuousBatcher(_BatcherBase):
             w = min(C, padded_len - dec)     # tail shortens at capacity
             has_last = 0 <= (L - 1) - dec < w
             at = (L - 1) - dec if has_last else 0
+            if self._groups:
+                with _span("serving.release_window"):
+                    self._group_rows(slot, dec0 + dec, dec0 + dec + w)
             with _span("serving.prefill_chunk", rid=rid,
                        state_bytes=self._slot_state_bytes,
                        carried=int(self._slot_state and dec0 + dec > 0)):
@@ -2081,6 +2308,8 @@ class PagedContinuousBatcher(_BatcherBase):
                 # k*C < L by the ceil-padding construction)
                 logits = lg
             dec += w
+            if self._groups:
+                self._group_insert(slot, ids_full, dec0 + dec)
         if scales is not None:
             if last_rest is not None:
                 # sampled saturation telemetry: one baseline read of the
@@ -2205,6 +2434,8 @@ class PagedContinuousBatcher(_BatcherBase):
             # executable) stays identical
             self._state["block_size"] = self.block_size
             self._state["capacity"] = self.blocks_per_seq * self.block_size
+            if self._groups:
+                self._state["group_tables"] = self._group_tables()
             if self.cache_quant and self._scales_dirty:
                 # scales change only at admit/release — skip the L x 4
                 # re-uploads on the steady-state decode path
@@ -2311,6 +2542,13 @@ class PagedContinuousBatcher(_BatcherBase):
         ones."""
         if self.policy == "ondemand":
             self._grow_for_step()
+        if self._groups:
+            # every running slot is about to write row dec[slot]
+            with _span("serving.release_window"):
+                for slot in self._slot_req:
+                    self._group_rows(slot, int(self._dec[slot]),
+                                     int(self._dec[slot]) + 1)
+                self._count_groups()
         self._tele.on_step()
         self._tele.on_occupancy(len(self._slot_req))
         self._tele.set_gauges(len(self._pending), len(self._slot_req))

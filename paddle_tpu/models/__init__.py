@@ -17,6 +17,7 @@ from .glm_dsa import GlmDsaConfig, GlmDsaForCausalLM, glm_dsa_tiny_config
 from .gpt import GPT2Config, GPT2ForCausalLM, GPT2Model, gpt2_124m_config
 from .resnet import (BasicBlock, BottleneckBlock, ResNet, resnet18, resnet34,
                      resnet50, resnet101, resnet152)
+from .mellum import MellumConfig, MellumForCausalLM, mellum_tiny_config
 from .sambay import SambaYConfig, SambaYForCausalLM, sambay_tiny_config
 from .unet import (UNetConfig, UNetModel, ddim_sample, ddpm_loss,
                    sd_unet_config, unet_tiny_config)
@@ -31,6 +32,7 @@ __all__ = [
     "resnet50", "resnet101", "resnet152",
     "SambaYConfig", "SambaYForCausalLM", "sambay_tiny_config",
     "GlmDsaConfig", "GlmDsaForCausalLM", "glm_dsa_tiny_config",
+    "MellumConfig", "MellumForCausalLM", "mellum_tiny_config",
     "UNetConfig", "UNetModel", "unet_tiny_config", "sd_unet_config",
     "ddpm_loss", "ddim_sample",
 ]

@@ -56,8 +56,10 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..ops.registry import dispatch
+from .routed_experts import (F32, _counts_of_chunk,  # noqa: F401
+                             _counts_of_step, _mm, _swiglu, _tile_rows,
+                             expert_counts, routed_experts)
 
-F32 = jnp.float32
 _NEG = -1e30
 
 
@@ -129,10 +131,6 @@ def glm_dsa_tiny_config(**overrides) -> GlmDsaConfig:
 # recorded as the executable's state. Matrix products accumulate in float32;
 # norms, softmax, the router and the index scores are float32.
 
-def _mm(x, w, out=None):
-    return jnp.dot(x, w, preferred_element_type=F32).astype(out or x.dtype)
-
-
 def _rms(x, gain, eps):
     xf = x.astype(F32)
     return (xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
@@ -177,12 +175,6 @@ def _widen(x, width: int):
     """x [..., w] with zeros up to ``width``."""
     pad = width - x.shape[-1]
     return x if not pad else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-
-
-def _swiglu(x, w1, w2):
-    gp = _mm(x, w1, F32)
-    f = gp.shape[-1] // 2
-    return _mm((jax.nn.silu(gp[..., :f]) * gp[..., f:]).astype(x.dtype), w2)
 
 
 def _attn_inputs(p, x, cos, sin, eps, ieps):
@@ -359,71 +351,6 @@ def route(p, h, top_k: int, scaling: float):
     return chosen.astype(jnp.int32), gates
 
 
-def _tile_rows(n: int) -> int:
-    """Rows of one tile of the grouped product: whole groups of a decode
-    step, MXU-sized tiles of a chunk."""
-    return int(min(128, max(8, 1 << (max(n, 1) - 1).bit_length())))
-
-
-def routed_experts(p, h, chosen, gates, held):
-    """The held experts' part of ``sum_e g_e SwiGLU_e(h)``: h [N, d] ->
-    ([N, d], assignments to each held expert [count] int32).
-
-    Dropless, whatever the skew: the N * k assignments are sorted by
-    expert, every held expert's group is padded to whole tiles of
-    ``_tile_rows(N)`` rows, and the tiles that hold anything are
-    multiplied one after another, each through its expert's weights (an
-    expert nobody chose costs nothing, its weights are not read); a token
-    then adds up its own rows of the result."""
-    start, count = held
-    n, k = chosen.shape
-    d = h.shape[-1]
-    tm = _tile_rows(n)
-    max_tiles = -(-n * k // tm) + count
-    local = (chosen >= start) & (chosen < start + count)
-    expert = jnp.where(local, chosen - start, count).reshape(-1)   # [A]
-    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
-    where_sorted = jnp.zeros_like(order).at[order].set(
-        jnp.arange(n * k, dtype=jnp.int32))
-    counts = jnp.sum(expert[:, None] == jnp.arange(count)[None, :], 0,
-                     dtype=jnp.int32)
-    padded = -(-counts // tm) * tm
-    pad_end = jnp.cumsum(padded)
-    pad_start = pad_end - padded
-    src_start = jnp.cumsum(counts) - counts
-    n_tiles = pad_end[-1] // tm
-    tile_expert = jnp.minimum(jnp.sum(
-        pad_end[None, :] <= (jnp.arange(max_tiles) * tm)[:, None], -1,
-        dtype=jnp.int32), count - 1)                         # [max_tiles]
-    r = jnp.arange(max_tiles * tm, dtype=jnp.int32)
-    e_r = jnp.repeat(tile_expert, tm)
-    within = r - pad_start[e_r]
-    real = within < counts[e_r]
-    assign = order[jnp.clip(src_start[e_r] + within, 0, n * k - 1)]
-    token = jnp.where(real, assign // k, 0).reshape(max_tiles, tm)
-    gate = jnp.where(real, gates.reshape(-1)[assign], 0.0).reshape(
-        max_tiles, tm)
-
-    def tile(t, out):
-        e = tile_expert[t]
-        x = h[token[t]]
-        y = _swiglu(x, lax.dynamic_index_in_dim(p["exp_w1"], e, 0, False),
-                    lax.dynamic_index_in_dim(p["exp_w2"], e, 0, False))
-        y = (y.astype(F32) * gate[t][:, None]).astype(h.dtype)
-        return lax.dynamic_update_slice_in_dim(out, y, t * tm, 0)
-
-    out = lax.fori_loop(0, n_tiles, tile,
-                        jnp.zeros((max_tiles * tm + 1, d), h.dtype))
-    # a token's own rows: an assignment to an expert held elsewhere reads
-    # the zero row at the end
-    own = jnp.minimum(expert, count - 1)
-    at = jnp.where(local.reshape(-1),
-                   pad_start[own] + where_sorted - src_start[own],
-                   max_tiles * tm)
-    mine = out[at.reshape(n, k)].astype(F32)
-    return jnp.sum(mine, 1).astype(h.dtype), counts
-
-
 def _ffn(p, x, eps, held, top_k, scaling, active=None):
     """x [N, d] -> (x + FFN(RMSNorm(x)), counts [4] int32: the assignments
     to experts held here, all the assignments the router made, the held
@@ -445,10 +372,7 @@ def _ffn(p, x, eps, held, top_k, scaling, active=None):
         y, counts = routed_experts(p, h, chosen, gates, held)
     with jax.named_scope("expert_shared"):
         y = y + _swiglu(h, p["sh_w1"], p["sh_w2"])
-    return x + y, jnp.stack([
-        jnp.sum(counts, dtype=jnp.int32),
-        jnp.asarray(routed * top_k, jnp.int32),
-        jnp.sum(counts > 0, dtype=jnp.int32), jnp.max(counts)])
+    return x + y, expert_counts(counts, routed * top_k)
 
 
 # -- blocks -------------------------------------------------------------------
@@ -636,16 +560,6 @@ def _block_tok(p, x, lat_pool, idx_pool, table, dec, cos_t, sin_t, eps,
     x = x + _absorbed_out(p, o_lat, x.dtype)
     x, counts = _ffn(p, x, eps, held, top_k, scaling, active=active)
     return x, lat_pool, idx_pool, jnp.concatenate([counts, chose])
-
-
-def _counts_of_step(counts, *per_layer):
-    """``step_counts`` after a decode step: its own in [0]."""
-    return jnp.stack([jnp.stack(per_layer), counts[1]])
-
-
-def _counts_of_chunk(counts, *per_layer):
-    """``step_counts`` after a chunk: its own added to [1]."""
-    return counts.at[1].add(jnp.stack(per_layer))
 
 
 def _head(top, x, eps):

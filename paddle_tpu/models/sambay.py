@@ -779,8 +779,11 @@ class SambaYForCausalLM(Layer):
             "window_rings": sum(l.kind == "window"
                                 for l in self.model.layers),
             "unsupported": {
-                "prefix_cache": f"a cached prefix is pages alone; {pages}, "
-                                f"and nothing snapshots them at a block "
+                "prefix_cache": f"a cached prefix is pages alone; {pages}: "
+                                f"a window layer gets a prefix cache by "
+                                f"keeping its rows as a page group "
+                                f"(models/mellum.py), and nothing "
+                                f"snapshots a recurrent state at a block "
                                 f"boundary",
                 "kv_quant": "no calibrated int8 path for a pool of key "
                             "groups beside float state",

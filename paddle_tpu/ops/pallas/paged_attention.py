@@ -60,9 +60,14 @@ def supported(pool_shape, pool_dtype, q_heads: int) -> bool:
     return hd % 128 == 0 and block % rows_a_tile == 0 and q_heads % kvh == 0
 
 
-def _decode_kernel(lens_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref, *,
-                   batch, blocks_per_seq, pages_per_block, scale):
+def _decode_kernel(lens_ref, bt_ref, *refs, batch, blocks_per_seq,
+                   pages_per_block, scale, from_row=False):
+    # ``from_row``: a third scalar-prefetch array, the first row of each
+    # sequence's table that counts (a window layer's table starts at the
+    # page the window starts in, not at the row)
+    start_ref = refs[0] if from_row else None
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, \
+        acc_ref = refs[1:] if from_row else refs
     b = pl.program_id(0)
     n_pool, kvh, bs, hd = k_hbm.shape
     rep = q_ref.shape[1]
@@ -131,8 +136,12 @@ def _decode_kernel(lens_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
         wait(b, i, slot)
 
         base = i * span
-        in_len = base + lax.broadcasted_iota(jnp.int32, (rep, span), 1) < n
-        v_live = base + lax.broadcasted_iota(jnp.int32, (span, hd), 0) < n
+        across = base + lax.broadcasted_iota(jnp.int32, (rep, span), 1)
+        down = base + lax.broadcasted_iota(jnp.int32, (span, hd), 0)
+        in_len, v_live = across < n, down < n
+        if from_row:
+            in_len &= across >= start_ref[b]
+            v_live &= down >= start_ref[b]
         for g in range(kvh):
             q = q_ref[g]                                   # [rep, D]
             k = kbuf[slot, :, g].reshape(span, hd)
@@ -160,12 +169,15 @@ def _decode_kernel(lens_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret",
                                              "scale"))
-def _decode_call(kv_len, block_tables, q, key_cache, value_cache, *,
-                 pages_per_block, interpret, scale=None):
+def _decode_call(kv_len, block_tables, q, key_cache, value_cache,
+                 kv_start=None, *, pages_per_block, interpret, scale=None):
     """The launch, jitted on its own: a step calls it once a layer, and
     the eager first call of a ``to_static`` step would otherwise trace,
     lower and compile the kernel anew for every layer (57 s of set-up at
-    16 layers on the v5e)."""
+    16 layers on the v5e). Without ``kv_start`` the kernel is the one it
+    was before there was one."""
+    from_row = kv_start is not None
+    scalars = (kv_len, block_tables) + ((kv_start,) if from_row else ())
     batch, kvh, rep, hd = q.shape
     bs = key_cache.shape[2]
     blocks_per_seq = block_tables.shape[0] // batch
@@ -175,9 +187,10 @@ def _decode_call(kv_len, block_tables, q, key_cache, value_cache, *,
         functools.partial(_decode_kernel, batch=batch,
                           blocks_per_seq=blocks_per_seq,
                           pages_per_block=pages_per_block,
-                          scale=scale or 1.0 / float(hd) ** 0.5),
+                          scale=scale or 1.0 / float(hd) ** 0.5,
+                          **({"from_row": True} if from_row else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(batch,),
             in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -198,18 +211,21 @@ def _decode_call(kv_len, block_tables, q, key_cache, value_cache, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention_decode",
-    )(kv_len, block_tables, q, key_cache, value_cache)
+    )(*scalars, q, key_cache, value_cache)
 
 
 def paged_attention_decode(q, key_cache, value_cache, block_tables, kv_len,
                            *, pages_per_block=None, interpret=False,
-                           scale=None):
+                           scale=None, kv_start=None):
     """q [B, H, D] (after RoPE) against the pool ``[n_pages, KV, block, D]``
     through ``block_tables [B, blocks_per_seq]``; ``kv_len [B]`` rows of
     each sequence count, this step's row among them (it is in the pool
     already). ``scale`` multiplies the scores (``D ** -0.5`` where None: a
-    caller whose rows are wider than its heads says so). Returns [B, H, D]
-    in q's dtype."""
+    caller whose rows are wider than its heads says so). ``kv_start [B]``,
+    where given: rows of the table before it do not count (a layer that
+    keeps a window hands in the pages the window lies in: its first row is
+    somewhere in the first of them; ``kv_start < kv_len``, within the
+    table's first page). Returns [B, H, D] in q's dtype."""
     batch, heads, hd = q.shape
     _, kvh, bs, _ = key_cache.shape
     if pages_per_block is None:
@@ -221,5 +237,6 @@ def paged_attention_decode(q, key_cache, value_cache, block_tables, kv_len,
         functools.partial(_decode_call, pages_per_block=pages_per_block,
                           interpret=interpret, scale=scale),
         kv_len.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1),
-        q.reshape(batch, kvh, heads // kvh, hd), key_cache, value_cache)
+        q.reshape(batch, kvh, heads // kvh, hd), key_cache, value_cache,
+        *(() if kv_start is None else (kv_start.astype(jnp.int32),)))
     return out.reshape(batch, heads, hd)

@@ -544,3 +544,64 @@ def test_glm_dsa_expert_block_compiles_at_published_widths(chip, entry):
     text = compiled.as_text()
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "bf16[16385,1,16," in ln]
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+@pytest.mark.parametrize("entry", ["decode", "chunk"])
+def test_mellum_blocks_compile_at_published_widths(chip, monkeypatch, entry,
+                                                   kind):
+    """One block of ``models/mellum.py`` of each kind at the cell
+    ``mellum2-ide-mixed``'s shapes (hidden 2304, 32/4 heads of 128, window
+    1,024, all 64 experts of width 896, 16 slots; the full group 24,577
+    pages of 16 rows over tables of 2,048, the window group 6,145 pages
+    behind rings of 99): a decode step (the row written by the page, PR
+    26's kernel over the table or, with a first row, over the 65 pages the
+    window lies in, the grouped product over the experts touched) and a
+    chunk of 512 (a full layer reads held rows 1,024 at a time, a window
+    layer the 97 pages before and under it). The pools keep their place and
+    a block needs well under a gigabyte beside them."""
+    import paddle_tpu.ops.pallas as pallas_tier
+    from paddle_tpu.models import mellum
+
+    monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
+    d, heads, kv, hd, experts, f = 2304, 32, 4, 128, 64, 896
+    slots, block, s_max = 16, 16, 32768
+    window = 1024 if kind == "window" else 0
+    pages, table = (6145, 99) if window else (24577, s_max // block)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    w = {"ln1_g": sds((d,)), "q_w": sds((d, heads * hd)),
+         "k_w": sds((d, kv * hd)), "v_w": sds((d, kv * hd)),
+         "q_g": sds((hd,)), "k_g": sds((hd,)), "o_w": sds((heads * hd, d)),
+         "ln2_g": sds((d,)), "router_w": sds((d, experts)),
+         "exp_w1": sds((experts, d, 2 * f)), "exp_w2": sds((experts, f, d))}
+    static = dict(eps=1e-6, heads=heads, kv_heads=kv, window=window, top_k=8,
+                  norm_topk=True)
+    pool = sds((pages, kv, block, hd))
+    angles = sds((s_max, hd // 2), jnp.float32)
+    if entry == "decode":
+        compiled = jax.jit(
+            lambda p, x, kc, vc, tab, dec, cos, sin: mellum._block_tok(
+                p, x, kc, vc, tab, dec, cos, sin, **static),
+            donate_argnums=(2, 3)).lower(
+                w, sds((slots, d)), pool, pool,
+                sds((slots, table), jnp.int32), sds((slots,), jnp.int32),
+                angles, angles).compile()
+        assert len(_kernel_calls(compiled, "paged_attention_decode")) == 1
+    else:
+        compiled = jax.jit(
+            lambda p, x, kc, vc, tab, dec, real, cos, sin:
+            mellum._block_chunk(p, x, kc, vc, tab, dec, real, cos, sin,
+                                kb=1024, **static),
+            donate_argnums=(2, 3)).lower(
+                w, sds((512, d)), pool, pool, sds((table,), jnp.int32),
+                sds((), jnp.int32), sds((), jnp.int32), angles,
+                angles).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * pages * kv * block * hd * 2
+    assert memory.temp_size_in_bytes < 768 << 20
+    text = compiled.as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"bf16[{pages},4,16,128]" in ln]

@@ -363,27 +363,7 @@ def test_the_cache_gauge_reads_the_pools_as_allocated():
 
 # -- the router and the experts -----------------------------------------------
 
-def _expert_layer(seed=0, d=16, f=8, experts=16):
-    rng = np.random.default_rng(seed)
-
-    def draw(*shape):
-        return jnp.asarray(rng.normal(size=shape), jnp.float32)
-
-    return {"router_w": draw(d, experts), "router_b": jnp.zeros(experts),
-            "exp_w1": draw(experts, d, 2 * f), "exp_w2": draw(experts, f, d),
-            "sh_w1": draw(d, 2 * f), "sh_w2": draw(f, d),
-            "ln2_g": jnp.ones(d)}, draw(24, d)
-
-
-def _by_hand(p, h, chosen, gates, held):
-    out = np.zeros(h.shape, np.float32)
-    for t in range(h.shape[0]):
-        for j in range(chosen.shape[1]):
-            e = int(chosen[t, j])
-            if held[0] <= e < held[0] + held[1]:
-                out[t] += float(gates[t, j]) * np.asarray(G._swiglu(
-                    h[t:t + 1], p["exp_w1"][e], p["exp_w2"][e]))[0]
-    return out
+from test_routed_experts import _expert_layer  # noqa: E402
 
 
 def test_the_bias_moves_the_choice_and_not_the_gate():
@@ -399,26 +379,6 @@ def test_the_bias_moves_the_choice_and_not_the_gate():
     picked = np.take_along_axis(score, chosen_b, -1)     # without the bias
     np.testing.assert_allclose(
         gates_b, 2.5 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
-
-
-@pytest.mark.parametrize("rows", [1, 3, 24])
-def test_nothing_is_dropped_when_every_token_picks_one_expert(rows):
-    p, h = _expert_layer(1)
-    h = h[:rows]
-    p = dict(p, router_b=jnp.zeros(16).at[5].set(10.0))
-    chosen, gates = G.route(p, h, 4, 2.5)
-    assert (np.asarray(chosen) == 5).any(-1).all()
-    for held in ((0, 16), (4, 4), (5, 1)):
-        share = dict(p, exp_w1=p["exp_w1"][held[0]:held[0] + held[1]],
-                     exp_w2=p["exp_w2"][held[0]:held[0] + held[1]])
-        got, counts = G.routed_experts(share, h, chosen, gates, held)
-        np.testing.assert_allclose(np.asarray(got),
-                                   _by_hand(p, h, chosen, gates, held),
-                                   atol=1e-4, rtol=1e-5)
-        assert int(counts[5 - held[0]]) == rows      # all of them, in one
-        assert int(counts.sum()) == int(np.sum(
-            (np.asarray(chosen) >= held[0])
-            & (np.asarray(chosen) < held[0] + held[1])))
 
 
 def test_the_parts_all_shares_give_add_up_to_the_uncut_layer():

@@ -126,6 +126,32 @@ def test_any_compute_block_width_gives_the_same_result(pages_per_block):
                                atol=1e-5, rtol=0)
 
 
+@pytest.mark.parametrize("pages_per_block", [1, 4])
+def test_rows_before_the_first_row_do_not_count(pages_per_block):
+    """A window layer's table starts at the page its window starts in:
+    ``kv_start`` rows of that page lie before the window. Without it the
+    kernel is the one it was."""
+    q, kc, vc, bt = _case(5, 8, 2, jnp.float32, seed=2)
+    lengths = np.array([1, 9, 33, 70, CAPACITY], np.int32)
+    starts = np.array([0, 8, 15, 3, BLOCK - 1], np.int32)
+    got = _kernel(q, kc, vc, bt, lengths, pages_per_block=pages_per_block,
+                  kv_start=jnp.asarray(starts))
+    heads, kv_heads = q.shape[1], kc.shape[1]
+    for b, (n, a) in enumerate(zip(lengths, starts)):
+        k = jnp.concatenate([kc[p] for p in bt[b]], axis=1)[:, a:n]
+        v = jnp.concatenate([vc[p] for p in bt[b]], axis=1)[:, a:n]
+        qg = q[b].reshape(kv_heads, heads // kv_heads, HEAD_DIM)
+        s = jnp.einsum("grd,gsd->grs", qg, k) / HEAD_DIM ** 0.5
+        want = jnp.einsum("grs,gsd->grd", jax.nn.softmax(s, -1), v)
+        np.testing.assert_allclose(got[b], np.asarray(want).reshape(
+            heads, HEAD_DIM), atol=1e-5, rtol=0)
+    zero = _kernel(q, kc, vc, bt, lengths, pages_per_block=pages_per_block,
+                   kv_start=jnp.zeros(5, jnp.int32))
+    np.testing.assert_allclose(
+        zero, _kernel(q, kc, vc, bt, lengths,
+                      pages_per_block=pages_per_block), atol=1e-6, rtol=0)
+
+
 def test_idle_slot_reads_the_scratch_page_and_disturbs_nobody():
     """A slot with no request: ``dec_lens`` 0, every table entry the scratch
     page. Its output is ignored by the batcher; it has to be finite and the
