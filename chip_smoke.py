@@ -18,9 +18,10 @@ one 16 GB v5e holds and random weights made from ``--seed``:
            prefill_chunk=256)`` replica: requests of mixed prompt length,
            two sharing a prefix.
            Checks: every request returns ``max_new_tokens`` tokens, each
-           request's first-step logits agree with the model's plain un-paged
-           forward within a bf16 tolerance, the prefix cache was hit, and
-           ``audit_pages() == 0``.
+           served token's logit in the model's plain un-paged forward lies
+           within a bf16 tolerance of that forward's best (the batcher is
+           greedy: its executables choose, no logits reach the host), the
+           prefix cache was hit, and ``audit_pages() == 0``.
 
 ``--chips 4`` runs, instead of those two, only the sharded train step
 (``MeshRuntime({"fsdp": 2, "tensor": 2})`` + ``TrainMeshPlan``) and the same
@@ -78,7 +79,8 @@ TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                  "rms_norm_fwd", "rms_norm_bwd")
 # bf16 keeps 8 bits of mantissa; the paged path (fp32 softmax over gathered
 # pages) and the plain forward (flash kernel) round differently at every
-# layer. Logits, not tokens: random weights put argmax on near-ties.
+# layer. A served token's distance below the plain forward's best, not
+# equal tokens: random weights put argmax on near-ties.
 LOGIT_TOL = 2.0 ** -4       # of the largest |logit| of the plain forward
 # the sharded step gathers parameters at use and computes what one device
 # computes; bf16 fusion boundaries may still differ between the programs
@@ -233,21 +235,12 @@ def server_phase(sz, seed, device):
         model, max_batch=len(prompts), s_max=sz.seq, block_size=sz.block,
         n_pages=sz.n_pages, compile=True, prefix_cache=True,
         prefill_chunk=sz.prefill_chunk)
-    # record what each step hands to token selection: the first row per
-    # request is its prefill's last-position logits
-    seen, pick = [], batcher._pick
-    batcher._pick = lambda logits: (seen.append(np.array(logits)),
-                                    pick(logits))[1]
     try:
         gateway = Gateway()
         gateway.add_replica("chip0", batcher)
         gids = [gateway.submit(p, sz.max_new) for p in prompts]
         done = gateway.run_until_done()
         t1 = time.perf_counter()
-        prefill_logits = [x for x in seen if x.shape[0] == 1]
-        if len(prefill_logits) != len(prompts):
-            raise AssertionError(f"{len(prefill_logits)} admissions for "
-                                 f"{len(prompts)} requests")
         for gid, prompt in zip(gids, prompts):
             out = done[gid]
             if len(out) != len(prompt) + sz.max_new \
@@ -263,29 +256,30 @@ def server_phase(sz, seed, device):
                                  f"{sz.shared}-token shared prefix")
         say("server.prefix_hit_tokens", hit)
 
-        # the plain forward, all prompts right-padded into one batch: under
-        # the causal mask a position's logits do not see the padding
-        # after it, and one shape compiles once
-        ids = np.zeros((len(prompts), max(sz.prompt_lens)), np.int64)
-        for row, prompt in zip(ids, prompts):
-            row[:len(prompt)] = prompt
+        # the plain forward over what was served, all sequences right-padded
+        # into one batch: under the causal mask a position's logits do not
+        # see the padding after it, and one shape compiles once
+        seqs = [done[gid] for gid in gids]
+        ids = np.zeros((len(seqs), max(len(s) for s in seqs)), np.int64)
+        for row, seq in zip(ids, seqs):
+            row[:len(seq)] = seq
         with paddle.no_grad():
             plain_all = model(paddle.to_tensor(ids))._data
-        errs = {}
-        for i, (prompt, paged) in enumerate(zip(prompts, prefill_logits)):
-            plain = np.asarray(plain_all[i, len(prompt) - 1], np.float32)
-            paged = np.asarray(paged, np.float32).reshape(-1)
-            if paged.shape != plain.shape or not np.isfinite(paged).all():
-                raise AssertionError("first-step logits: bad shape or "
-                                     "non-finite values")
-            errs[f"prompt{len(prompt)}"] = round(float(
-                np.abs(paged - plain).max() / np.abs(plain).max()), 5)
-        worst = max(errs.values())
-        say("server.logit_err", f"{errs} of max |logit| (tolerance "
+        gaps = {}
+        for i, (prompt, seq) in enumerate(zip(prompts, seqs)):
+            at = np.arange(len(prompt) - 1, len(seq) - 1)
+            plain = np.asarray(plain_all[i, at], np.float32)
+            if not np.isfinite(plain).all():
+                raise AssertionError("plain forward: non-finite logits")
+            served = plain[np.arange(len(at)), seq[at + 1]]
+            gaps[f"prompt{len(prompt)}"] = round(float(
+                (plain.max(-1) - served).max() / np.abs(plain).max()), 5)
+        worst = max(gaps.values())
+        say("server.token_gap", f"{gaps} of max |logit| (tolerance "
             f"{LOGIT_TOL:.5f})")
         if worst > LOGIT_TOL:
-            raise AssertionError("paged first-step logits disagree with "
-                                 "the plain forward")
+            raise AssertionError("a served token lies below the plain "
+                                 "forward's best")
         leaked = batcher.audit_pages()
         if leaked:
             raise AssertionError(f"audit_pages() == {leaked}")

@@ -1002,6 +1002,17 @@ class PagedContinuousBatcher(_BatcherBase):
             "serving.prefix_promotion_failures",
             "promotions that failed/timed out/lost the page race "
             "(admission degraded to full prefill)")
+        picks = _reg.counter(
+            "serving.picks_total",
+            "tokens chosen on the plain path (admission's first and every "
+            "decode step's), by where: inside the executable, or on the "
+            "host from fetched logits", labelnames=("where",))
+        self._picks_c = {w: picks.labels(where=w)
+                         for w in ("device", "host")}
+        self._fetch_bytes_c = _reg.counter(
+            "serving.fetch_bytes_total",
+            "bytes copied to the host for token selection on the plain "
+            "path: int32 ids or whole logits")
         self._demote_bytes_c = _reg.counter(
             "serving.prefix_demoted_bytes",
             "KV bytes spilled device -> host tier on eviction")
@@ -1157,15 +1168,35 @@ class PagedContinuousBatcher(_BatcherBase):
             from ..perf.buckets import BucketLadder
             self._cu_ladder = BucketLadder.pow2(hi=s_max)
         self.prefill_chunk = prefill_chunk
+
+        # where the next token is chosen. Greedy: inside the executable
+        # that made the logits, as one more output; the host fetches int32
+        # ids ([B] a decode step, [1] a chunk; jnp.argmax keeps the lower
+        # index of equal maxima, as numpy's does) and the [B, V] logits
+        # never leave the device. Sampled: the generator's key is a
+        # constant of a traced step, so the logits go to the host whole
+        # and ``_select_token`` draws there
+        def _chosen(logits):
+            if do_sample:
+                return logits
+            return paddle.argmax(logits, axis=-1, dtype="int32")
+
+        def _decode(tok, state):
+            logits, state = model.paged_decode_step(tok, state)
+            return _chosen(logits), state
         if compile:
             from .. import jit
             # donate the state pytree (arg 1): the page pool is the big
-            # buffer — XLA appends into it in place every step
+            # buffer — XLA appends into it in place every step. Compiled,
+            # the step stays the model's own method and the choice is made
+            # of its result (``_post``): a wrapper's frame under the model's
+            # call cost 4 s of warm-up at 16 layers (PERF.md section 6)
             self._step_fn = jit.to_static(model.paged_decode_step,
                                           donate_args=(1,))
             self._step_fn._opprof_label = "serving.paged_decode"
+            self._step_fn._post = lambda out: (_chosen(out[0]), out[1])
         else:
-            self._step_fn = model.paged_decode_step
+            self._step_fn = _decode
         if prefill_chunk is not None:
             # one fixed-width append executable serves EVERY prompt
             # length (vLLM chunked prefill); without it each distinct
@@ -1174,9 +1205,10 @@ class PagedContinuousBatcher(_BatcherBase):
             # and how many of the chunk's rows are real (``_slot_args``);
             # the pad rows of a fixed-width chunk must leave the state alone
             def _chunk(ids, layers, bt_row, dec, at, **slot_args):
-                return model.paged_prefill_into(
+                logits, layers = model.paged_prefill_into(
                     ids, layers, bt_row, block_size, dec_base=dec,
                     logits_at=at, **slot_args)
+                return _chosen(logits), layers
             if compile:
                 from .. import jit
                 # donate the pool (arg 1) exactly like the decode step —
@@ -1194,15 +1226,17 @@ class PagedContinuousBatcher(_BatcherBase):
                 # scale set (VERDICT r3 #5; reference analog
                 # block_multihead_attention.py's scales+chunk signature)
                 def _chunk_dyn_first(ids, layers, bt_row, dec, at, nvalid):
-                    return model.paged_prefill_into(
+                    logits, layers, scales = model.paged_prefill_into(
                         ids, layers, bt_row, block_size, dec_base=dec,
                         logits_at=at, dynamic_cache_scales=True,
                         dynamic_scale_valid=nvalid)
+                    return _chosen(logits), layers, scales
 
                 def _chunk_dyn_rest(ids, layers, bt_row, dec, at, scales):
-                    return model.paged_prefill_into(
+                    logits, layers = model.paged_prefill_into(
                         ids, layers, bt_row, block_size, dec_base=dec,
                         logits_at=at, cache_scales=scales)
+                    return _chosen(logits), layers
                 if compile:
                     self._chunk_dyn_first_fn = jit.to_static(
                         _chunk_dyn_first, donate_args=(1,))
@@ -2207,9 +2241,12 @@ class PagedContinuousBatcher(_BatcherBase):
                     # host tier, not by recomputing
                     end_tags["host_promoted"] = promoted_rows
                 self._trace_prefill_end(req, **end_tags)
+                # chunked admission under greedy brings the one id its
+                # last chunk chose; every other admission the [1, V] logits
                 with _span("serving.fetch"):
-                    logits_np = np.asarray(logits._data)
-                tok = int(self._pick(logits_np)[0])
+                    fetched = np.asarray(logits._data)
+                tok = int(self._pick(fetched)[0])
+                self._count_picks(fetched, 1)
                 req.slot = slot
                 req.tokens.append(tok)
                 self._tele.on_admit()
@@ -2223,6 +2260,21 @@ class PagedContinuousBatcher(_BatcherBase):
                     finished.append(req.rid)
         return finished
 
+    def _pick(self, fetched):
+        """Next tokens [B] from what a step's fetch brought. Ids (one
+        axis, int32): the executable chose them on the device (greedy,
+        the decode step and chunked admission) and they pass through.
+        Logits [B, V]: picked here on the host, by the model's sampling
+        with ``do_sample``, by numpy's argmax for a greedy admission that
+        prefilled its whole prompt uncompiled."""
+        if fetched.ndim == 1:
+            return fetched
+        return super()._pick(fetched)
+
+    def _count_picks(self, fetched: np.ndarray, tokens: int):
+        self._fetch_bytes_c.inc(fetched.nbytes)
+        self._picks_c["device" if fetched.ndim == 1 else "host"].inc(tokens)
+
     def _count_pad_waste(self, rung: int, waste: int):
         from ..observability.metrics import get_registry
         get_registry().counter(
@@ -2235,8 +2287,10 @@ class PagedContinuousBatcher(_BatcherBase):
         """Feed the prompt through fixed-width append chunks (ONE compiled
         executable for every prompt length). The tail chunk is zero-padded;
         pad rows land past the true timeline and are overwritten by decode
-        before any bounded read reaches them. Returns the last REAL
-        position's logits [1, V].
+        before any bounded read reaches them. Returns what the chunk that
+        holds the last REAL position handed back for selection: the id it
+        chose [1] under greedy, that position's logits [1, V] with
+        ``do_sample``.
 
         Dynamic cachekv-int8 composition (VERDICT r3 #5): with
         cache_quant set, chunk 1 computes the sequence's per-head scales
@@ -2509,22 +2563,27 @@ class PagedContinuousBatcher(_BatcherBase):
             upto = max(padded_len, L + 1)
         return ids_np, L, padded_len, upto
 
-    def _advance_decoders(self, logits, finished: List[int]):
-        """Consume a step's decode logits: advance timelines, append the
-        picked tokens, evict finished slots."""
+    def _advance_decoders(self, chosen, finished: List[int]):
+        """Consume what a decode step handed back for selection: advance
+        timelines, append the next tokens, evict finished slots.
+
+        ``serving.fetch`` is the host's wait for the step and one copy:
+        under greedy the [B] int32 ids the executable chose (4 B bytes),
+        with ``do_sample`` the [B, V] logits; a model's step counts come
+        in the same wait. ``serving.pick`` is ``_pick`` (nothing to do
+        under greedy) and the per-request loop."""
         with _span("serving.fetch"):
-            # the host waits here for the device, then copies [B, V]; a
-            # model's step counts come in the same wait
             counts = self._state["layers"]["step_counts"]._data \
                 if self._step_counts else None
             if counts is not None:
                 counts.copy_to_host_async()
-            logits_np = np.asarray(logits._data)
+            fetched = np.asarray(chosen._data)
         if counts is not None:
             self._add_step_counts(np.asarray(counts))
         with _span("serving.pick"):
             self._dec += np.asarray(self._slot_active_mask(), np.int32)
-            next_tok = self._pick(logits_np)
+            next_tok = self._pick(fetched)
+            self._count_picks(fetched, len(self._slot_req))
             for slot, req in list(self._slot_req.items()):
                 tok = int(next_tok[slot])
                 req.tokens.append(tok)
@@ -2571,8 +2630,8 @@ class PagedContinuousBatcher(_BatcherBase):
             self._kv_write_c.inc()
             self._count_slot_state_step()
             tok_t = paddle.to_tensor(self._last_tok)
-            logits, self._state = self._step_fn(tok_t, self._state)
-        self._advance_decoders(logits, finished)
+            chosen, self._state = self._step_fn(tok_t, self._state)
+        self._advance_decoders(chosen, finished)
         self._tele.on_decode_time(_time.perf_counter() - t0,
                                   tokens=n_active)
 

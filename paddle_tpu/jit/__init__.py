@@ -104,6 +104,13 @@ class StaticFunction:
         # inputs are invalid after the call, so any grad-mode call on a
         # donating function raises up front.
         self._donate_args = tuple(donate_args) if donate_args else ()
+        # ``_post(result)``, where a caller sets it: what to make of the
+        # function's result, in the eager first call and in the traced
+        # step alike. It runs after the function has returned, so no frame
+        # of the caller's stands under the function's own call: how long
+        # the eager first call takes to lower a Pallas kernel depends on
+        # the depth of the Python stack there (PERF.md section 6, PR 39)
+        self._post = None
 
     @property
     def code(self):
@@ -115,7 +122,8 @@ class StaticFunction:
 
     def __call__(self, *args, **kwargs):
         if not _TO_STATIC_ENABLED[0]:
-            return self._fn(*args, **kwargs)
+            out = self._fn(*args, **kwargs)
+            return out if self._post is None else self._post(out)
         if self._donate_args and _engine.is_grad_enabled():
             # fail fast and CONSISTENTLY (not only once compiled): donated
             # buffers die after the call, which would corrupt the tape
@@ -314,6 +322,8 @@ class StaticFunction:
             (args, kwargs), is_leaf=_is_tensor) if _is_tensor(t)}
         with _capture.CaptureContext() as cap:
             out = self._fn(*args, **kwargs)
+            if self._post is not None:
+                out = self._post(out)
 
         state = [t for i, t in cap.reads.items()
                  if i not in arg_ids and not isinstance(t._data, jax.core.Tracer)]
@@ -338,6 +348,8 @@ class StaticFunction:
                 a2, k2 = _rewrap_args(flat_args, self._treedef, self._tensor_pos,
                                       self._static_flat)
                 res = fn(*a2, **k2)
+                if self._post is not None:
+                    res = self._post(res)
                 out_arrays = jax.tree_util.tree_map(
                     lambda x: x._data if _is_tensor(x) else x, res,
                     is_leaf=_is_tensor)

@@ -7,7 +7,7 @@ aspect of what it printed. The test stands in for the two things only a TPU
 can answer: the device assertion, and the presence of the Pallas kernels in
 the step's HLO (off the chip the program takes the kernels' XLA references).
 Everything else the script checks — falling loss, no warm compile, token
-counts, paged logits against the plain forward, the prefix hit, the page
+counts, served tokens against the plain forward, the prefix hit, the page
 audit, parameter placement on a four-device mesh — it checks for real, and a
 failed check fails the rehearsal fixture.
 """
@@ -108,7 +108,7 @@ def test_server_phase_rehearsed(one_chip):
     assert len(tokens) >= 4 and len(set(tokens.values())) == 1
     assert int(f["server.prefix_hit_tokens"]) >= 32
     assert f["server.audit_pages"] == "0"
-    assert "tolerance" in f["server.logit_err"]
+    assert "tolerance" in f["server.token_gap"]
 
 
 def test_four_chip_option_runs_the_sharded_phase_alone(four_chips):

@@ -65,7 +65,10 @@ def build(held=(0, 16)):
 
 class Tap:
     """Keeps, for every request, the logits row each of its tokens was
-    picked from: admission picks from [1, V], a decode step from [B, V]."""
+    picked from: admission picks from [1, V], a decode step from [B, V].
+    Logits reach the host only where the batcher samples (a greedy one
+    fetches the ids its executables chose), so ``SERVER`` samples from the
+    one best row: the tokens greedy would serve."""
 
     def __init__(self, batcher):
         self.rows, self.last = {}, None
@@ -85,7 +88,8 @@ class Tap:
 
 
 SERVER = dict(max_batch=3, s_max=128, block_size=8, n_pages=48,
-              prefill_chunk=16, prefix_cache=True, compile=False)
+              prefill_chunk=16, prefix_cache=True, compile=False,
+              do_sample=True, top_k=1)
 
 
 def serve(model, prompts, news, batcher=None, **server):

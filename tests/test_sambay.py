@@ -57,7 +57,10 @@ def build(dtype="float32", seed=3):
 
 class Tap:
     """Keeps, for every request, the logits row each of its tokens was
-    picked from: admission picks from [1, V], a decode step from [B, V]."""
+    picked from: admission picks from [1, V], a decode step from [B, V].
+    Logits reach the host only where the batcher samples (a greedy one
+    fetches the ids its executables chose), so ``SERVER`` samples from the
+    one best row: the tokens greedy would serve."""
 
     def __init__(self, batcher):
         self.rows, self.last = {}, None
@@ -156,7 +159,7 @@ def test_published_sizes_count_3_85_billion_parameters():
 # -- the served path ----------------------------------------------------------
 
 SERVER = dict(max_batch=2, s_max=64, block_size=4, n_pages=32,
-              prefill_chunk=8, compile=True)
+              prefill_chunk=8, compile=True, do_sample=True, top_k=1)
 SCENARIOS = {
     # prompts of 19 and 5 (three chunks and one), answers that run past
     # the window of 8 so that every ring wraps, two more requests than
