@@ -270,8 +270,11 @@ def ring_attention(query, key, value, mesh=None, axis_name: str = "sep",
 def _cached_impl(jax_mesh, axis_name, causal, batch_axis, head_axis,
                  has_mask=False, has_seqlens=False):
     """Bounded cache (a jax Mesh is hashable); avoids re-closing over the
-    mesh per call without growing an unbounded registry."""
-    return functools.partial(_ring_attention_impl, jax_mesh=jax_mesh,
-                             axis_name=axis_name, causal=causal,
-                             batch_axis=batch_axis, head_axis=head_axis,
-                             has_mask=has_mask, has_seqlens=has_seqlens)
+    mesh per call without growing an unbounded registry. Jitted here, so
+    that an eager call compiles the ring once per shape: an eager
+    shard_map runs its body primitive by primitive, each one a program
+    of its own over the whole mesh, at every call and again under vjp."""
+    return jax.jit(functools.partial(
+        _ring_attention_impl, jax_mesh=jax_mesh, axis_name=axis_name,
+        causal=causal, batch_axis=batch_axis, head_axis=head_axis,
+        has_mask=has_mask, has_seqlens=has_seqlens))

@@ -60,6 +60,12 @@ else:
     from paddle_tpu.perf.compile_cache import enable_persistent_cache
     enable_persistent_cache()
 
+import collections
+import contextlib
+import signal
+import threading
+import traceback
+
 import numpy as np
 import pytest
 
@@ -70,6 +76,76 @@ def _seed():
     paddle.seed(1234)
     np.random.seed(1234)
     yield
+
+
+# A hang or a new ten-minute case costs its own PR one failed test, not
+# every PR the run: the driver's clock (1,470 s) cuts the whole suite.
+CASE_LIMIT_S = 180.0
+
+
+@contextlib.contextmanager
+def case_limit(name, seconds=CASE_LIMIT_S):
+    """Fail the case ``name``, with the stack it was in, once its body
+    has run ``seconds``. SIGALRM reaches only the main thread, and only
+    between bytecodes: a call stuck inside native code fails on return."""
+    if not hasattr(signal, "setitimer") \
+            or threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def on_alarm(signum, frame):
+        pytest.fail(f"{name} ran past its limit of {seconds:g} s "
+                    f"(tests/conftest.py CASE_LIMIT_S), in:\n"
+                    + "".join(traceback.format_stack(frame)),
+                    pytrace=False)
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
+@pytest.fixture(autouse=True)
+def _case_limit(request):
+    with case_limit(request.node.nodeid):
+        yield
+
+
+def suite_summary(reports, top=5):
+    """The run's critical path from its reports (``nodeid``, ``duration``;
+    a case's set-up, call and tear-down are summed, as junit does): total
+    test-seconds, the ``top`` longest files, the longest case. Under
+    ``--dist loadfile`` a run cannot end before its longest file does."""
+    cases = collections.defaultdict(float)
+    for rep in reports:
+        cases[rep.nodeid] += rep.duration
+    if not cases:
+        return []
+    files = collections.defaultdict(lambda: [0.0, 0])
+    for nodeid, secs in cases.items():
+        entry = files[nodeid.split("::")[0]]
+        entry[0] += secs
+        entry[1] += 1
+    longest = sorted(files.items(), key=lambda kv: -kv[1][0])[:top]
+    name, secs = max(cases.items(), key=lambda kv: kv[1])
+    return [f"test-seconds {sum(cases.values()):.0f} in {len(cases)} cases "
+            f"of {len(files)} files",
+            "longest files (s / cases): " + ", ".join(
+                f"{f} {t:.0f} / {n}" for f, (t, n) in longest),
+            f"longest case: {name} {secs:.0f} s"]
+
+
+def pytest_terminal_summary(terminalreporter, config):
+    if hasattr(config, "workerinput"):      # an xdist worker: not its job
+        return
+    reports = [rep for reps in terminalreporter.stats.values()
+               for rep in reps
+               if hasattr(rep, "nodeid") and hasattr(rep, "duration")]
+    for line in suite_summary(reports):
+        terminalreporter.write_line(line)
 
 
 # -- quick tier (VERDICT weak #8): one representative fast test per subsystem

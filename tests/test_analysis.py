@@ -9,7 +9,7 @@ plus the pass-registry integration (diagnostic passes via apply_pass),
 the observability findings counters, the suppression/baseline machinery,
 and the tier-1 lint gate (``pytest -m lint``) that runs tools/tpu_lint.py
 over the shipped tree (paddle_tpu/, examples/, tools/, benchmarks/) AND
-the tools/shard_check.py PLAN_7B gate with a combined <10s runtime guard.
+the tools/shard_check.py PLAN_7B gate.
 """
 import json
 import os
@@ -776,23 +776,20 @@ def _run_shard_cli(*args, cwd=REPO):
 @pytest.mark.lint
 @pytest.mark.quick
 def test_lint_gate_shipped_tree_is_clean_and_fast():
-    t0 = time.monotonic()
     proc = _run_cli("paddle_tpu", "examples", "tools", "benchmarks")
-    elapsed = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    # runtime guard: the gate must never threaten the tier-1 timeout
-    assert elapsed < 10.0, f"lint gate took {elapsed:.1f}s"
+    # the gate ran to its summary over the four trees (how long a case
+    # may take is conftest's CASE_LIMIT_S, the same for every case)
+    assert "finding(s): 0 error(s)" in proc.stdout, proc.stdout
 
 
 @pytest.mark.lint
 @pytest.mark.quick
 def test_shard_check_gate_shipped_plan_is_clean_and_fast():
-    t0 = time.monotonic()
     proc = _run_shard_cli()
-    elapsed = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "s3_full" in proc.stdout
-    assert elapsed < 10.0, f"shard_check gate took {elapsed:.1f}s"
+    assert "finding(s): 0 error(s)" in proc.stdout, proc.stdout
 
 
 @pytest.mark.lint
@@ -803,12 +800,10 @@ def test_trace_analyze_gate_demo_workload_attributes_cleanly():
     waterfalls, a balanced goodput ledger, and no findings parse
     errors — the smoke gate for the observability.{waterfall,ledger,
     anomaly} stack."""
-    t0 = time.monotonic()
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "trace_analyze.py"),
          "--json", "--top", "3"], cwd=REPO, capture_output=True,
         text=True)
-    elapsed = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
     payload = json.loads(proc.stdout)
     assert payload["n_traces"] >= 3 and payload["incomplete"] == 0
@@ -820,9 +815,6 @@ def test_trace_analyze_gate_demo_workload_attributes_cleanly():
         "speculation_rejected", "recompile", "dequant"}
     assert {"prefill", "decode"} <= set(led["by_phase"])
     assert {"prefill", "decode"} <= set(payload["critical_path_summary"])
-    # in-process demo + analysis; generous vs the 10s lint budget
-    # because this one boots jax AND runs serving traffic
-    assert elapsed < 30.0, f"trace_analyze gate took {elapsed:.1f}s"
 
 
 @pytest.mark.lint
@@ -831,16 +823,13 @@ def test_ckpt_inspect_gate_selftest_is_clean_and_fast():
     """tools/ckpt_inspect.py rides the lint lane: its --selftest builds
     a synthetic checkpoint root (one sound step, one torn step, then a
     corrupted payload) with hand-crafted npy bytes and asserts its own
-    verdicts — stdlib only, no jax import, so it stays within the 10s
-    lint budget."""
-    t0 = time.monotonic()
+    verdicts — stdlib only: it must run where jax cannot be imported."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "ckpt_inspect.py"),
+        [sys.executable, "-S",      # -S: no site-packages to import
+         os.path.join(REPO, "tools", "ckpt_inspect.py"),
          "--selftest"], cwd=REPO, capture_output=True, text=True)
-    elapsed = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "selftest" in (proc.stdout + proc.stderr).lower()
-    assert elapsed < 10.0, f"ckpt_inspect selftest took {elapsed:.1f}s"
 
 
 @pytest.mark.lint
@@ -849,17 +838,14 @@ def test_session_inspect_gate_selftest_is_clean_and_fast():
     """tools/session_inspect.py rides the lint lane: its --selftest
     builds a synthetic session root (sound, torn-publish debris, token
     bit-rot under stale CRCs, chain-hash drift under a re-sealed
-    document CRC) and asserts every verdict — stdlib only, no
-    numpy/jax import, so it stays within the 10s lint budget."""
-    t0 = time.monotonic()
+    document CRC) and asserts every verdict — stdlib only: it must run
+    where neither numpy nor jax can be imported."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools",
-                                      "session_inspect.py"),
+        [sys.executable, "-S",      # -S: no site-packages to import
+         os.path.join(REPO, "tools", "session_inspect.py"),
          "--selftest"], cwd=REPO, capture_output=True, text=True)
-    elapsed = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "selftest" in (proc.stdout + proc.stderr).lower()
-    assert elapsed < 10.0, f"session_inspect selftest took {elapsed:.1f}s"
 
 
 def test_shard_check_cli_flags_oversubscribed_batch():
@@ -1260,25 +1246,23 @@ def test_witness_off_overhead_under_one_percent(monkeypatch):
 
     raw, traced = threading.Lock(), locks.TracedLock("serve.step")
     drive(raw), drive(traced)                      # warm both paths
-    t_raw = min(drive(raw) for _ in range(3))
-    t_traced = min(drive(traced) for _ in range(3))
-    # same type -> same cost; 25% headroom swallows scheduler noise in
-    # a shared CI box while still catching any accidental wrapper
-    assert t_traced < t_raw * 1.25, (t_raw, t_traced)
+    # same type -> same cost. Each ratio is of two loops run back to
+    # back, so a busy machine slows both sides of it; the median of nine
+    # drops the pairs a preemption split. 25% headroom still catches any
+    # accidental wrapper (a Python-level __enter__ costs 3x and more)
+    ratios = sorted(drive(traced) / drive(raw) for _ in range(9))
+    assert ratios[4] < 1.25, ratios
 
 
 @pytest.mark.lint
 @pytest.mark.quick
 def test_race_check_gate_shipped_tree_is_clean_and_fast():
-    t0 = time.monotonic()
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "race_check.py"),
          "paddle_tpu", "tools", "benchmarks"],
         cwd=REPO, capture_output=True, text=True)
-    elapsed = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    # runtime guard: the gate must never threaten the tier-1 timeout
-    assert elapsed < 10.0, f"race_check gate took {elapsed:.1f}s"
+    assert "finding(s): 0 error(s)" in proc.stdout, proc.stdout
 
 
 def test_race_check_cli_flags_cycle_and_respects_baseline(tmp_path):

@@ -41,6 +41,8 @@ from paddle_tpu.observability import (AnomalyDetector, GatewayProbe,
 from paddle_tpu.observability.export import snapshot_series
 from paddle_tpu.resilience import arm_scenario, disarm
 
+from drill_clock import DrillClock
+
 pytestmark = pytest.mark.attr
 
 
@@ -262,17 +264,21 @@ def test_shared_prefix_goodput_and_prefill_shrink_cache_on_vs_off(lm):
 
 # -- failover: waste pricing + anomaly naming the survivor --------------------
 
-def test_failover_prices_requeue_waste_and_anomaly_names_survivor(lm):
+def test_failover_prices_requeue_waste_and_anomaly_names_survivor(
+        lm, monkeypatch):
     """The replica-death drill, read back through the attribution plane:
     total charged chip-seconds balance the span record within 1%, the
     survivor's duplicated re-prefill is priced as
     ``waste.requeue_recompute``, and the ONLINE detector (GatewayProbe)
     emits a tpot_spike finding naming the survivor — whose step time
-    jumps when it absorbs the dead replica's re-prefills."""
+    jumps when it absorbs the dead replica's re-prefills (on
+    tests/drill_clock.py's clock, where a step costs the rows it
+    computes; the spans keep the wall clock)."""
     prompts = _prompts(6, (5, 9, 7, 11))
     gw = Gateway(policy="least_loaded")
     gw.add_replica("r0", _batcher(lm))
     gw.add_replica("r1", _batcher(lm))
+    DrillClock().install(monkeypatch, gw)
     probe = GatewayProbe(gw, AnomalyDetector(threshold=4.0,
                                              min_samples=6))
     pre = _trace_mark()

@@ -13,6 +13,8 @@ import paddle_tpu as paddle
 from paddle_tpu.inference.serving import ContinuousBatcher
 from paddle_tpu.models.gpt import GPT2Config, GPT2ForCausalLM
 
+from greedy_ref import greedy_ref
+
 
 def _model():
     paddle.seed(0)
@@ -24,10 +26,7 @@ def _model():
     return m
 
 
-def _ref(m, prompt, n):
-    ids = paddle.to_tensor(np.asarray(prompt, np.int64)[None, :])
-    with paddle.no_grad():
-        return m.generate(ids, max_new_tokens=n).numpy()[0]
+_ref = greedy_ref
 
 
 def test_batched_requests_match_single_generate():
@@ -188,6 +187,4 @@ def test_batcher_serves_llama():
         rids = [b.submit(p, 5) for p in prompts]
         outs = b.run_until_done()
         for rid, p in zip(rids, prompts):
-            ids = paddle.to_tensor(np.asarray(p, np.int64)[None, :])
-            ref = m.generate(ids, max_new_tokens=5).numpy()[0]
-            np.testing.assert_array_equal(outs[rid], ref)
+            np.testing.assert_array_equal(outs[rid], _ref(m, p, 5))

@@ -27,6 +27,8 @@ from paddle_tpu.inference.serving import ContinuousBatcher
 from paddle_tpu.resilience import (DeadlineExceeded, Overloaded,
                                    arm_scenario, disarm)
 
+from greedy_ref import greedy_ref
+
 pytestmark = pytest.mark.gateway
 
 
@@ -54,9 +56,7 @@ def _prompts(seed, sizes):
     return [rng.randint(0, 128, size=n).astype(np.int64) for n in sizes]
 
 
-def _ref(lm, prompt, n):
-    return np.asarray(lm.generate(prompt.reshape(1, -1),
-                                  max_new_tokens=n)).reshape(-1)
+_ref = greedy_ref
 
 
 def _batcher(lm, **kw):

@@ -31,6 +31,8 @@ from paddle_tpu.inference.prefix_cache import (DiskTier, HostTier,
 from paddle_tpu.inference.serving import PagedContinuousBatcher
 from paddle_tpu.resilience import arm_scenario, disarm
 
+from greedy_ref import greedy_ref
+
 pytestmark = pytest.mark.kvtier
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -55,9 +57,7 @@ def lm():
     return m
 
 
-def _ref(lm, prompt, n):
-    return np.asarray(lm.generate(prompt.reshape(1, -1),
-                                  max_new_tokens=n)).reshape(-1)
+_ref = greedy_ref
 
 
 def _churn_prompts(seed, n_prefixes=6, n_requests=14, prefix_len=48,

@@ -231,16 +231,23 @@ def test_exporter_overhead_under_one_percent():
     counter-inc loop — exporting may never be the hot path."""
     reg = MetricsRegistry()
     c = reg.counter("obs_overhead_c", "x")
-    n = 100_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        c.inc()
-    loop = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    render_prometheus(registry=reg)
-    render = time.perf_counter() - t0
-    assert render < 0.01 * loop, (
-        f"render {render * 1e6:.0f}us vs loop {loop * 1e6:.0f}us")
+
+    def ratio(n=100_000, renders=20):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            c.inc()
+        t1 = time.perf_counter()
+        for _ in range(renders):
+            render_prometheus(registry=reg)
+        t2 = time.perf_counter()
+        return (t2 - t1) / renders / (t1 - t0)
+
+    # loop and renders are timed back to back, so a busy machine slows
+    # both. One render is 10 to 100 us, less than a time slice and, under
+    # five other workers, mostly its cache misses: a render is the mean of
+    # twenty, and the ratio the median of seven
+    ratios = sorted(ratio() for _ in range(7))
+    assert ratios[3] < 0.01, ratios
 
 
 # ---------------------------------------------------------------------------

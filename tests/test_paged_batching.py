@@ -26,6 +26,8 @@ import paddle_tpu as paddle
 from paddle_tpu.inference.serving import PagedContinuousBatcher
 from paddle_tpu.models.gpt import GPT2Config, GPT2ForCausalLM
 
+from greedy_ref import greedy_ref
+
 
 def _model(vocab_size=128):
     paddle.seed(0)
@@ -38,10 +40,7 @@ def _model(vocab_size=128):
     return m
 
 
-def _ref(m, prompt, n):
-    ids = paddle.to_tensor(np.asarray(prompt, np.int64)[None, :])
-    with paddle.no_grad():
-        return m.generate(ids, max_new_tokens=n).numpy()[0]
+_ref = greedy_ref
 
 
 # session-wide retry accounting: one or two load flips across a whole
@@ -398,86 +397,6 @@ def test_decode_attention_path_is_the_models_word(monkeypatch):
     assert after["gather"] == before["gather"]
 
 
-# -- chunked prefill (one executable for every prompt length) --------------
-
-def test_chunked_prefill_token_exact_mixed_lengths():
-    """Fixed-width append chunks reproduce the one-shot prefill exactly
-    for prompts shorter, equal, and longer than the chunk — including a
-    zero-padded tail chunk — for both families."""
-    for mk in (_model, _llama):
-        m = mk()
-        rng = np.random.RandomState(7)
-        prompts = [rng.randint(0, 128, (s,)) for s in (3, 8, 13, 17)]
-        b = PagedContinuousBatcher(m, max_batch=4, s_max=40, block_size=8,
-                                   prefill_chunk=8, compile=False)
-        rids = [b.submit(p, 6) for p in prompts]
-        outs = b.run_until_done()
-        for rid, p in zip(rids, prompts):
-            np.testing.assert_array_equal(outs[rid], _ref(m, p, 6),
-                                          err_msg=f"{mk.__name__} {rid}")
-        assert b.free_page_count == b.n_pages
-
-
-def test_chunked_prefill_single_executable():
-    """The point of chunking: serving many distinct prompt lengths
-    compiles exactly ONE prefill executable (vs one per length on the
-    unchunked path)."""
-    m = _model()
-    rng = np.random.RandomState(8)
-    prompts = [rng.randint(0, 128, (s,)) for s in (3, 7, 9, 14)]
-
-    def body():
-        b = PagedContinuousBatcher(m, max_batch=4, s_max=40, block_size=8,
-                                   prefill_chunk=8, compile=True)
-        rids = [b.submit(p, 4) for p in prompts[:2]]
-        b.step()
-        # two prompts join while the first two decode
-        rids += [b.submit(p, 4) for p in prompts[2:]]
-        outs = b.run_until_done()
-        assert len(b._chunk_fn._cache) == 1, \
-            list(b._chunk_fn._cache)      # one signature ever
-        assert len(b._step_fn._cache) == 1, list(b._step_fn._cache)
-        for rid, p in zip(rids, prompts):
-            np.testing.assert_array_equal(outs[rid], _ref(m, p, 4))
-
-    _retry_load_flake(body)
-
-
-def test_chunked_prefill_with_preemption():
-    """Chunked admission composes with on-demand growth + preemption
-    (resume re-prefills prompt+generated through the chunk path)."""
-    m = _model()
-    rng = np.random.RandomState(9)
-    p0 = rng.randint(0, 128, (6,))
-    p1 = rng.randint(0, 128, (6,))
-    b = PagedContinuousBatcher(m, max_batch=2, s_max=24, block_size=4,
-                               n_pages=6, policy="ondemand",
-                               prefill_chunk=4, compile=False)
-    r0, r1 = b.submit(p0, 10), b.submit(p1, 10)
-    outs = b.run_until_done()
-    assert b.stats()["preemptions"] >= 1
-    np.testing.assert_array_equal(outs[r0], _ref(m, p0, 10))
-    np.testing.assert_array_equal(outs[r1], _ref(m, p1, 10))
-    assert b.free_page_count == b.n_pages
-    assert b.audit_pages() == 0
-
-
-def test_chunked_prefill_tail_clamped_to_capacity():
-    """Chunk width not aligned to capacity: the tail chunk shortens
-    instead of overflowing the block table (review finding)."""
-    m = _model()
-    rng = np.random.RandomState(10)
-    # s_max=40, block_size=8 -> capacity 40; C=16: a 35-token prompt pads
-    # to 48 unclamped, which would index a 6th block in a 5-block table
-    p = rng.randint(0, 128, (35,))
-    b = PagedContinuousBatcher(m, max_batch=1, s_max=40, block_size=8,
-                               prefill_chunk=16, compile=False)
-    rid = b.submit(p, 5)
-    outs = b.run_until_done()
-    np.testing.assert_array_equal(outs[rid], _ref(m, p, 5))
-    assert b.free_page_count == b.n_pages
-
-
 # -- what the constructor refuses: one case a ``raise ValueError`` ---------
 
 def _calibrated():
@@ -568,146 +487,3 @@ def test_batcher_refuses(case):
 def test_batcher_refuses_has_a_case_a_guard():
     source = inspect.getsource(PagedContinuousBatcher.__init__)
     assert source.count("raise ValueError") == len(REFUSED)
-
-
-# -- the page-granular K/V writer's invariant ------------------------------
-
-def _kv_write_launches(engine="paged"):
-    from paddle_tpu.observability.metrics import get_registry
-    family = get_registry().get("serving_kv_write_launches_total")
-    return {w: family.labels(engine=engine, writer=w).value
-            for w in ("page", "row")}
-
-
-def _watch_written_pages(b):
-    """Wrap the batcher's decode launches: before each one, the page each
-    running slot is about to write a row of must be backed, pairwise
-    distinct, in no other slot's table and not the prefix cache's. Returns
-    the list the launches are logged to (the running slots of each)."""
-    seen = []
-
-    def check():
-        live = sorted(b._slot_req)
-        cached = set(b.prefix_cache.pages()) if b.prefix_cache else set()
-        for slot in live:
-            page = int(b._bt[slot, int(b._dec[slot]) // b.block_size])
-            assert page != b._scratch, f"slot {slot} writes no backed page"
-            assert page not in cached, (slot, page)
-            for other in range(b.max_batch):
-                if other != slot:
-                    assert page not in b._bt[other], (slot, other, page)
-        # parked slots name nothing but scratch
-        for slot in set(range(b.max_batch)) - set(live):
-            assert set(int(p) for p in b._bt[slot]) == {b._scratch}
-        seen.append(len(live))
-
-    step_fn = b._step_fn
-
-    def launch(tok, state):
-        check()
-        return step_fn(tok, state)
-
-    b._step_fn = launch
-    return seen
-
-
-def _row_scatter_route(monkeypatch):
-    """Put the Llama family back on the row scatter, decode step and chunk:
-    what the page writers' tokens are held against."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.incubate.nn.functional import decode_attention as da
-
-    def row_run(pool, table, line, dec, run):
-        rows = dec + jnp.arange(run.shape[0])
-        block = pool.shape[2]
-        pool = pool.at[table[rows // block], :, rows % block].set(
-            run.astype(pool.dtype))
-        return pool, da._gather_paged(pool, pool, table[None],
-                                      pool.shape[1])[0][0]
-    monkeypatch.setattr(da, "decode_kv_writer", lambda dtype: "row")
-    monkeypatch.setattr(da, "_write_page_run", row_run)
-
-
-# documents of whole and part pages, each asked several times with another
-# question behind it; block_size 4
-def _document_sessions(rng, n_docs=2, asks=3):
-    docs = [rng.randint(0, 128, (n,)) for n in (16, 22)[:n_docs]]
-    return [np.concatenate([docs[i % n_docs], rng.randint(0, 128, (q,))])
-            for i, q in enumerate(rng.randint(1, 6, (n_docs * asks,)))]
-
-
-@pytest.mark.parametrize("options", [
-    dict(), dict(policy="ondemand", n_pages=13), dict(prefill_chunk=8)],
-    ids=["reserve", "ondemand_preempting", "chunked"])
-def test_no_two_sequences_write_one_page(options, monkeypatch):
-    """The page writer's invariant, held under the prefix cache: documents
-    asked several times share their FULL pages, and at every decode launch
-    the pages the running slots write are pairwise distinct, in nobody
-    else's table and not the cache's. Tokens equal the row-scatter route's
-    and the solo reference's; ``audit_pages()`` is clean; every launch is
-    counted under ``writer="page"``."""
-    m = _llama()
-    prompts = _document_sessions(np.random.RandomState(11))
-    budgets = [7, 5, 9, 6, 8, 5]
-    kw = dict(max_batch=3, s_max=48, block_size=4, compile=False,
-              prefix_cache=True, **options)
-
-    def serve(watch):
-        b = PagedContinuousBatcher(m, **kw)
-        seen = _watch_written_pages(b) if watch else None
-        before = _kv_write_launches()
-        rids = [b.submit(p, n) for p, n in zip(prompts, budgets)]
-        outs = b.run_until_done()
-        assert b.audit_pages() == 0
-        s = dict(b.stats(), hit_tokens=b.prefix_cache.hit_tokens)
-        counted = {w: n - before[w]
-                   for w, n in _kv_write_launches().items()}
-        b.close()
-        return [outs[r] for r in rids], s, counted, seen
-
-    got, s, counted, seen = serve(watch=True)
-    assert s["kv_writer"] == "page" and counted["row"] == 0
-    assert counted["page"] == len(seen) > 0
-    assert s["hit_tokens"] > 0, "no page was shared"
-    assert max(seen) > 1, "no two sequences ever ran together"
-    assert counted["page"] == s["steps"]
-    if options.get("policy") == "ondemand":
-        assert s["preemptions"] > 0, "the pool never ran dry"
-    for p, n, out in zip(prompts, budgets, got):
-        ids = paddle.to_tensor(np.asarray(p, np.int64)[None, :])
-        with paddle.no_grad():
-            np.testing.assert_array_equal(
-                out, m.generate(ids, max_new_tokens=n).numpy()[0])
-
-    _row_scatter_route(monkeypatch)
-    want, s, counted, _ = serve(watch=False)
-    assert s["kv_writer"] == "row" and counted["page"] == 0
-    assert counted["row"] > 0
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(g, w)
-
-
-def test_an_int8_pool_keeps_the_row_scatter():
-    """``cache_quant`` allocates int8 pools: rows are quantized on the way
-    in by the general op, and the launches are counted under ``row``."""
-    m = _llama()
-    rng = np.random.RandomState(12)
-    b = PagedContinuousBatcher(m, max_batch=2, s_max=32, block_size=8,
-                               compile=False, cache_quant="dynamic_int8")
-    assert b.stats()["kv_writer"] == "row"
-    before = _kv_write_launches()
-    for _ in range(2):
-        b.submit(rng.randint(0, 128, (5,)), 6)
-    b.run_until_done()
-    after = _kv_write_launches()
-    assert after["row"] - before["row"] == b.stats()["steps"] > 0
-    assert after["page"] == before["page"]
-
-
-def test_kv_writer_of_a_family_without_the_word_is_row():
-    """GPT-2's paged step (``block_multihead_attention``) scatters rows and
-    says nothing: the batcher's default."""
-    b = PagedContinuousBatcher(_model(), max_batch=2, s_max=32,
-                               block_size=8, compile=False)
-    assert b.stats()["kv_writer"] == "row"

@@ -10,7 +10,7 @@ for bit on every page but scratch (the pool's last page, which parked slots
 and unbacked table entries name and nothing reads). That the page writers
 leave the pool's layout alone on the chip is tests/test_chip_compile.py's
 question; that the batcher never has two sequences write one page is
-tests/test_paged_batching.py's.
+tests/test_paged_page_invariant.py's.
 """
 import jax.numpy as jnp
 import numpy as np

@@ -129,27 +129,6 @@ def test_flash_forward_matches_dense(case, causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("case", list(CASES))
-def test_flash_grads_match_dense(case, causal):
-    q, k, v, mask, lens, valid, kw = _case(case, 3)
-    w = _rand(q.shape, 9) * valid       # a cotangent with no structure
-
-    def f(q, k, v):
-        return (flash_attention_pallas(q, k, v, causal=causal, **kw
-                                       ).astype(jnp.float32) * w).sum()
-
-    def g(q, k, v):
-        return (_dense(q, k, v, causal, mask=mask, seqlens=lens,
-                       neg=-1e30) * w).sum()
-
-    got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    ref = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(got, ref):
-        assert a.dtype == q.dtype
-        _assert_close(a, b_, q.dtype, dict(rtol=1e-4, atol=1e-5))
-
-
-@pytest.mark.parametrize("causal", [False, True])
 def test_flash_gqa_native(causal):
     """K/V stay at kv-head count; the kernel's index map expands the group."""
     b, s, hq, hkv, d = 2, 256, 4, 2, 32
@@ -159,25 +138,6 @@ def test_flash_gqa_native(causal):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_dense(q, k, v, causal)),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_flash_gqa_grads():
-    b, s, hq, hkv, d = 1, 256, 4, 2, 16
-    q = _rand((b, s, hq, d), 9)
-    k, v = _rand((b, s, hkv, d), 10), _rand((b, s, hkv, d), 11)
-
-    def f(q, k, v):
-        return flash_attention_pallas(q, k, v, causal=True,
-                                      interpret=True).sum()
-
-    def g(q, k, v):
-        return _dense(q, k, v, True).sum()
-
-    got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    ref = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(got, ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=1e-4, atol=1e-5)
 
 
 def test_flash_additive_mask():
@@ -315,43 +275,6 @@ def test_flash_dropout():
     np.testing.assert_array_equal(np.asarray(g), np.asarray(g2))
 
 
-@pytest.mark.parametrize("blocks", [None, (256, 128)],
-                         ids=["derived", "256x128"])
-def test_flash_dropout_backward_redraws_the_forwards_mask(blocks):
-    """The mask is drawn per score tile from the tile's coordinates, so the
-    three kernels must agree on the score tile: under dropout
-    ``_resolve_blocks`` hands all three ONE tiling (the shape-derived one
-    that fits the hungriest of them, or the caller's). With the seed fixed
-    the masked function is smooth, so a central difference along each
-    gradient must give its norm; a backward that drew another mask returns
-    a vector the function does not rise along."""
-    from paddle_tpu.ops.pallas.flash_attention import _resolve_blocks
-    b, s, h, d = 1, 1024, 1, 64
-    q, k, v = _rand((b, s, h, d), 33), _rand((b, s, h, d), 34), \
-        _rand((b, s, h, d), 35)
-    bq, bk = blocks or (None, None)
-    tiles = _resolve_blocks(q, k, v, True, None, 0.3, bq, bk, True)
-    assert len(set(tiles)) == 1
-    assert s // tiles.fwd.sub_q > 1 or s // tiles.fwd.sub_k > 1
-    w = _rand((b, s, h, d), 36)
-
-    def f(*qkv):
-        return (flash_attention_pallas(
-            *qkv, causal=True, dropout_p=0.3, seed=7, block_q=bq,
-            block_k=bk, interpret=True) * w).sum()
-
-    grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    for i, g in enumerate(grads):
-        # along the gradient itself the slope is its norm, if it is the
-        # gradient of what the forward computed
-        norm = float(jnp.linalg.norm(g.ravel()))
-        u = g / norm
-        hi, lo = [q, k, v], [q, k, v]
-        hi[i], lo[i] = hi[i] + 0.1 * u, lo[i] - 0.1 * u
-        fd = float(f(*hi) - f(*lo)) / 0.2
-        assert abs(fd - norm) <= 0.02 * norm, (i, fd, norm)
-
-
 def test_block_sparse_attention_matches_dense_masked():
     """Active tiles only: output must equal dense attention under the
     expanded block mask (ref sparse_attention semantics at tile granularity)."""
@@ -422,3 +345,59 @@ def test_block_sparse_empty_row_zero_output():
         q, k, v_, bm, interpret=True).sum())(v)
     # masked rows contribute nothing to dv's second half either
     np.testing.assert_allclose(np.asarray(g)[:, 128:], 0.0, atol=1e-6)
+
+
+def test_flash_gqa_grads():
+    b, s, hq, hkv, d = 1, 256, 4, 2, 16
+    q = _rand((b, s, hq, d), 9)
+    k, v = _rand((b, s, hkv, d), 10), _rand((b, s, hkv, d), 11)
+
+    def f(q, k, v):
+        return flash_attention_pallas(q, k, v, causal=True,
+                                      interpret=True).sum()
+
+    def g(q, k, v):
+        return _dense(q, k, v, True).sum()
+
+    got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("blocks", [None, (256, 128)],
+                         ids=["derived", "256x128"])
+def test_flash_dropout_backward_redraws_the_forwards_mask(blocks):
+    """The mask is drawn per score tile from the tile's coordinates, so the
+    three kernels must agree on the score tile: under dropout
+    ``_resolve_blocks`` hands all three ONE tiling (the shape-derived one
+    that fits the hungriest of them, or the caller's). With the seed fixed
+    the masked function is smooth, so a central difference along each
+    gradient must give its norm; a backward that drew another mask returns
+    a vector the function does not rise along."""
+    from paddle_tpu.ops.pallas.flash_attention import _resolve_blocks
+    b, s, h, d = 1, 1024, 1, 64
+    q, k, v = _rand((b, s, h, d), 33), _rand((b, s, h, d), 34), \
+        _rand((b, s, h, d), 35)
+    bq, bk = blocks or (None, None)
+    tiles = _resolve_blocks(q, k, v, True, None, 0.3, bq, bk, True)
+    assert len(set(tiles)) == 1
+    assert s // tiles.fwd.sub_q > 1 or s // tiles.fwd.sub_k > 1
+    w = _rand((b, s, h, d), 36)
+
+    def f(*qkv):
+        return (flash_attention_pallas(
+            *qkv, causal=True, dropout_p=0.3, seed=7, block_q=bq,
+            block_k=bk, interpret=True) * w).sum()
+
+    grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    for i, g in enumerate(grads):
+        # along the gradient itself the slope is its norm, if it is the
+        # gradient of what the forward computed
+        norm = float(jnp.linalg.norm(g.ravel()))
+        u = g / norm
+        hi, lo = [q, k, v], [q, k, v]
+        hi[i], lo[i] = hi[i] + 0.1 * u, lo[i] - 0.1 * u
+        fd = float(f(*hi) - f(*lo)) / 0.2
+        assert abs(fd - norm) <= 0.02 * norm, (i, fd, norm)

@@ -5,7 +5,7 @@ Three layers, same exactness bar as tests/test_paged_batching.py:
   * pure-host radix-tree units — insert/match/evict/refcount under
     pressure, chain-hash summaries (no model, sub-second);
   * paged-batcher integration — shared-prefix admissions must be
-    token-exact vs solo ``generate_paged`` with the cache hitting,
+    token-exact vs the solo greedy reference with the cache hitting,
     pages audited (``serving.pages_leaked`` stays 0) through eviction
     pressure and preemption;
   * speculative decoding — ``draft_model=`` output must equal
@@ -19,6 +19,8 @@ import paddle_tpu as paddle
 from paddle_tpu.inference.prefix_cache import RadixPrefixCache, chain_hashes
 from paddle_tpu.inference.serving import PagedContinuousBatcher
 from paddle_tpu.models.gpt import GPT2Config, GPT2ForCausalLM
+
+from greedy_ref import greedy_ref
 
 pytestmark = pytest.mark.perf
 
@@ -119,13 +121,7 @@ def _model(seed=0):
 
 
 def _refs(m, prompts, n):
-    out = []
-    with paddle.no_grad():
-        for p in prompts:
-            r = m.generate_paged(paddle.to_tensor(
-                np.asarray(p, np.int64)[None, :]), n, block_size=16)
-            out.append(np.asarray(r._data)[0])
-    return out
+    return [greedy_ref(m, p, n) for p in prompts]
 
 
 def _shared_prompts(seed, n, shared_len=40):

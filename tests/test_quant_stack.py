@@ -50,6 +50,8 @@ from paddle_tpu.quantization import (PTQ, AbsmaxObserver,
                                      QuantedConv2D, QuantedLinear,
                                      serving_quantize)
 
+from greedy_ref import greedy_ref
+
 pytestmark = pytest.mark.quant
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -98,9 +100,7 @@ def _no_stale_calibration(sharp_lm):
     sharp_lm.calibrate_cachekv_int8(None)
 
 
-def _ref(lm, prompt, n):
-    return np.asarray(lm.generate(np.asarray(prompt).reshape(1, -1),
-                                  max_new_tokens=n)).reshape(-1)
+_ref = greedy_ref
 
 
 def _counter(name):

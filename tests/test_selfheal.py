@@ -18,8 +18,9 @@ The acceptance bars:
     under queue pressure, scale-down drains (not kills) its own
     addition once idle.
 
-Everything is single-threaded and deterministic; chaos delays are the
-only wall-clock dependence.
+Everything is single-threaded and deterministic, time included: the two
+drills run on tests/drill_clock.py's clock, where a step costs what it
+computes and a chaos delay its ``delay_s``.
 """
 import os
 import sys
@@ -41,13 +42,18 @@ from paddle_tpu.resilience.remediator import (AutoRemediator, FlapGuard,
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 import traffic  # noqa: E402
+from drill_clock import DrillClock  # noqa: E402
 
 pytestmark = pytest.mark.selfheal
 
-# separation: honest prefill-heavy steps run 2-4x the decode-step
-# median (robust z up to ~10 on these tiny models), so the detector
-# threshold sits above that and the injected delay far above it; the
-# TTFT SLO is one honest traffic meets and the straggler breaks
+# separation, in DrillClock's seconds: an honest step that admits k
+# requests costs 1 + k dispatches, a robust z of 20 k against a baseline
+# of decode-only steps (the detector floors its scale at 5% of the
+# median), and a step admits at most its 8 slots; the injected delay is
+# 40 dispatches, a z of 800. The detector threshold sits between. The
+# TTFT SLO is one honest traffic meets (a gateway step is 0.02 to 0.06 s)
+# and the straggler breaks
+DETECT_Z = 200.0
 TTFT_SLO_S = 0.15
 STRAGGLE_S = 0.4
 
@@ -99,25 +105,24 @@ def _spec(**kw):
     return traffic.TrafficSpec(**kw)
 
 
-def _rig(lm, policy):
-    """Gateway + probe/detector/remediator, baselines warmed on healthy
-    steps (chaos arms AFTER this returns)."""
+def _rig(lm, policy, monkeypatch):
+    """Gateway + probe/detector/remediator on the drill's clock,
+    baselines warmed on healthy steps (chaos arms AFTER this returns)."""
     make = _factory(lm)
     gw = Gateway(policy="least_loaded", max_queue_depth=128)
     gw.add_replica("r0", make("r0"))
     gw.add_replica("r1", make("r1"))
-    detector = AnomalyDetector(threshold=15.0, min_samples=8)
+    clock = DrillClock().install(monkeypatch, gw)
+    detector = AnomalyDetector(threshold=DETECT_Z, min_samples=8)
     probe = GatewayProbe(gw, detector)
     rem = AutoRemediator(gw, detector=detector, policy=policy,
-                         replica_factory=make,
+                         replica_factory=make, clock=clock.monotonic,
                          flap_guard=FlapGuard(max_actions=4,
-                                              window_s=30.0))
+                                              window_s=30.0,
+                                              clock=clock.monotonic))
     rng = np.random.RandomState(7)
-    # warm EVERY prompt rung the traffic will hit (pow2 buckets): a
-    # first-touch prefill compile mid-run would register as a huge step
-    # and fire a false per-replica spike. Loop until BOTH replicas'
-    # detector series are past warmup — routing does not split work
-    # evenly on small batches.
+    # Loop until BOTH replicas' detector series are past warmup —
+    # routing does not split work evenly on small batches.
     for _ in range(8):
         for n in (6, 10, 20, 28):
             gw.submit(rng.randint(0, 128, (n,)), 4, tenant="warmup")
@@ -136,10 +141,11 @@ DRAIN_POLICY = (PolicyRule("tpot_spike", "drain_replica", hysteresis=2,
 
 # -- the chaos drill ----------------------------------------------------------
 
-def test_straggler_drill_names_and_drains_the_right_replica(lm):
+def test_straggler_drill_names_and_drains_the_right_replica(
+        lm, monkeypatch):
     """One replica goes slow; the loop drains THAT replica and TTFT
     returns in-SLO within a bounded number of steps of the action."""
-    gw, rem, probe = _rig(lm, DRAIN_POLICY)
+    gw, rem, probe = _rig(lm, DRAIN_POLICY, monkeypatch)
     arm_scenario(f"seed=0; gateway.step.r1:delay:"
                  f"delay_s={STRAGGLE_S},after=1,count=10000")
     drain_step = []
@@ -178,9 +184,9 @@ def test_straggler_drill_names_and_drains_the_right_replica(lm):
         f"last breach at {res.last_breach_step}")
 
 
-def test_no_fault_control_run_takes_zero_actions(lm):
+def test_no_fault_control_run_takes_zero_actions(lm, monkeypatch):
     """The IDENTICAL schedule with no chaos: a quiet loop."""
-    gw, rem, probe = _rig(lm, DRAIN_POLICY)
+    gw, rem, probe = _rig(lm, DRAIN_POLICY, monkeypatch)
     try:
         res = traffic.drive(gw, traffic.generate(_spec()), TTFT_SLO_S,
                             tick=lambda s: rem.tick())

@@ -43,6 +43,8 @@ from paddle_tpu.inference.session_store import (SessionManifest,
                                                 model_identity)
 from paddle_tpu.resilience import arm_scenario, disarm
 
+from greedy_ref import greedy_ref
+
 pytestmark = pytest.mark.session
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -68,9 +70,7 @@ def lm():
     return m
 
 
-def _ref(lm, prompt, n):
-    return np.asarray(lm.generate(np.asarray(prompt).reshape(1, -1),
-                                  max_new_tokens=n)).reshape(-1)
+_ref = greedy_ref
 
 
 def _tiered(lm, tmp, host_blocks=2, disk_blocks=64, slots=3, chunk=2,

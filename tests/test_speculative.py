@@ -18,6 +18,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPT2Config, GPT2ForCausalLM
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 
+from greedy_ref import greedy_ref
 from test_paged_batching import _retry_load_flake
 
 
@@ -38,10 +39,7 @@ def _llama(seed):
     return m
 
 
-def _ref(m, prompt, n):
-    ids = paddle.to_tensor(np.asarray(prompt, np.int64)[None])
-    with paddle.no_grad():
-        return m.generate(ids, max_new_tokens=n).numpy()[0]
+_ref = greedy_ref
 
 
 def test_speculative_matches_greedy_any_draft():

@@ -4,6 +4,7 @@ Reference test model: test/legacy_test/test_vision_models.py —每个
 architecture gets a forward-shape check; parameter counts pin the
 architectures to their published sizes (weights can't be diffed offline).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -17,8 +18,39 @@ def _x(size=64):
         / 10)
 
 
-def _n_params(m):
-    return sum(int(np.prod(p.shape)) for p in m.parameters())
+def _abstract(build):
+    """``build()`` made under ``jax.eval_shape``: the module with its
+    layers, attributes and parameter shapes, and no initializer compiled or
+    run (a normal draw is a program per shape, a quarter of a second each).
+    For the cases that read an architecture and compute nothing."""
+    made = []
+    try:
+        jax.eval_shape(lambda: made.append(build()))
+    finally:
+        paddle.seed(1234)   # the global generator's key was split in the trace
+    return made[0]
+
+
+def _n_params(ctor):
+    return sum(int(np.prod(p.shape)) for p in _abstract(ctor).parameters())
+
+
+def _forward(build, x):
+    """``build()``'s forward of ``x`` in eval mode as one compiled program,
+    the building included. Run eagerly, every layer of a network is a
+    program of its own to compile (743 of them for densenet121, 37 s; 2 s
+    this way) and so is every initializer's draw, and these cases assert
+    on the shape the whole forward gives (no buffer is written)."""
+    def fwd(a):
+        m = build()
+        m.eval()
+        with paddle.no_grad():
+            out = m(paddle.Tensor(a))
+        return jax.tree_util.tree_map(lambda t: t._data, out)
+    try:
+        return jax.jit(fwd)(x._data)
+    finally:
+        paddle.seed(1234)   # the global generator's key was split in the trace
 
 
 class TestZooForward:
@@ -28,22 +60,19 @@ class TestZooForward:
         "shufflenet_v2_x1_0",
     ])
     def test_forward_shape(self, name):
-        m = getattr(M, name)(num_classes=4)
-        m.eval()
-        out = m(_x())
+        out = _forward(lambda: getattr(M, name)(num_classes=4), _x())
         assert list(out.shape) == [1, 4]
 
     def test_googlenet_aux_heads(self):
-        m = M.googlenet(num_classes=4)
-        m.eval()
-        out, aux1, aux2 = m(_x(96))
+        out, aux1, aux2 = _forward(lambda: M.googlenet(num_classes=4),
+                                   _x(96))
         assert list(out.shape) == [1, 4]
         assert list(aux1.shape) == [1, 4]
         assert list(aux2.shape) == [1, 4]
 
     def test_pretrained_raises_offline(self):
         with pytest.raises(Exception):
-            M.alexnet(pretrained=True)
+            _abstract(lambda: M.alexnet(pretrained=True))
 
 
 class TestZooArchitectures:
@@ -62,19 +91,19 @@ class TestZooArchitectures:
         (M.wide_resnet50_2, 68.88),
     ])
     def test_param_count(self, ctor, expected_m):
-        n = _n_params(ctor()) / 1e6
+        n = _n_params(ctor) / 1e6
         assert abs(n - expected_m) / expected_m < 0.03, \
             f"{ctor.__name__}: {n:.2f}M params, expected ~{expected_m}M"
 
     def test_resnext_grouped_conv(self):
-        m = M.resnext50_32x4d(num_classes=4)
+        m = _abstract(lambda: M.resnext50_32x4d(num_classes=4))
         # the 3x3 stage of the first bottleneck must be 32-grouped, width 128
         blk = m.layer1.blocks[0]
         assert blk.conv2.groups == 32
         assert blk.conv2.weight.shape[0] == 128
 
     def test_wide_resnet_width(self):
-        m = M.wide_resnet50_2(num_classes=4)
+        m = _abstract(lambda: M.wide_resnet50_2(num_classes=4))
         blk = m.layer1.blocks[0]
         assert blk.conv2.weight.shape[0] == 128  # 64 * (128/64) = 128
 
