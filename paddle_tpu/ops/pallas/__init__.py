@@ -4,9 +4,13 @@ Reference disposition (SURVEY.md N27): the reference dynloads a vendored
 flashattn library (third_party/flashattn, phi/backends/dynload/flashattn.cc)
 and carries 66k LoC of fused CUDA kernels (phi/kernels/fusion). Here the
 fused tier is a small set of Pallas TPU kernels behind availability gates —
-XLA's fusion covers the long tail, Pallas covers the blockwise-softmax
-attention family where XLA's dataflow fusion cannot restructure the
-computation.
+XLA's fusion covers the long tail, Pallas covers what XLA's dataflow fusion
+cannot restructure: ``flash_attention`` (blockwise softmax, forward and
+backward), ``block_sparse_attention``, ``fused_ops`` (rmsnorm, AdamW),
+``paged_attention`` (a decode step's query against the pages a sequence
+holds) and ``grouped_experts`` (a routed-expert layer's tiles, each through
+its own expert's weights read in place: one pipeline across the tiles where
+XLA has a ``while`` with a turn a tile).
 
 Every kernel has an XLA reference; `on_tpu()` picks between them from the
 backend JAX reports, so the same code runs on the CPU test mesh and on

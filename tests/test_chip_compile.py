@@ -486,17 +486,22 @@ def test_sambay_decoder_blocks_compile_with_the_kernel(chip, monkeypatch):
 
 
 @pytest.mark.parametrize("entry", ["decode", "chunk"])
-def test_glm_dsa_expert_block_compiles_at_published_widths(chip, entry):
+def test_glm_dsa_expert_block_compiles_at_published_widths(chip, monkeypatch,
+                                                           entry):
     """One expert block of ``models/glm_dsa.py`` at the cell
     ``glm5-longdoc-sessions``'s shapes (hidden 6144, 64 heads over one
     latent row of 512 + 64, 32 index heads of 128, 2,048 rows kept, 16 of
     256 experts held, 16 slots of 32,768 rows over 16,385 pages of 16): a
     decode step (every held row scored, rows gathered by token, absorbed
-    attention, the grouped product over the experts touched) and a chunk of
-    512 (held rows read 2,048 at a time). Both pools keep their place
+    attention, the grouped product over the experts touched: the Pallas
+    kernel, its blocks of ``f`` under the VMEM the call states) and a chunk
+    of 512 (held rows read 2,048 at a time). Both pools keep their place
     (donated, aliased), and what a block needs beside them stays well
     under a gigabyte."""
+    import paddle_tpu.ops.pallas as pallas_tier
     from paddle_tpu.models import glm_dsa
+
+    monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
     d, ql, heads, experts, f = 6144, 2048, 64, 16, 2048
     slots, block, s_max, pages = 16, 16, 32768, 16385
 
@@ -539,6 +544,7 @@ def test_glm_dsa_expert_block_compiles_at_published_widths(chip, entry):
                 sds((), jnp.int32), angles, angles).compile()
     memory = compiled.memory_analysis()
     pools = pages * block * (640 + 128) * 2
+    assert len(_kernel_calls(compiled, "grouped_experts")) == 1
     assert memory.alias_size_in_bytes >= pools
     assert memory.temp_size_in_bytes < 512 << 20
     text = compiled.as_text()
@@ -556,10 +562,11 @@ def test_mellum_blocks_compile_at_published_widths(chip, monkeypatch, entry,
     pages of 16 rows over tables of 2,048, the window group 6,145 pages
     behind rings of 99): a decode step (the row written by the page, PR
     26's kernel over the table or, with a first row, over the 65 pages the
-    window lies in, the grouped product over the experts touched) and a
-    chunk of 512 (a full layer reads held rows 1,024 at a time, a window
-    layer the 97 pages before and under it). The pools keep their place and
-    a block needs well under a gigabyte beside them."""
+    window lies in, the grouped product over the experts touched as one
+    Pallas kernel) and a chunk of 512 (a full layer reads held rows 1,024
+    at a time, a window layer the 97 pages before and under it). The pools
+    keep their place and a block needs well under a gigabyte beside
+    them."""
     import paddle_tpu.ops.pallas as pallas_tier
     from paddle_tpu.models import mellum
 
@@ -599,6 +606,9 @@ def test_mellum_blocks_compile_at_published_widths(chip, monkeypatch, entry,
                 w, sds((512, d)), pool, pool, sds((table,), jnp.int32),
                 sds((), jnp.int32), sds((), jnp.int32), angles,
                 angles).compile()
+    assert len(_kernel_calls(compiled, "grouped_experts")) == 1
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if " while(" in ln and "experts_routed" in ln]   # no walk
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * pages * kv * block * hd * 2
     assert memory.temp_size_in_bytes < 768 << 20

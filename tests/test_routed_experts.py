@@ -60,7 +60,7 @@ def test_nothing_is_dropped_when_every_token_picks_one_expert(rows):
 
 
 
-@pytest.mark.parametrize("rows,tile", [(1, 8), (8, 8), (9, 16), (16, 16),
+@pytest.mark.parametrize("rows,tile", [(1, 16), (8, 16), (9, 16), (16, 16),
                                        (100, 128), (512, 128)])
 def test_a_decode_step_is_whole_groups_and_a_chunk_mxu_tiles(rows, tile):
     assert E._tile_rows(rows) == tile
