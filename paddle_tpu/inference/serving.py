@@ -1043,6 +1043,7 @@ class PagedContinuousBatcher(_BatcherBase):
         self.kv_quant = kv_quant
         self.tier_quant = tier_quant
         self._slot_state = bool(contract.get("slot_state"))
+        self._position_axes = int(contract.get("position_axes", 0))
         self._init_page_groups(contract, group_pages, prefill_chunk)
         pool_pages = n_pages + 1                    # the scratch page too
         if self._primary_group:
@@ -1475,13 +1476,16 @@ class PagedContinuousBatcher(_BatcherBase):
         self._window_drop_c.inc(int((dec >= self._window_rows).sum())
                                 * self._window_rings)
 
-    def _slot_args(self, slot: int, n_valid: int) -> dict:
+    def _slot_args(self, slot: int, n_valid: int, first_row: int,
+                   rows: int) -> dict:
         """What ``paged_prefill_into`` takes besides: its slot where the
         model keeps per-slot state, how many of the chunk's rows are real
-        there and where the model counts what its steps did, and the
-        slot's row of every window group's table where it has page
-        groups."""
-        if not (self._slot_state or self._step_counts or self._groups):
+        there and where the model counts what its steps did, the slot's
+        row of every window group's table where it has page groups, and
+        the positions of the chunk's ``rows`` rows from ``first_row`` on
+        where the contract says positions have axes."""
+        if not (self._slot_state or self._step_counts or self._groups
+                or self._position_axes):
             return {}
         import paddle_tpu as paddle
         args = {"slot": paddle.to_tensor(np.array([slot], np.int32))} \
@@ -1489,7 +1493,18 @@ class PagedContinuousBatcher(_BatcherBase):
         args["n_valid"] = paddle.to_tensor(np.array([n_valid], np.int32))
         if self._groups:
             args["group_tables"] = self._group_tables(slot)
+        if self._position_axes:
+            args["position_ids"] = self._positions(
+                np.arange(first_row, first_row + rows, dtype=np.int32))
         return args
+
+    def _positions(self, rows: np.ndarray):
+        """``position_ids`` [axes, N] of rows that are text: a row's number
+        on every axis (the contract's ``position_axes``: a model whose
+        rotary position is more than one number takes it from here, chunk
+        and decode step alike, and not from where the row lies)."""
+        import paddle_tpu as paddle
+        return paddle.to_tensor(np.tile(rows, (self._position_axes, 1)))
 
     # -- what a model's steps chose -------------------------------------------
     _STEP_COUNTS = ("local", "assigned", "touched", "fullest", "scored",
@@ -1502,16 +1517,17 @@ class PagedContinuousBatcher(_BatcherBase):
         assignments its router made, the held experts touched, the fullest
         one's tokens, the (query, row) pairs its indexer scored and those
         its attention read. [0] is the last decode step's; [1] all the
-        chunks' so far (real rows alone; it wraps). A family without an
-        indexer (``mellum``) leaves the last two columns zero, one without
-        experts would leave the first four. Both come with the step's
-        logits, and these series follow them."""
+        chunks' so far (real rows alone; it wraps: ``_add_chunk_counts``).
+        A family without an indexer (``mellum``) leaves the last two columns
+        zero, one without experts would leave the first four. Both come with
+        the step's logits, and these series follow them."""
         self._step_counts = bool(contract.get("step_counts"))
         if not self._step_counts:
             return
         from ..observability.metrics import get_registry
         reg = get_registry()
         self._chunk_counts_seen = 0
+        self._chunk_counts_due = []     # admissions' copies not yet read
 
         def by_phase(name, text):
             c = reg.counter(name, text, labelnames=("phase",))
@@ -1547,16 +1563,44 @@ class PagedContinuousBatcher(_BatcherBase):
             "tokens of the fullest held expert, a decode step and expert "
             "layer")
 
-    def _add_step_counts(self, counts: np.ndarray):
-        step, chunks = counts.astype(np.int64)
+    def _chunk_counts_after_admission(self):
+        """``step_counts[1]`` as an admission leaves it, set aside and its
+        copy to the host started; the next decode step's fetch reads it
+        (``_add_step_counts``), so an admission waits for nothing more
+        than its token. The slice is an array of its own: the cache's is
+        donated to the next executable."""
+        if self._step_counts:
+            chunks = self._state["layers"]["step_counts"]._data[1]
+            chunks.copy_to_host_async()
+            self._chunk_counts_due.append(chunks)
+
+    def _add_chunk_counts(self, chunks: np.ndarray):
+        """What the chunks chose since the last reading: ``step_counts[1]``
+        is an int32 running sum on the device, read as every admission
+        left it and as every decode step finds it, each difference taken
+        modulo 2^32. That is exact while ONE admission adds under 2^32 to
+        one layer's entry: its scored pairs, L (L + 1) / 2 of a cold
+        prompt of L tokens, pass that at 92,681 tokens. (Read with the
+        decode steps alone, four cold 49,152-token admissions inside one
+        gateway step wrapped it.)"""
+        chunks = chunks.astype(np.int64)
         new = (chunks - self._chunk_counts_seen) % (1 << 32)  # int32 wraps
         self._chunk_counts_seen = chunks
-        for phase, got in (("decode", step), ("prefill", new)):
-            of = dict(zip(self._STEP_COUNTS, got.sum(0)))
-            for name, series in self._step_counts_c.items():
-                series[phase].inc(int(of[name]))
+        of = dict(zip(self._STEP_COUNTS, new.sum(0)))
+        for name, series in self._step_counts_c.items():
+            series["prefill"].inc(int(of[name]))
         self._experts_touched_prefill_c.inc(
             int(new[:, self._STEP_COUNTS.index("touched")].sum()))
+
+    def _add_step_counts(self, counts: np.ndarray):
+        step = counts[0].astype(np.int64)
+        for due in self._chunk_counts_due:      # in the admissions' order
+            self._add_chunk_counts(np.asarray(due))
+        self._chunk_counts_due = []
+        self._add_chunk_counts(counts[1])
+        of = dict(zip(self._STEP_COUNTS, step.sum(0)))
+        for name, series in self._step_counts_c.items():
+            series["decode"].inc(int(of[name]))
         of = dict(zip(self._STEP_COUNTS, step.T))
         self._experts_touched_c.inc(int(of["touched"].sum()))
         for fullest in of["fullest"][of["assigned"] > 0]:
@@ -2190,14 +2234,14 @@ class PagedContinuousBatcher(_BatcherBase):
                                     np.array([m_rows], np.int32)),
                                 logits_at=paddle.to_tensor(
                                     np.array([S - 1], np.int32)),
-                                **self._slot_args(slot, S))
+                                **self._slot_args(slot, S, m_rows, pad_s))
                     else:
                         ids = paddle.to_tensor(ids_np[None, :])
                         logits, self._state["layers"] = \
                             self.model.paged_prefill_into(
                                 ids, self._state["layers"], bt_row,
                                 self.block_size,
-                                **self._slot_args(slot, L))
+                                **self._slot_args(slot, L, 0, L))
                     if self.draft_model is not None:
                         # mirror the suffix into the DRAFT pool (same block-
                         # table row, its own physical pages); cached pages
@@ -2245,6 +2289,7 @@ class PagedContinuousBatcher(_BatcherBase):
                 # last chunk chose; every other admission the [1, V] logits
                 with _span("serving.fetch"):
                     fetched = np.asarray(logits._data)
+                self._chunk_counts_after_admission()
                 tok = int(self._pick(fetched)[0])
                 self._count_picks(fetched, 1)
                 req.slot = slot
@@ -2342,7 +2387,8 @@ class PagedContinuousBatcher(_BatcherBase):
                 if not self.cache_quant:
                     lg, self._state["layers"] = self._chunk_fn(
                         ids_t, self._state["layers"], bt_row, dec_t, at_t,
-                        **self._slot_args(slot, min(L - dec, w)))
+                        **self._slot_args(slot, min(L - dec, w),
+                                          dec0 + dec, w))
                 elif scales is None:
                     first_nvalid = min(L - dec, w)
                     nvalid = paddle.to_tensor(
@@ -2490,6 +2536,8 @@ class PagedContinuousBatcher(_BatcherBase):
             self._state["capacity"] = self.blocks_per_seq * self.block_size
             if self._groups:
                 self._state["group_tables"] = self._group_tables()
+            if self._position_axes:
+                self._state["position_ids"] = self._positions(self._dec)
             if self.cache_quant and self._scales_dirty:
                 # scales change only at admit/release — skip the L x 4
                 # re-uploads on the steady-state decode path

@@ -14,6 +14,7 @@ from .bert import (BertConfig, BertForMaskedLM,
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     llama2_7b_config, llama_tiny_config, shard_llama)
 from .glm_dsa import GlmDsaConfig, GlmDsaForCausalLM, glm_dsa_tiny_config
+from .keye import KeyeConfig, KeyeForCausalLM, keye_tiny_config
 from .gpt import GPT2Config, GPT2ForCausalLM, GPT2Model, gpt2_124m_config
 from .resnet import (BasicBlock, BottleneckBlock, ResNet, resnet18, resnet34,
                      resnet50, resnet101, resnet152)
@@ -33,6 +34,7 @@ __all__ = [
     "SambaYConfig", "SambaYForCausalLM", "sambay_tiny_config",
     "GlmDsaConfig", "GlmDsaForCausalLM", "glm_dsa_tiny_config",
     "MellumConfig", "MellumForCausalLM", "mellum_tiny_config",
+    "KeyeConfig", "KeyeForCausalLM", "keye_tiny_config",
     "UNetConfig", "UNetModel", "unet_tiny_config", "sd_unet_config",
     "ddpm_loss", "ddim_sample",
 ]

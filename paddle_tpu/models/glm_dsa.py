@@ -56,6 +56,9 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..ops.registry import dispatch
+from .dsa_select import (_order_bits, _select,  # noqa: F401
+                         index_scores as _index_scores, kth_largest_bits,
+                         select_indices, select_rows)
 from .routed_experts import (F32, _counts_of_chunk,  # noqa: F401
                              _counts_of_step, _mm, _swiglu, _tile_rows,
                              expert_counts, routed_experts)
@@ -205,14 +208,6 @@ def _attn_inputs(p, x, cos, sin, eps, ieps):
     return q, latent, q_i, k_i, w_i
 
 
-def _index_scores(q_i, keys, w_i):
-    """I of queries q_i [..., n, D] (head weights w_i [..., n]) against
-    keys [..., T, D] (a query's own, or one set for all): [..., T]."""
-    sc = jnp.einsum("...hd,...td->...ht", q_i, keys,
-                    preferred_element_type=F32)
-    return jnp.sum(jax.nn.relu(sc) * w_i[..., None], -2)
-
-
 def _split_q(q, heads, rope, cos, sin):
     """q [N, H * (nope + R)] -> (q_nope [N, H, nope], q_pe [N, H, R])."""
     q = q.reshape(q.shape[0], heads, -1)
@@ -244,100 +239,6 @@ def _absorbed_out(p, o_lat, dtype):
         o = jnp.einsum("nhc,chv->nhv", o_lat.astype(dtype), w_v,
                        preferred_element_type=F32).astype(dtype)
         return _mm(o.reshape(o.shape[0], -1), p["o_w"])
-
-
-# -- selection: the index_topk-th largest without a sort ----------------------
-
-def _order_bits(x):
-    """float32 -> uint32 whose unsigned order is the floats' order."""
-    b = lax.bitcast_convert_type(x.astype(F32) + 0.0, jnp.int32)
-    key = jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
-    return lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
-
-
-def kth_largest_bits(bits, k: int, digit: int = 1):
-    """For every row of bits [..., T] uint32 the largest value v with
-    ``count(bits >= v) >= k`` (the k-th largest; 0 where fewer than k
-    entries are above 0), found ``digit`` bits a pass from the top: a pass
-    counts the entries at or above each of ``2 ** digit - 1`` candidates
-    and keeps the largest that k entries reach. ``digit`` 1 is bisection
-    (32 passes of one compare and count over the scores: a chunk's, whose
-    passes are bound by reading them); 4 is 8 passes of 15 (a decode
-    step's, whose passes are bound by their count)."""
-    steps = jnp.arange(1, 1 << digit, dtype=jnp.uint32)
-
-    def body(i, t):
-        shift = jnp.uint32(32 - digit) - i.astype(jnp.uint32) * digit
-        cands = t[..., None] | (steps << shift)               # [..., c]
-        reach = jnp.sum((bits[..., None, :] >= cands[..., None])
-                        .astype(jnp.int32), -1) >= k
-        best = jnp.sum(reach, -1).astype(jnp.uint32)    # reach is monotone
-        return t | (best << shift)
-
-    return lax.fori_loop(0, 32 // digit, body,
-                         jnp.zeros(bits.shape[:-1], jnp.uint32))
-
-
-_TILE = 128
-
-
-def _select(scores, valid, k: int, digit: int = 1):
-    """The k best valid rows of scores [B, T] as a mask [B, tiles, _TILE]
-    over T padded to whole tiles: every valid row where k or fewer are
-    valid; under a tie at the k-th largest score the lowest rows first, as
-    ``lax.top_k`` orders them. A threshold by bisection; ranks among tied
-    rows (a running count by tile) only where some query has a tie."""
-    b, t = scores.shape
-    pad = -t % _TILE
-    if pad:
-        scores = jnp.pad(scores, ((0, 0), (0, pad)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))
-    tiles = (t + pad) // _TILE
-    bits = jnp.where(valid, _order_bits(scores), jnp.uint32(0))
-    thr = kth_largest_bits(bits, k, digit)[:, None]
-    at_least = valid & (bits >= thr)
-
-    def break_ties():
-        above = valid & (bits > thr)
-        tie = (valid & (bits == thr)).reshape(b, tiles, _TILE)
-        need = k - jnp.sum(above, -1, dtype=jnp.int32)
-        tie_in = jnp.cumsum(tie, -1, dtype=jnp.int32)
-        before = jnp.cumsum(tie_in[..., -1], -1) - tie_in[..., -1]
-        rank = tie_in + before[..., None]
-        return (above.reshape(b, tiles, _TILE)
-                | (tie & (rank <= need[:, None, None]))).reshape(b, -1)
-
-    tied = jnp.any(jnp.sum(at_least, -1, dtype=jnp.int32) > k)
-    return lax.cond(tied, break_ties, lambda: at_least).reshape(
-        b, tiles, _TILE)
-
-
-def select_rows(scores, valid, k: int):
-    """``_select`` as a mask [B, T]."""
-    return _select(scores, valid, k).reshape(scores.shape[0], -1)[
-        :, :scores.shape[1]]
-
-
-def select_indices(scores, valid, k: int):
-    """``_select`` as (rows [B, k] int32 in rising order, kept [B, k] bool:
-    False on the slots past the valid rows). No sort and no scatter: the
-    j-th kept row is found by tile."""
-    sel = _select(scores, valid, k, digit=4)
-    tiles = sel.shape[1]
-    per_tile = jnp.sum(sel, -1, dtype=jnp.int32)            # [B, tiles]
-    upto = jnp.cumsum(per_tile, -1)
-    j = jnp.arange(k, dtype=jnp.int32)
-    tile_j = jnp.sum(upto[:, None, :] <= j[None, :, None], -1,
-                     dtype=jnp.int32)                       # [B, k]
-    kept = j[None, :] < upto[:, -1:]
-    tile_j = jnp.minimum(tile_j, tiles - 1)
-    rank = j[None, :] - (jnp.take_along_axis(upto, tile_j, 1)
-                         - jnp.take_along_axis(per_tile, tile_j, 1))
-    bits_j = jnp.take_along_axis(sel, tile_j[..., None], 1)  # [B, k, TILE]
-    seen = jnp.cumsum(bits_j, -1, dtype=jnp.int32)
-    pos = jnp.argmax(bits_j & (seen == rank[..., None] + 1), -1)
-    rows = tile_j * _TILE + pos.astype(jnp.int32)
-    return jnp.where(kept, rows, 0), kept
 
 
 # -- the expert layer ---------------------------------------------------------

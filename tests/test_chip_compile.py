@@ -615,3 +615,70 @@ def test_mellum_blocks_compile_at_published_widths(chip, monkeypatch, entry,
     text = compiled.as_text()
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and f"bf16[{pages},4,16,128]" in ln]
+
+
+@pytest.mark.parametrize("entry", ["decode", "chunk"])
+def test_keye_block_compiles_at_published_widths(chip, monkeypatch, entry):
+    """One block of ``models/keye.py`` at the cell ``keye2-longctx-
+    sessions``' shapes (hidden 2048, 32/4 heads of 128, an indexer of 16 x
+    64 that keeps 2,048 rows, all 128 experts of width 768, 8 slots; K, V
+    and index-key pools of 12,289 pages of 16 rows over tables of 4,096): a
+    decode step (rows written by the page; the index keys a slot holds
+    scored, 2,048 kept and their K and V gathered by row, whatever a slot
+    holds: no second attention path is compiled beside it; the grouped
+    product as one Pallas kernel) and a chunk of 512 (held rows scored and read 1,024 at a
+    time). The three pools keep their place (no copy of a whole pool) and a
+    block needs well under 1.5 GB beside them."""
+    import paddle_tpu.ops.pallas as pallas_tier
+    from paddle_tpu.models import keye
+
+    monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
+    d, heads, kv, hd, experts, f = 2048, 32, 4, 128, 128, 768
+    n_idx, di, slots, block, s_max, pages = 16, 64, 8, 16, 65536, 12289
+    table = s_max // block
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    w = {"ln1_g": sds((d,)), "q_w": sds((d, heads * hd)),
+         "k_w": sds((d, kv * hd)), "v_w": sds((d, kv * hd)),
+         "q_g": sds((hd,)), "k_g": sds((hd,)), "o_w": sds((heads * hd, d)),
+         "iq_w": sds((d, n_idx * di)), "ik_w": sds((d, di)),
+         "ik_g": sds((di,)), "ik_b": sds((di,)), "iw_w": sds((d, n_idx)),
+         "ln2_g": sds((d,)), "router_w": sds((d, experts)),
+         "exp_w1": sds((experts, d, 2 * f)), "exp_w2": sds((experts, f, d))}
+    static = dict(eps=1e-6, ieps=1e-6, heads=heads, kv_heads=kv, topk=2048,
+                  top_k=8, norm_topk=True)
+    pool = sds((pages, kv, block, hd))
+    keys = sds((pages, 1, block, keye.key_width(di)))
+
+    def angles(n):
+        return tuple(sds((n, w_), jnp.float32)
+                     for w_ in (hd // 2, hd // 2, 16, 16))
+
+    if entry == "decode":
+        compiled = jax.jit(
+            lambda p, x, kc, vc, ic, tab, dec, ang: keye._block_tok(
+                p, x, kc, vc, ic, tab, dec, ang, **static),
+            donate_argnums=(2, 3, 4)).lower(
+                w, sds((slots, d)), pool, pool, keys,
+                sds((slots, table), jnp.int32), sds((slots,), jnp.int32),
+                angles(slots)).compile()
+        assert not _kernel_calls(compiled, "paged_attention_decode")
+    else:
+        compiled = jax.jit(
+            lambda p, x, kc, vc, ic, tab, dec, real, ang:
+            keye._block_chunk(p, x, kc, vc, ic, tab, dec, real, ang,
+                              kb=1024, **static),
+            donate_argnums=(2, 3, 4)).lower(
+                w, sds((512, d)), pool, pool, keys, sds((table,), jnp.int32),
+                sds((), jnp.int32), sds((), jnp.int32),
+                angles(512)).compile()
+    assert len(_kernel_calls(compiled, "grouped_experts")) == 1
+    memory = compiled.memory_analysis()
+    pools = (2 * kv * hd + keye.key_width(di)) * pages * block * 2
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.temp_size_in_bytes < 1536 << 20
+    text = compiled.as_text()
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and (
+        f"bf16[{pages},4,16,128]" in ln or f"bf16[{pages},1,16,128]" in ln)]
