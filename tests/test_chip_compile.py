@@ -13,6 +13,7 @@ off around the compiles: an executable for a described device cannot be
 read back without the device.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
 
@@ -67,6 +68,39 @@ def _kernel_calls(compiled, name):
     ``pallas_call(name=...)``."""
     return [ln for ln in compiled.as_text().splitlines()
             if "tpu_custom_call" in ln and name in ln]
+
+
+def _loop_reads(compiled, scope, shape):
+    """Of the optimized HLO's fusions whose ``op_name`` lies under ``scope``
+    inside a ``while`` body, those with an operand of ``shape`` ("512,
+    65536"), as (fusion, the operand's own line in the fused computation,
+    whole): ``whole`` where anything but a ``dynamic-slice`` reads it."""
+    computations, name = {}, None
+    for ln in compiled.as_text().splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \((.*)\) -> .* \{$", ln)
+        if head:
+            name = head.group(1)
+            computations[name] = (head.group(2), [])
+        elif name and ln.strip() != "}":
+            computations[name][1].append(ln)
+    reads = []
+    for _, lines in computations.values():
+        for ln in lines:
+            call = re.search(r" fusion\(.*calls=%([\w.\-]+)", ln)
+            where = re.search(r'op_name="([^"]*)"', ln)
+            if not (call and where
+                    and scope + "/while/body" in where.group(1)):
+                continue
+            params, inner = computations[call.group(1)]
+            for p in re.findall(r"([\w.\-]+): \w+\[%s\]" % shape, params):
+                used = re.compile(r"%%%s\b" % re.escape(p))
+                own, = [u for u in inner if " parameter(" in u
+                        and used.search(u.split(" = ")[0])]
+                users = [u for u in inner
+                         if used.search(u.split(" = ", 1)[-1])]
+                reads.append((ln.strip(), own.strip(), any(
+                    " dynamic-slice(" not in u for u in users)))
+    return reads
 
 
 @pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha32", "gqa32_8"])
@@ -550,6 +584,12 @@ def test_glm_dsa_expert_block_compiles_at_published_widths(chip, monkeypatch,
     text = compiled.as_text()
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "bf16[16385,1,16," in ln]
+    if entry == "chunk":
+        # the thresholds' 32 passes count 512 x 32,768 bits whole, out of
+        # VMEM: 64 MiB that ``dsa_select.select_block`` leaves one block
+        # because they stay there from the first pass to the last
+        reads = _loop_reads(compiled, "index_topk", f"512,{s_max}")
+        assert reads and all("S(1)" in own for _, own, _ in reads)
 
 
 @pytest.mark.parametrize("kind", ["window", "full"])
@@ -682,3 +722,8 @@ def test_keye_block_compiles_at_published_widths(chip, monkeypatch, entry):
     text = compiled.as_text()
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (
         f"bf16[{pages},4,16,128]" in ln or f"bf16[{pages},1,16,128]" in ln)]
+    if entry == "chunk":
+        # the thresholds' passes read the 128 MiB of bits out of HBM, and
+        # only a block of the rows held at a time
+        reads = _loop_reads(compiled, "index_topk", f"512,{s_max}")
+        assert reads and not [fusion for fusion, _, whole in reads if whole]
