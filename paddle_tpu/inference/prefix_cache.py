@@ -45,6 +45,16 @@ reused, and are then reclaimed oldest release first from a queue (no walk
 of the tree). ``usable`` cuts a match back to the longest boundary at which
 a group still has every page of the window before it.
 
+State snapshots (``StateSnapshots``): a model whose layers carry a recurrent
+state beside their pages can resume a sequence behind a cached prefix only
+where that state was kept. The device holds a store of snapshots; this owner
+follows ``PageGroup``'s protocol over its indices: the node of the block
+that ends at a boundary adopts the snapshot the prefill wrote there, the
+snapshot goes free with the node, and when the store is full the one held
+longest without use is handed out anew (its node stays, with its pages, and
+stops being a boundary a match can end at). ``usable`` cuts a match back to
+the deepest node that still has its snapshot.
+
 Only FULL blocks are cached: a partially-filled page is still being
 appended to by its owner and cannot be shared. Generated tokens are
 cacheable too — a preempted/failed-over request resumes with
@@ -72,7 +82,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 __all__ = ["RadixPrefixCache", "HostTier", "DiskTier", "PageGroup",
-           "chain_hashes", "blob_nbytes"]
+           "StateSnapshots", "chain_hashes", "blob_nbytes"]
 
 _ROOT_HASH = 0
 
@@ -471,6 +481,130 @@ class PageGroup:
         return len(leaked)
 
 
+class StateSnapshots:
+    """The owner of a device store of ``n`` recurrent-state snapshots, one
+    for every ``rows`` rows of a cached prefix (``rows`` whole blocks), by
+    ``PageGroup``'s protocol: what a node holds of it is an index under
+    ``node.gpages[name]``.
+
+    A prefill ``take``s an index for each boundary it is about to pass (a
+    free one, else the one that has gone longest without use: ``owned`` is
+    in that order, and a resume moves its snapshot to the end) and the
+    executable writes the state there; when the block that ends at the
+    boundary has entered the tree, its node ``adopt``s the index. Until
+    then it is ``pending`` under its slot, and goes back if the prefill
+    never got there. No walk of the tree anywhere: a take, an adoption and
+    a reclaim are each a step or two on dicts (``steps`` counts them)."""
+
+    def __init__(self, name: str, rows: int, n: int, block_size: int):
+        if rows < block_size or rows % block_size:
+            raise ValueError(f"a snapshot every {rows} rows: whole blocks "
+                             f"of {block_size}")
+        self.name, self.rows, self.n = name, int(rows), int(n)
+        self.block_size = block_size
+        self.blocks = self.rows // block_size   # blocks between boundaries
+        self.free = list(range(self.n))
+        self.owned: "collections.OrderedDict[int, _Node]" = \
+            collections.OrderedDict()           # index -> node, oldest first
+        self.pending: Dict[int, Dict[int, int]] = {}   # slot -> {block: idx}
+        self.taken_total = 0
+        self.restored_total = 0
+        self.reclaimed_total = 0
+        self.steps = 0
+
+    def of(self, node: _Node) -> int:
+        """The node's snapshot, -1 where it has none (any more)."""
+        return -1 if node.gpages is None else node.gpages.get(self.name, -1)
+
+    def usable(self, path: Sequence[_Node]) -> int:
+        """The deepest boundary m <= len(path) whose node still holds the
+        state after its block: a sequence can resume there and nowhere
+        between."""
+        for m in range(len(path) // self.blocks * self.blocks, 0,
+                       -self.blocks):
+            self.steps += 1
+            if self.of(path[m - 1]) >= 0:
+                return m
+        return 0
+
+    def resume(self, path: Sequence[_Node]) -> int:
+        """The snapshot a sequence behind the matched blocks ``path``
+        (``usable`` has passed them) starts from, -1 behind none; it counts
+        as used now."""
+        if not path:
+            return -1
+        idx = self.of(path[-1])
+        self.owned.move_to_end(idx)
+        self.restored_total += 1
+        self.steps += 1
+        return idx
+
+    def take(self, slot: int, block: int) -> int:
+        """An index for ``slot``'s prefill to write the state after
+        ``block`` to: a free one, else the one longest unused (its node
+        loses it), -1 when every one is pending."""
+        self.steps += 1
+        if self.free:
+            idx = self.free.pop()
+        elif self.owned:
+            idx, node = self.owned.popitem(last=False)
+            del node.gpages[self.name]
+            self.reclaimed_total += 1
+        else:
+            return -1
+        self.pending.setdefault(slot, {})[block] = idx
+        self.taken_total += 1
+        return idx
+
+    def adopt(self, slot: int, block: int, node: _Node):
+        """The node of ``block`` of the slot's path takes the snapshot the
+        slot's prefill wrote after that block, where it wrote one; a node
+        that has one already keeps it, and the slot's goes back."""
+        idx = self.pending.get(slot, {}).pop(block, None)
+        if idx is None:
+            return
+        self.steps += 1
+        if self.of(node) >= 0:
+            self.free.append(idx)
+            return
+        self.owned[idx] = node
+        if node.gpages is None:
+            node.gpages = {}
+        node.gpages[self.name] = idx
+
+    def drop_slot(self, slot: int):
+        """What the slot's prefill took and no node adopted goes back."""
+        self.free.extend(self.pending.pop(slot, {}).values())
+
+    def forget(self, node: _Node):
+        """The node leaves the tree: its snapshot is free."""
+        idx = self.of(node)
+        if idx < 0:
+            return
+        self.steps += 1
+        del node.gpages[self.name]
+        del self.owned[idx]
+        self.free.append(idx)
+
+    def audit(self) -> int:
+        """Free, pending and owned indices cover the store exactly once and
+        every owned one is its node's. Raises on an anomaly; returns the
+        snapshots nobody owns (0 on the path that does not raise)."""
+        free, owned = set(self.free), set(self.owned)
+        pending = [i for taken in self.pending.values()
+                   for i in taken.values()]
+        lost = set(range(self.n)) - free - owned - set(pending)
+        if len(free) != len(self.free) or len(set(pending)) != len(pending) \
+                or free & owned or free & set(pending) \
+                or owned & set(pending) or lost \
+                or any(self.of(n) != i for i, n in self.owned.items()):
+            raise RuntimeError(
+                f"snapshot accounting bug in {self.name!r}: "
+                f"lost={sorted(lost)} free-and-owned={sorted(free & owned)} "
+                f"pending={sorted(pending)}")
+        return len(lost)
+
+
 class RadixPrefixCache:
     """Block-granular radix tree mapping token-block chains to pages,
     with optional host-DRAM (and disk) spill tiers beneath the pool.
@@ -511,7 +645,9 @@ class RadixPrefixCache:
         # cached routing advertisement (satellite: invalidate on mutation)
         self._summary_cache: Optional[Dict[str, object]] = None
         self._dirty = True
-        # the window groups whose pages nodes carry beside ``page``
+        # the window groups whose pages nodes carry beside ``page``, and
+        # the owner of recurrent-state snapshots where the model has one:
+        # each is told when a node leaves the tree (``forget``)
         self.groups: Dict[str, PageGroup] = {}
 
     # -- bookkeeping ---------------------------------------------------------

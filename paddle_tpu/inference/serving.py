@@ -1045,6 +1045,7 @@ class PagedContinuousBatcher(_BatcherBase):
         self._slot_state = bool(contract.get("slot_state"))
         self._position_axes = int(contract.get("position_axes", 0))
         self._init_page_groups(contract, group_pages, prefill_chunk)
+        self._init_state_snapshots(contract)
         pool_pages = n_pages + 1                    # the scratch page too
         if self._primary_group:
             pool_pages = dict({self._primary_group: pool_pages}, **{
@@ -1052,7 +1053,9 @@ class PagedContinuousBatcher(_BatcherBase):
         pool = model.paged_alloc(
             pool_pages, block_size,
             cache_dtype="int8" if cache_quant else None,
-            **({"max_batch": max_batch} if self._slot_state else {}))
+            **({"max_batch": max_batch} if self._slot_state else {}),
+            **({"n_snapshots": self._snapshots.n} if self._snapshots
+               else {}))
         if self._groups:
             self._init_group_bytes()
         self._init_slot_state_series(contract, pool)
@@ -1328,15 +1331,28 @@ class PagedContinuousBatcher(_BatcherBase):
             "serving.kv_bytes_per_resident_row",
             "bytes of all groups' pages that running sequences hold, as "
             "allocated, over their resident rows, a decode step")
+
+    def _init_cut_series(self):
+        """The series of matches cut back: by a window group that has
+        reclaimed pages of the window before the boundary, or for want of
+        a recurrent-state snapshot at it."""
+        from ..observability.metrics import get_registry
+        reg = get_registry()
         cut = reg.counter(
             "serving.prefix_hits_cut_total",
             "prefix matches cut back to a boundary at which a window "
-            "group still had the window's pages", labelnames=("why",))
-        self._hits_cut_c = cut.labels(why="window_pages_reclaimed")
+            "group still had the window's pages, or the recurrent layers "
+            "their state's snapshot", labelnames=("why",))
+        self._hits_cut_c = {
+            why: cut.labels(why=why)
+            for why in ("window_pages_reclaimed", "no_state_snapshot")}
         self._matches_c = reg.counter(
             "serving.prefix_matches_total",
             "admissions whose prompt matched cached blocks, before any "
             "cut")
+        self._rows_cut_c = reg.counter(
+            "serving.prefix_rows_cut_total",
+            "matched rows that a cut handed back to be prefilled again")
 
     def _init_group_bytes(self):
         """Bytes a page of each group, all its layers, from what the model
@@ -1362,18 +1378,26 @@ class PagedContinuousBatcher(_BatcherBase):
 
     def _cut_to_groups(self, matched: list) -> list:
         """A prefix match, cut back to the longest boundary at which every
-        window group still has the pages of the window before it."""
-        if not self._groups or not matched:
+        window group still has the pages of the window before it and, where
+        the model's layers carry a recurrent state, a snapshot of it is
+        still held."""
+        if not self._by_chunk or not matched:
             return matched
         self._matches_c.inc()
-        m = len(matched)
+        owners = [(g, "window_pages_reclaimed")
+                  for g in self._groups.values()]
+        if self._snapshots:
+            owners.append((self._snapshots, "no_state_snapshot"))
+        m, cut_by = len(matched), None
         while True:
-            cut = min(g.usable(matched[:m]) for g in self._groups.values())
-            if cut == m:
+            at, why = min(((o.usable(matched[:m]), why)
+                           for o, why in owners), key=lambda c: c[0])
+            if at == m:
                 break
-            m = cut
-        if m < len(matched):
-            self._hits_cut_c.inc()
+            m, cut_by = at, cut_by or why
+        if cut_by:
+            self._hits_cut_c[cut_by].inc()
+            self._rows_cut_c.inc((len(matched) - m) * self.block_size)
         return matched[:m]
 
     def _group_rows(self, slot: int, dec: int, upto_row: int):
@@ -1406,6 +1430,10 @@ class PagedContinuousBatcher(_BatcherBase):
         for j in range(start, n_blocks):
             for g in self._groups.values():
                 g.adopt(slot, j, path[j])
+            if self._snapshots:
+                self._snapshots.adopt(slot, j, path[j])
+        if self._snapshots:
+            self._count_snapshots()
 
     def _count_groups(self):
         """The groups' series, once a decode step."""
@@ -1426,6 +1454,80 @@ class PagedContinuousBatcher(_BatcherBase):
             self._row_bytes_h.observe(sum(
                 pages * self._page_bytes[n]
                 for n, pages in held.items()) / rows)
+
+    # -- snapshots of per-slot state, for the prefix cache ---------------------
+    def _init_state_snapshots(self, contract: dict):
+        """A model whose layers carry a recurrent state says every how many
+        rows a prefill can leave a snapshot of it (``state_snapshots``);
+        with the prefix cache on, the device holds one for every so many
+        rows of the pool and ``prefix_cache.StateSnapshots`` owns them: a
+        match is cut to the deepest boundary that still has its snapshot,
+        the first prefill behind a hit starts from it, and every prefill
+        writes those of the boundaries it passes. The blocks then enter the
+        tree as the prefill calls complete (``_group_insert``), as a model
+        with page groups has them. Without the contract's word nothing here
+        is on any path."""
+        spec = contract.get("state_snapshots")
+        self._snapshots = None
+        self._snapshot_rows = int(spec["rows"]) if spec else 0
+        self._resume_from: Dict[int, int] = {}   # slot -> snapshot, once
+        if spec and self.prefix_cache is not None:
+            from .prefix_cache import StateSnapshots
+            self._snapshots = StateSnapshots(
+                "state", self._snapshot_rows,
+                self.n_pages * self.block_size // self._snapshot_rows,
+                self.block_size)
+            self.prefix_cache.groups = dict(self._groups,
+                                            state=self._snapshots)
+            from ..observability.metrics import get_registry
+            reg = get_registry()
+            self._snapshot_series = (
+                reg.counter("serving.state_snapshots_taken_total",
+                            "recurrent-state snapshots prefills wrote"),
+                reg.counter("serving.state_snapshots_restored_total",
+                            "admissions that started from a snapshot"),
+                reg.counter("serving.state_snapshots_reclaimed_total",
+                            "snapshots taken from a node of the prefix "
+                            "cache, the store being full"),
+                reg.gauge("serving.state_snapshots_held",
+                          "snapshots that nodes of the prefix cache hold"))
+            self._snapshots_seen = [0, 0, 0]
+        if self._by_chunk:
+            self._init_cut_series()
+
+    @property
+    def _by_chunk(self) -> bool:
+        """Whether a sequence's blocks enter the prefix cache as its
+        prefill calls complete and a match may be cut back."""
+        return bool(self._groups) or self._snapshots is not None
+
+    def _snapshot_args(self, slot: int, n_valid: int, first_row: int,
+                       rows: int) -> dict:
+        """``snapshot_from`` and ``snapshot_to`` of one prefill call over
+        rows ``first_row .. first_row + rows`` of ``slot``, ``n_valid`` of
+        them real: the snapshot the admission resumes from (its first call
+        alone) and one for each boundary among the real rows."""
+        import paddle_tpu as paddle
+        every = self._snapshot_rows
+        src = self._resume_from.pop(slot, -1)
+        to = np.full((-(-rows // every),), -1, np.int32)
+        if self._snapshots:
+            with _span("serving.state_snapshot"):
+                first = first_row // every + 1
+                for j in range(first, (first_row + n_valid) // every + 1):
+                    to[j - first] = self._snapshots.take(
+                        slot, j * self._snapshots.blocks - 1)
+        return {"snapshot_from": paddle.to_tensor(np.array([src], np.int32)),
+                "snapshot_to": paddle.to_tensor(to)}
+
+    def _count_snapshots(self):
+        o = self._snapshots
+        now = [o.taken_total, o.restored_total, o.reclaimed_total]
+        for series, new, seen in zip(self._snapshot_series, now,
+                                     self._snapshots_seen):
+            series.inc(new - seen)
+        self._snapshots_seen = now
+        self._snapshot_series[3].set(len(o.owned))
 
     # -- per-slot state beside the pool ---------------------------------------
     def _init_slot_state_series(self, contract: dict, pool):
@@ -1491,6 +1593,8 @@ class PagedContinuousBatcher(_BatcherBase):
         args = {"slot": paddle.to_tensor(np.array([slot], np.int32))} \
             if self._slot_state else {}
         args["n_valid"] = paddle.to_tensor(np.array([n_valid], np.int32))
+        if self._snapshot_rows:
+            args.update(self._snapshot_args(slot, n_valid, first_row, rows))
         if self._groups:
             args["group_tables"] = self._group_tables(slot)
         if self._position_axes:
@@ -1937,6 +2041,9 @@ class PagedContinuousBatcher(_BatcherBase):
         self._dec[slot] = 0
         for g in self._groups.values():
             g.drop_slot(slot)
+        if self._snapshots:
+            self._snapshots.drop_slot(slot)
+        self._resume_from.pop(slot, None)
         self._slot_path.pop(slot, None)
         if self.draft_model is not None:
             self._ddec[slot] = 0
@@ -1989,8 +2096,10 @@ class PagedContinuousBatcher(_BatcherBase):
             # accounting must reconcile exactly too
             rep = self.prefix_cache.audit_tiers()
             self._host_bytes_g.set(rep.get("host_bytes", 0))
-        # every window group reconciles its own pool the same way
-        return sum(g.audit() for g in self._groups.values())
+        # every window group reconciles its own pool the same way, and the
+        # store of state snapshots its indices
+        return sum(g.audit() for g in self._groups.values()) \
+            + (self._snapshots.audit() if self._snapshots else 0)
 
     @property
     def free_page_count(self) -> int:
@@ -2171,7 +2280,10 @@ class PagedContinuousBatcher(_BatcherBase):
             m_rows = len(matched) * self.block_size
             ids_np, L, padded_len, upto = self._admission_plan(req, m_rows)
             need = self._pages_for(upto) - len(matched)
-            if need > len(self._free_pages) + (
+            # the tree is walked for what it could give up only where the
+            # free list alone does not cover the request
+            short = need - len(self._free_pages)
+            if short > 0 and short > (
                     self.prefix_cache.evictable_pages()
                     if self.prefix_cache is not None else 0) or any(
                     (len(self._slot_req) + 1) * g.ring > g.n_pages
@@ -2191,16 +2303,22 @@ class PagedContinuousBatcher(_BatcherBase):
                 if not self._alloc_pages(slot, upto):
                     raise RuntimeError("page accounting bug: admission gate "
                                        "passed but allocation failed")
-                if self._groups:
+                if self._by_chunk:
                     for g in self._groups.values():
                         g.start(slot, matched)
                     self._slot_path[slot] = list(matched)
                     self._slot_nodes[slot] = list(matched)
-                    if not self.prefill_chunk:
+                    if self._groups and not self.prefill_chunk:
                         self._group_rows(slot, m_rows, padded_len)
+                if self._snapshots and matched:
+                    with _span("serving.state_restore", rid=req.rid,
+                               rows=m_rows):
+                        self._resume_from[slot] = \
+                            self._snapshots.resume(matched)
                 self._trace_admit_begin(req)
                 self._trace_prefill_begin(req)
-                self._count_slot_state_admit(L)
+                if slot not in self._resume_from:
+                    self._count_slot_state_admit(L)
                 bt_row = paddle.to_tensor(self._bt[slot:slot + 1])
                 S = L - m_rows
                 with paddle.no_grad():
@@ -2270,7 +2388,7 @@ class PagedContinuousBatcher(_BatcherBase):
                     for t in src_tiers:
                         self._tier_hit_c.labels(tier=t).inc(self.block_size)
                     self.prefix_cache.host_hit_tokens += promoted_rows
-                    if self._groups:
+                    if self._by_chunk:
                         self._group_insert(slot, ids_np, L)
                     else:
                         new_nodes = self.prefix_cache.insert(
@@ -2408,7 +2526,7 @@ class PagedContinuousBatcher(_BatcherBase):
                 # k*C < L by the ceil-padding construction)
                 logits = lg
             dec += w
-            if self._groups:
+            if self._by_chunk:
                 self._group_insert(slot, ids_full, dec0 + dec)
         if scales is not None:
             if last_rest is not None:

@@ -18,6 +18,7 @@ from .keye import KeyeConfig, KeyeForCausalLM, keye_tiny_config
 from .gpt import GPT2Config, GPT2ForCausalLM, GPT2Model, gpt2_124m_config
 from .resnet import (BasicBlock, BottleneckBlock, ResNet, resnet18, resnet34,
                      resnet50, resnet101, resnet152)
+from .lfm2 import Lfm2Config, Lfm2ForCausalLM, lfm2_tiny_config
 from .mellum import MellumConfig, MellumForCausalLM, mellum_tiny_config
 from .sambay import SambaYConfig, SambaYForCausalLM, sambay_tiny_config
 from .unet import (UNetConfig, UNetModel, ddim_sample, ddpm_loss,
@@ -35,6 +36,7 @@ __all__ = [
     "GlmDsaConfig", "GlmDsaForCausalLM", "glm_dsa_tiny_config",
     "MellumConfig", "MellumForCausalLM", "mellum_tiny_config",
     "KeyeConfig", "KeyeForCausalLM", "keye_tiny_config",
+    "Lfm2Config", "Lfm2ForCausalLM", "lfm2_tiny_config",
     "UNetConfig", "UNetModel", "unet_tiny_config", "sd_unet_config",
     "ddpm_loss", "ddim_sample",
 ]

@@ -779,12 +779,16 @@ class SambaYForCausalLM(Layer):
             "window_rings": sum(l.kind == "window"
                                 for l in self.model.layers),
             "unsupported": {
-                "prefix_cache": f"a cached prefix is pages alone; {pages}: "
-                                f"a window layer gets a prefix cache by "
+                "prefix_cache": f"a cached prefix is pages and snapshots "
+                                f"of a few rows of state; {pages}: a "
+                                f"window layer gets a prefix cache by "
                                 f"keeping its rows as a page group "
-                                f"(models/mellum.py), and nothing "
-                                f"snapshots a recurrent state at a block "
-                                f"boundary",
+                                f"(models/mellum.py); a state of a few "
+                                f"rows a layer is snapshotted at block "
+                                f"boundaries (models/lfm2.py's "
+                                f"state_snapshots), and this model's "
+                                f"float32 state-space h and its rings are "
+                                f"not",
                 "kv_quant": "no calibrated int8 path for a pool of key "
                             "groups beside float state",
                 "cache_quant": "no dynamic int8 path for a pool of key "

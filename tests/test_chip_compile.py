@@ -727,3 +727,93 @@ def test_keye_block_compiles_at_published_widths(chip, monkeypatch, entry):
         # only a block of the rows held at a time
         reads = _loop_reads(compiled, "index_topk", f"512,{s_max}")
         assert reads and not [fusion for fusion, _, whole in reads if whole]
+
+
+@pytest.mark.parametrize("kind", ["conv", "attn"])
+@pytest.mark.parametrize("entry", ["decode", "chunk"])
+def test_lfm2_blocks_compile_at_published_widths(chip, monkeypatch, entry,
+                                                 kind):
+    """One block of ``models/lfm2.py`` of each kind at the cell ``lfm2-
+    agent-sessions``' shapes (hidden 2048, 32/8 heads of 64, 3 taps, all 64
+    experts of width 1536, 32 slots; K and V pools of 24,577 pages of 16
+    rows that hold two key heads a lane row, tables of 2,048): a decode
+    step (an attention layer writes its row by the page and reads the
+    packed pages through PR 26's kernel, query heads laid into their key
+    head's half of the lanes; a convolution layer reads and writes a
+    slot's two rows; the grouped product as one Pallas kernel) and a chunk
+    of 512 (held rows read 1,024 at a time; the convolution over the two
+    carried rows and the chunk, four boundary states cut out of it). The
+    pools keep their place and a block needs well under a gigabyte beside
+    them; the whole step's state hand-over (slots and the store of 3,073
+    snapshots) keeps the store's place too."""
+    import paddle_tpu.ops.pallas as pallas_tier
+    from paddle_tpu.models import lfm2
+
+    monkeypatch.setattr(pallas_tier, "on_tpu", lambda: True)
+    d, heads, kv, hd, experts, f = 2048, 32, 8, 64, 64, 1536
+    slots, block, s_max, pages, snaps = 32, 16, 32768, 24577, 3073
+    table = s_max // block
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    w = {"ln1_g": sds((d,)), "ln2_g": sds((d,)),
+         "router_w": sds((d, experts)), "router_b": sds((experts,)),
+         "exp_w1": sds((experts, d, 2 * f)), "exp_w2": sds((experts, f, d))}
+    ffn = dict(eps=1e-5, top_k=4, norm_topk=True, scaling=1.0)
+    if kind == "conv":
+        w.update(in_w=sds((d, 3 * d)), conv_w=sds((d, 3)), out_w=sds((d, d)))
+        if entry == "decode":
+            compiled = jax.jit(
+                lambda p, x, st, dec: lfm2._conv_tok(p, x, st, dec, **ffn)
+            ).lower(w, sds((slots, d)), sds((slots, 2, d)),
+                    sds((slots,), jnp.int32)).compile()
+        else:
+            compiled = jax.jit(
+                lambda p, x, st, real, at: lfm2._conv_chunk(
+                    p, x, st, real, at, **ffn)
+            ).lower(w, sds((512, d)), sds((2, d)), sds((), jnp.int32),
+                    sds((4,), jnp.int32)).compile()
+    else:
+        w.update(q_w=sds((d, heads * hd)), k_w=sds((d, kv * hd)),
+                 v_w=sds((d, kv * hd)), o_w=sds((heads * hd, d)),
+                 q_g=sds((hd,)), k_g=sds((hd,)))
+        static = dict(ffn, heads=heads, kv_heads=kv)
+        pool = sds((pages, kv // 2, block, 2 * hd))
+        angles = sds((s_max, hd // 2), jnp.float32)
+        if entry == "decode":
+            compiled = jax.jit(
+                lambda p, x, kc, vc, tab, dec, cos, sin: lfm2._attn_tok(
+                    p, x, kc, vc, tab, dec, cos, sin, **static),
+                donate_argnums=(2, 3)).lower(
+                    w, sds((slots, d)), pool, pool,
+                    sds((slots, table), jnp.int32),
+                    sds((slots,), jnp.int32), angles, angles).compile()
+            assert len(_kernel_calls(compiled,
+                                     "paged_attention_decode")) == 1
+        else:
+            compiled = jax.jit(
+                lambda p, x, kc, vc, tab, dec, real, cos, sin:
+                lfm2._attn_chunk(p, x, kc, vc, tab, dec, real, cos, sin,
+                                 kb=1024, **static),
+                donate_argnums=(2, 3)).lower(
+                    w, sds((512, d)), pool, pool, sds((table,), jnp.int32),
+                    sds((), jnp.int32), sds((), jnp.int32), angles,
+                    angles).compile()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= 2 * pages * kv * block * hd * 2
+        assert not [ln for ln in compiled.as_text().splitlines()
+                    if " copy(" in ln and f"bf16[{pages},4,16,128]" in ln]
+    assert len(_kernel_calls(compiled, "grouped_experts")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 768 << 20
+    if kind == "conv" and entry == "chunk":
+        # where a chunk's states go: four snapshots and the slot, in place
+        store = sds((snaps, 7, 2, d))
+        moved = jax.jit(lfm2._state_after_chunk, donate_argnums=(0, 1)).lower(
+            sds((slots, 7, 2, d)), store, sds((), jnp.int32),
+            sds((4,), jnp.int32), sds((7, 2, d)),
+            sds((7, 4, 2, d))).compile()
+        assert moved.memory_analysis().alias_size_in_bytes \
+            >= snaps * 7 * 2 * d * 2
+        assert not [ln for ln in moved.as_text().splitlines()
+                    if " copy(" in ln and f"bf16[{snaps},7,2,{d}]" in ln]

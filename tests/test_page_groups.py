@@ -266,3 +266,102 @@ def test_admission_waits_while_the_window_group_could_not_hold_a_ring_each(
         out = b.run_until_done()
     assert sorted(out) == sorted(rids) and b.audit_pages() == 0
     b.close()
+
+
+# -- the owner of recurrent-state snapshots (``prefix_cache.StateSnapshots``) --
+
+def _chain(n):
+    from paddle_tpu.inference.prefix_cache import _Node
+    return [_Node((i,), i, None, i, i + 1) for i in range(n)]
+
+
+def test_the_snapshot_owner_alone():
+    """``StateSnapshots`` without a batcher: take, adopt, resume, reclaim
+    the one longest unused, forget, drop, audit."""
+    from paddle_tpu.inference.prefix_cache import StateSnapshots
+    s = StateSnapshots("state", rows=16, n=3, block_size=8)
+    assert s.blocks == 2
+    nodes = _chain(8)
+    assert s.usable(nodes) == 0                # nothing held yet
+    taken = [s.take(0, b) for b in (1, 3, 5)]  # boundaries at 16, 32, 48
+    assert sorted(taken) == [0, 1, 2] and not s.free and s.audit() == 0
+    for b in (1, 3, 5):
+        s.adopt(0, b, nodes[b])
+    assert [s.of(n) for n in nodes] == [-1, taken[0], -1, taken[1], -1,
+                                        taken[2], -1, -1]
+    # a match ends at the deepest boundary that has a snapshot, never
+    # between two, and a path shorter than a boundary has none
+    assert [s.usable(nodes[:m]) for m in range(9)] \
+        == [0, 0, 2, 2, 4, 4, 6, 6, 6]
+    assert s.resume(nodes[:2]) == taken[0] and s.resume([]) == -1
+    # the store is full: the next boundary takes the one longest unused,
+    # which is no longer the first (it was resumed from) but the second
+    assert s.take(1, 7) == taken[1] and s.of(nodes[3]) == -1
+    assert s.reclaimed_total == 1 and s.usable(nodes[:4]) == 2
+    s.adopt(1, 7, nodes[7])
+    assert s.usable(nodes) == 8
+    # a node that has one already keeps it; the slot's goes back
+    s.forget(nodes[5])
+    assert s.of(nodes[5]) == -1 and s.free == [taken[2]]
+    assert s.take(0, 1) == taken[2]
+    s.adopt(0, 1, nodes[1])
+    assert s.of(nodes[1]) == taken[0] and s.free == [taken[2]]
+    # what a slot took and no node adopted goes back with the slot
+    s.take(0, 5)
+    assert not s.free and s.pending == {0: {5: taken[2]}, 1: {}}
+    s.drop_slot(0)
+    assert s.free == [taken[2]] and s.audit() == 0
+    s.free.clear()
+    with pytest.raises(RuntimeError, match="snapshot accounting"):
+        s.audit()
+    with pytest.raises(ValueError, match="whole blocks"):
+        StateSnapshots("state", rows=12, n=3, block_size=8)
+
+
+def test_snapshots_are_kept_in_steps_and_never_by_a_walk_of_the_tree(
+        monkeypatch):
+    """Two contexts of 90 rows, asked three times each, through a pool
+    that holds them whole and a store of 12 snapshots that does not: the
+    second context takes the first one's oldest snapshots and leaves its
+    deepest, which the first one's next ask resumes from; boundaries are
+    taken, adopted and reclaimed a step or two each (``steps`` grows with
+    the boundaries passed, not with the tree), and ``evict`` and its walk
+    are never entered."""
+    from paddle_tpu.models import Lfm2ForCausalLM, lfm2_tiny_config
+    calls = {"evict": 0, "walk": 0}
+    evict, walk = RadixPrefixCache.evict, \
+        RadixPrefixCache._lru_device_evictable
+    monkeypatch.setattr(RadixPrefixCache, "evict", lambda self, n: (
+        calls.__setitem__("evict", calls["evict"] + 1), evict(self, n))[1])
+    monkeypatch.setattr(RadixPrefixCache, "_lru_device_evictable",
+                        lambda self: (calls.__setitem__(
+                            "walk", calls["walk"] + 1), walk(self))[1])
+    paddle.seed(0)
+    m = Lfm2ForCausalLM(lfm2_tiny_config())
+    m.eval()
+    b = PagedContinuousBatcher(m, max_batch=2, s_max=128, block_size=4,
+                               n_pages=256, prefill_chunk=16,
+                               prefix_cache=True, compile=False)
+    store = b._snapshots
+    store.n, store.free = 12, list(range(12))
+    rng = np.random.default_rng(0)
+    docs = [rng.integers(0, 128, 90) for _ in range(2)]
+    per_request = []
+    with paddle.no_grad():
+        for ask in range(3):
+            for doc in docs:
+                before = store.steps
+                b.submit(np.concatenate([doc, rng.integers(0, 128, 3)]), 2)
+                b.run_until_done()
+                per_request.append(store.steps - before)
+    assert store.reclaimed_total > 0 and calls == {"evict": 0, "walk": 0}
+    assert b.audit_pages() == 0
+    # a cold context passes 11 boundaries: a take and an adoption each; a
+    # second ask looks back over at most 11 boundaries for one that is
+    # left, resumes, and passes the boundaries behind it; the tree holds
+    # some 50 nodes by then and no count here grows with it
+    assert max(per_request) <= 2 * 11 + 11 + 1, per_request
+    assert per_request[2:] == [2] * 4          # one look back, one resume
+    assert len(store.owned) == 12 and store.restored_total == 4
+    assert b.prefix_cache.stats()["hit_tokens"] == 4 * 88
+    b.close()

@@ -244,3 +244,26 @@ def test_speculative_with_prefix_cache_composes():
     assert b.spec_stats["rounds"] > 0
     b.audit_pages()
     assert _pages_leaked() == 0
+
+
+def test_a_node_that_leaves_the_tree_tells_the_owner_of_its_snapshot():
+    """``RadixPrefixCache.groups`` holds every owner of what nodes carry
+    beside their page: a ``StateSnapshots`` among them hears ``forget`` for
+    an evicted node, and the node's snapshot is free again."""
+    from paddle_tpu.inference.prefix_cache import (RadixPrefixCache,
+                                                   StateSnapshots)
+    cache = RadixPrefixCache(4)
+    store = StateSnapshots("state", rows=8, n=4, block_size=4)
+    cache.groups = {"state": store}
+    tokens = list(range(16))
+    nodes = cache.insert(tokens, [10, 11, 12, 13], 0, 4)
+    for block in (1, 3):
+        store.take(0, block)
+        store.adopt(0, block, nodes[block])
+    cache.unpin(nodes)
+    assert store.usable(cache.match(tokens)) == 4 and len(store.free) == 2
+    assert cache.evict(1) == [13]              # the deepest block goes
+    assert len(store.free) == 3 and store.audit() == 0
+    assert store.usable(cache.match(tokens)) == 2
+    assert cache.evict(3) == [12, 11, 10]
+    assert len(store.free) == 4 and not store.owned
